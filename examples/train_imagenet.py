@@ -133,6 +133,11 @@ def main():
         span = toc - (warm_done or tic)
         logging.info("epoch %d: %.1f img/s (%d images, %.1fs)",
                      epoch, n_img / span, n_img, span)
+    # no context is named anywhere above: everything landed on the default
+    # context (accelerator 0 when one is attached, else the host)
+    weight = next(iter(net.collect_params().values())).data()
+    print("parameters on %s (%s)" % (weight.context, ", ".join(
+        str(d) for d in weight._data.devices())))
     print("final-throughput: %.2f img/s" % (n_img / span))
     return n_img / span
 

@@ -3,9 +3,13 @@
 Reference analogue: the ctypes bridge in ``python/mxnet/base.py`` loading
 ``libmxnet.so``.  Here the native surface is split per subsystem
 (RecordIO codec, threaded image loader, dependency engine; SURVEY §2.1).
-Binding is optional: when a shared object hasn't been built
-(``make -C native``), callers fall back to pure-python implementations of
-the identical contract.
+
+The shared objects are build products (git-ignored): a checkout builds
+each one from ``native/`` on first use.  A build that fails raises with
+make's output — callers never degrade to their pure-python twins
+because a compile broke.  The pure-python implementations are used only
+where no build was asked for: ``MXNET_TPU_BUILD_NATIVE=0`` with the
+library absent, or an install that ships no ``native/`` sources.
 """
 from __future__ import annotations
 
@@ -13,15 +17,20 @@ import ctypes
 import os
 import subprocess
 
+from ..base import MXNetError
+
 _DIR = os.path.dirname(os.path.abspath(__file__))
+_NATIVE_SRC = os.path.join(os.path.dirname(os.path.dirname(_DIR)), "native")
 _LOADED = {}              # so_name -> CDLL | None (memoized, incl. misses)
 
 
 def load_shared(so_name, required_symbol=None):
-    """Load ``so_name`` from the package dir, lazily building it with the
+    """Load ``so_name`` from the package dir, building it with the
     in-image toolchain on first miss (serialized via a per-target lock
     file so concurrent workers don't race the same ``make``).  Returns a
-    CDLL or None.  Memoized per name — a failed build is not retried.
+    CDLL, or None when no build was asked for (see the module
+    docstring); raises ``MXNetError`` when the build or the load fails.
+    Memoized per name.
 
     ``required_symbol`` guards against a stale prebuilt library: when
     the loaded object lacks the symbol, it is rebuilt once from source
@@ -32,64 +41,52 @@ def load_shared(so_name, required_symbol=None):
     lib = _load_uncached(so_name)
     if lib is not None and required_symbol is not None and \
             not hasattr(lib, required_symbol):
-        try:
-            os.remove(os.path.join(_DIR, so_name))
-        except OSError:
-            pass
-        lib = _load_uncached(so_name)
-        if lib is not None and not hasattr(lib, required_symbol):
-            lib = None          # still stale: degrade to the fallback
+        lib = _load_uncached(so_name, rebuild=True)
+        if lib is None or not hasattr(lib, required_symbol):
+            raise MXNetError("%s lacks symbol %s even after a rebuild "
+                             "from native/" % (so_name, required_symbol))
     _LOADED[so_name] = lib
     return lib
 
 
-def _load_uncached(so_name):
+def _load_uncached(so_name, rebuild=False):
     so_path = os.path.join(_DIR, so_name)
-    if not os.path.exists(so_path) and \
-            os.environ.get("MXNET_TPU_BUILD_NATIVE", "1") == "1":
-        _try_build(so_path)
+    can_build = os.path.isdir(_NATIVE_SRC) and \
+        os.environ.get("MXNET_TPU_BUILD_NATIVE", "1") == "1"
+    if rebuild and can_build and os.path.exists(so_path):
+        os.remove(so_path)
     if not os.path.exists(so_path):
-        return None
+        if not can_build:
+            return None
+        _build(so_path)
     try:
         return ctypes.CDLL(so_path)
     except OSError:
-        # corrupt or ABI-incompatible artifact: rebuild once, then degrade
-        # to the pure-Python fallback (callers expect CDLL-or-None)
-        try:
-            os.remove(so_path)
-        except OSError:
-            return None
-        if os.environ.get("MXNET_TPU_BUILD_NATIVE", "1") == "1":
-            _try_build(so_path)
-        if os.path.exists(so_path):
-            try:
-                return ctypes.CDLL(so_path)
-            except OSError:
-                return None
-        return None
+        # corrupt or ABI-incompatible artifact: rebuild once from source
+        if rebuild or not can_build:
+            raise
+        return _load_uncached(so_name, rebuild=True)
 
 
-def _try_build(so_path):
-    native_dir = os.path.join(os.path.dirname(_DIR), "..", "native")
-    if not os.path.isdir(native_dir):
-        return False
+def _build(so_path):
+    import fcntl
     import logging
     logging.getLogger("mxnet_tpu").info(
         "building %s (one-time; set MXNET_TPU_BUILD_NATIVE=0 to skip)",
         os.path.basename(so_path))
-    lock_path = so_path + ".build.lock"
-    try:
-        import fcntl
-        with open(lock_path, "w") as lock:
-            fcntl.flock(lock, fcntl.LOCK_EX)
-            if os.path.exists(so_path):      # another process built it
-                return True
-            subprocess.run(["make", "-C", native_dir,
-                            os.path.relpath(so_path, native_dir)],
-                           check=True, capture_output=True, timeout=120)
-        return os.path.exists(so_path)
-    except Exception:
-        return False
+    with open(so_path + ".build.lock", "w") as lock:
+        fcntl.flock(lock, fcntl.LOCK_EX)
+        if os.path.exists(so_path):      # another process built it
+            return
+        proc = subprocess.run(
+            ["make", "-C", _NATIVE_SRC,
+             os.path.relpath(so_path, _NATIVE_SRC)],
+            capture_output=True, text=True, timeout=300)
+    if proc.returncode or not os.path.exists(so_path):
+        raise MXNetError(
+            "building %s from %s failed (make rc=%d):\n%s\n%s"
+            % (os.path.basename(so_path), _NATIVE_SRC, proc.returncode,
+               proc.stdout[-2000:], proc.stderr[-4000:]))
 
 
 _lib = None

@@ -9,9 +9,10 @@ dependency protocol (const/mutable vars, serialized writes, parallel
 reads, WaitForVar/WaitForAll) is the same observable contract
 (SURVEY §3.3).
 
-Binding is optional: when the shared object is missing and cannot be
-built, ``lib()`` returns None and the Python facade degrades to
-synchronous inline execution.
+``lib()`` returns None only where no build was asked for
+(``MXNET_TPU_BUILD_NATIVE=0`` / no ``native/`` sources — see the package
+docstring) and the Python facade then runs tasks synchronously inline;
+a failed build raises.
 """
 from __future__ import annotations
 

@@ -252,7 +252,10 @@ class HybridBlock(Block):
         (reference block.py:417)."""
         params = {p.name: p for p in self.collect_params().values()}
         flat_args, in_fmt = _flatten(list(args))
-        flat_vars = [_sym.var("data%d" % i) for i in range(len(flat_args))]
+        # the inputs' dtypes ride along: a cast() net (bf16 params) traced
+        # against default-f32 data vars fails conv/dot dtype checks
+        flat_vars = [_sym.var("data%d" % i, dtype=getattr(a, "dtype", None))
+                     for i, a in enumerate(flat_args)]
         arg_tree, _ = _regroup(list(flat_vars), in_fmt)
         pkw = {name: p.var() for name, p in self._reg_params.items()}
         with autograd.pause():

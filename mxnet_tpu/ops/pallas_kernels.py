@@ -13,9 +13,15 @@ kernels are Pallas.  This module holds the framework's built-in kernels:
   Differentiable via ``jax.custom_vjp``; the backward recomputes scores in
   q-row chunks (O(chunk·S) memory, not O(S²)).
 
-On non-TPU backends the kernels run in Pallas interpret mode (tests) or
-callers fall back to the jnp reference (``parallel/ring_attention.py``'s
-``local_attention``).
+The kernels compile with Mosaic (``interpret=False``, the default) and
+that only works on a TPU.  ``interpret=True`` is the explicit CPU-test
+mode (tests/test_pallas.py); nothing here picks it silently.
+
+Mosaic has no 64-bit types and the package runs with ``jax_enable_x64``
+on, so every constant inside a kernel body carries an explicit 32-bit
+dtype: a bare Python float routed through a jitted ``jnp`` helper
+(``jnp.where``) would otherwise enter the kernel as an f64 operand and
+fail to lower (``Unsupported cast: float64 -> float32``).
 """
 from __future__ import annotations
 
@@ -24,27 +30,41 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu  # noqa: F401 (probe)
-    HAS_PALLAS = True
-except Exception:  # pragma: no cover
-    HAS_PALLAS = False
+__all__ = ["flash_attention"]
 
-__all__ = ["flash_attention", "HAS_PALLAS"]
-
-_NEG = -1e30
+_NEG = np.float32(-1e30)
+_TINY = np.float32(1e-30)
 _LANES = 128  # m/l scratch is lane-replicated to satisfy TPU tiling
+
+
+def _lane_cols(x, n):
+    """Re-width a lane-replicated [rows, 128] value to [rows, n]; n is
+    below one lane tile or a multiple of it (checked by the caller)."""
+    reps, rem = divmod(n, _LANES)
+    if rem:
+        return x[:, :n]
+    return x if reps == 1 else jnp.tile(x, (1, reps))
 
 
 def _attn_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
                  block_q, block_k, causal, sm_scale, seq_len):
     """One (bh, qi, ki) program. Scratch (acc/m/l) carries across ki —
-    the innermost grid axis is sequential on TPU."""
+    the innermost grid axis is sequential on TPU.  Row statistics stay
+    2-D ([block_q, 128], every lane equal) end to end: Mosaic lays
+    vectors out on (sublane, lane) tiles and 1-D row vectors have no
+    stable layout."""
     qi = pl.program_id(1)
     ki = pl.program_id(2)
     num_k = pl.num_programs(2)
+    head_dim = q_ref.shape[-1]
+    # f32 in means f32 math: Mosaic's default contraction rounds f32
+    # operands to bf16 for a single MXU pass
+    precision = jax.lax.Precision.HIGHEST \
+        if q_ref.dtype == jnp.float32 else None
 
     @pl.when(ki == 0)
     def _init():
@@ -59,11 +79,13 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
 
     @pl.when(live)
     def _step():
-        q = q_ref[:].astype(jnp.float32) * sm_scale
-        kb = k_ref[:].astype(jnp.float32)
-        vb = v_ref[:].astype(jnp.float32)
-        s = jax.lax.dot_general(q, kb, (((1,), (1,)), ((), ())),
+        # operands stay in their storage dtype (bf16 feeds the MXU at
+        # full rate); products accumulate in f32
+        s = jax.lax.dot_general(q_ref[:], k_ref[:],
+                                (((1,), (1,)), ((), ())),
+                                precision=precision,
                                 preferred_element_type=jnp.float32)
+        s = s * np.float32(sm_scale)
         k_pos = ki * block_k + jax.lax.broadcasted_iota(
             jnp.int32, (block_q, block_k), 1)
         valid = k_pos < seq_len          # mask the padded K tail
@@ -73,30 +95,38 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
             valid = jnp.logical_and(valid, q_pos >= k_pos)
         s = jnp.where(valid, s, _NEG)
 
-        m_prev = m_ref[:, 0]
-        blk_max = jnp.max(s, axis=-1)
-        m_new = jnp.maximum(m_prev, blk_max)
+        m_prev = m_ref[:]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1)[:, None])
         corr = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new[:, None])
-        l_new = l_ref[:, 0] * corr + jnp.sum(p, axis=-1)
-        acc_ref[:] = acc_ref[:] * corr[:, None] + jax.lax.dot_general(
-            p, vb, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[:] = jnp.broadcast_to(m_new[:, None], m_ref.shape)
-        l_ref[:] = jnp.broadcast_to(l_new[:, None], l_ref.shape)
+        p = jnp.exp(s - _lane_cols(m_new, block_k))
+        l_ref[:] = l_ref[:] * corr + jnp.sum(p, axis=1)[:, None]
+        m_ref[:] = m_new
+        acc_ref[:] = acc_ref[:] * _lane_cols(corr, head_dim) + \
+            jax.lax.dot_general(p.astype(v_ref.dtype), v_ref[:],
+                                (((1,), (0,)), ((), ())),
+                                precision=precision,
+                                preferred_element_type=jnp.float32)
 
     @pl.when(ki == num_k - 1)
     def _finalize():
-        o_ref[:] = (acc_ref[:] /
-                    jnp.maximum(l_ref[:, 0], 1e-30)[:, None]
-                    ).astype(o_ref.dtype)
+        denom = _lane_cols(jnp.maximum(l_ref[:], _TINY), head_dim)
+        o_ref[:] = (acc_ref[:] / denom).astype(o_ref.dtype)
 
 
 def _flash_fwd_impl(q, k, v, causal, sm_scale, block_q, block_k, interpret):
     b, h, s, d = q.shape
+    if d > _LANES and d % _LANES:
+        raise ValueError("flash_attention: head dim %d must be <= %d or a "
+                         "multiple of it" % (d, _LANES))
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(d)
     bq = min(block_q, s)
-    bk = min(block_k, s)
+    # K tiles are whole lane tiles (K/V are padded up to them below);
+    # a sequence shorter than one lane tile is a single block
+    bk = min(block_k, -(-s // _LANES) * _LANES) if s >= _LANES \
+        else min(block_k, s)
+    if bk > _LANES and bk % _LANES:
+        raise ValueError("flash_attention: block_k %d must be <= %d or a "
+                         "multiple of it" % (bk, _LANES))
     qf = q.reshape(b * h, s, d)
     kf = k.reshape(b * h, s, d)
     vf = v.reshape(b * h, s, d)
@@ -109,21 +139,26 @@ def _flash_fwd_impl(q, k, v, causal, sm_scale, block_q, block_k, interpret):
         vf = jnp.pad(vf, pad)
     kernel = functools.partial(_attn_kernel, block_q=bq, block_k=bk,
                                causal=causal, sm_scale=scale, seq_len=s)
+    zero = np.int32(0)      # a bare 0 is an i64 block index under x64
     out = pl.pallas_call(
         kernel,
         grid=(b * h, pl.cdiv(s, bq), s_pad // bk),
         in_specs=[
-            pl.BlockSpec((None, bq, d), lambda bh, i, t: (bh, i, 0)),
-            pl.BlockSpec((None, bk, d), lambda bh, i, t: (bh, t, 0)),
-            pl.BlockSpec((None, bk, d), lambda bh, i, t: (bh, t, 0)),
+            pl.BlockSpec((None, bq, d), lambda bh, i, t: (bh, i, zero)),
+            pl.BlockSpec((None, bk, d), lambda bh, i, t: (bh, t, zero)),
+            pl.BlockSpec((None, bk, d), lambda bh, i, t: (bh, t, zero)),
         ],
-        out_specs=pl.BlockSpec((None, bq, d), lambda bh, i, t: (bh, i, 0)),
+        out_specs=pl.BlockSpec((None, bq, d),
+                               lambda bh, i, t: (bh, i, zero)),
         out_shape=jax.ShapeDtypeStruct((b * h, s, d), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((bq, d), jnp.float32),
             pltpu.VMEM((bq, _LANES), jnp.float32),
             pltpu.VMEM((bq, _LANES), jnp.float32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name="flash_attention_fwd",
         interpret=interpret,
     )(qf, kf, vf)
     return out.reshape(b, h, s, d)

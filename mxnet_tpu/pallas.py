@@ -26,10 +26,18 @@ Gluon blocks, Module training — exactly like a built-in op:
     y = mx.nd.my_scale(mx.nd.ones((4, 4)), alpha=3.0)   # eager
     s = mx.sym.my_scale(mx.sym.Variable("d"), alpha=3.0)  # symbolic
 
-Kernels that accept an ``interpret`` keyword get it filled automatically:
-``False`` on TPU (compiled Mosaic), ``True`` elsewhere (the Pallas
-interpreter — the CPU-test story, mirroring how the in-tree flash
-attention kernels degrade, ``ops/pallas_kernels.py:16``).
+Kernels compile with Mosaic, which needs a TPU.  A kernel that accepts
+an ``interpret`` keyword gets ``False`` unless the registration
+(``register(..., interpret=True)``) or the call site
+(``mx.nd.my_scale(x, interpret=True)``) says otherwise: the Pallas
+interpreter is the explicit CPU-test mode, never a silent fallback — a
+compiled kernel on a host without a TPU raises.
+
+Mosaic has no 64-bit types and this package enables ``jax_enable_x64``:
+give constants inside a kernel body an explicit 32-bit dtype
+(``np.float32(-1e30)``, not ``-1e30``) wherever they pass through a
+jitted ``jnp`` helper such as ``jnp.where``, or lowering fails with
+``Unsupported cast: float64 -> float32``.
 
 Gradients: pure-JAX ops differentiate through ``jax.vjp`` automatically;
 ``pl.pallas_call`` does not, so kernels used in training either pass
@@ -40,8 +48,6 @@ from __future__ import annotations
 
 import inspect
 
-import jax
-
 from .base import MXNetError
 from .ops.registry import OP_REGISTRY, Op
 
@@ -49,11 +55,6 @@ __all__ = ["register", "unregister", "registered_kernels"]
 
 _USER_KERNELS = []
 _SHADOWED = {}  # name -> Op it force-replaced, restored on unregister()
-
-
-def _auto_interpret():
-    """Interpret-mode default: compiled on TPU, interpreter elsewhere."""
-    return jax.default_backend() != "tpu"
 
 
 def _expose(name, op):
@@ -77,21 +78,21 @@ def _expose(name, op):
 
 
 def register(name, fn=None, *, grad=None, num_outputs=1, takes_mode=False,
-             needs_rng=False, interpret=None, force=False):
+             needs_rng=False, interpret=False, force=False):
     """Register *fn* as operator *name*, usable from nd/sym/gluon.
 
     Parameters
     ----------
     fn : pure function ``(*jax_arrays, **attrs) -> array | tuple`` —
         typically wrapping ``pl.pallas_call``. If it accepts an
-        ``interpret`` keyword, the registry fills it per-backend unless
-        the call site pins it.
+        ``interpret`` keyword, the registry fills it with *interpret*
+        unless the call site pins it.
     grad : optional semantic backward
         ``bwd(out_grads, inputs, outputs, attrs) -> input_grads`` (tuple,
         one per input). Without it, gradients flow through ``jax.vjp`` —
         fine for pure-JAX bodies, unavailable for raw pallas_call.
-    interpret : force interpret mode on (True) / off (False); default
-        auto-selects by backend at call time.
+    interpret : run the kernel in the Pallas interpreter (True; CPU
+        tests) instead of compiling it with Mosaic (False, the default).
     force : allow replacing an existing registration.
 
     Returns the eager ``mx.nd.<name>`` callable (decorator-friendly).
@@ -118,8 +119,7 @@ def register(name, fn=None, *, grad=None, num_outputs=1, takes_mode=False,
     if accepts_interpret:
         def body(*arrays, **attrs):
             if attrs.get("interpret") is None:
-                attrs["interpret"] = (_auto_interpret() if interpret is None
-                                      else interpret)
+                attrs["interpret"] = interpret
             return fn(*arrays, **attrs)
         body.__name__ = getattr(fn, "__name__", name)
     else:

@@ -23,11 +23,10 @@ used to carry privately:
    on dp-only, dp+tp, or dp+tp+sp meshes), :func:`named_sharding`,
    :func:`replicated`, and :func:`shard_put` (multi-process-safe
    placement: each process materializes only its addressable shards).
-3. **Program entry points** — :func:`shard_map`, a version-adaptive
-   wrapper over jax's drifting shard_map surface (``jax.shard_map`` +
-   ``check_vma`` on current jax, ``jax.experimental.shard_map`` +
-   ``check_rep`` on older releases), plus the :func:`pvary` /
-   :func:`vma_axes` capability shims its callers need; and
+3. **Program entry points** — :func:`shard_map`, the one wrapper over
+   ``jax.shard_map`` (mesh defaulting + the ``check`` switch for
+   ``check_vma``), plus the :func:`pvary` / :func:`vma_axes` helpers its
+   scan-carrying callers need; and
    :func:`jit_sharded`, ``jax.jit`` + ``watch_jit`` in one call so every
    SPMD program lands in the telemetry retrace watchdog, cost accounting
    and ``MXNET_DEVICE_TIME`` attribution from day one.
@@ -287,30 +286,14 @@ def shard_put(value, sharding, spec=None):
 
 
 # --------------------------------------------------------------------------
-# Program entry points: shard_map (version-adaptive) and watched jit
+# Program entry points: shard_map and watched jit
 # --------------------------------------------------------------------------
-
-def _resolve_shard_map():
-    fn = getattr(jax, "shard_map", None)
-    if fn is not None:
-        return fn, "check_vma"          # current jax: top-level API
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm, "check_rep"             # older jax: experimental API
-
-
-_SHARD_MAP, _CHECK_KW = _resolve_shard_map()
-
 
 def shard_map(fn, mesh=None, in_specs=None, out_specs=None, check=None):
     """Map ``fn`` over mesh shards with explicit collectives — the ONE
-    shard_map entry point in the tree.
-
-    jax renamed both the callable (``jax.experimental.shard_map`` →
-    ``jax.shard_map``) and the replication-check kwarg (``check_rep`` →
-    ``check_vma``) across releases; this wrapper presents one stable
-    surface (``check=False`` disables the replication/varying-manual-axes
-    checker on either API).  ``mesh`` defaults to the innermost
-    :func:`using_mesh` scope.
+    ``jax.shard_map`` call site in the tree.  ``check=False`` disables
+    the varying-manual-axes checker (``check_vma``); ``mesh`` defaults to
+    the innermost :func:`using_mesh` scope.
     """
     if mesh is None:
         mesh = current_mesh()
@@ -320,38 +303,25 @@ def shard_map(fn, mesh=None, in_specs=None, out_specs=None, check=None):
                 "active")
     kwargs = {}
     if check is not None:
-        kwargs[_CHECK_KW] = check
-    return _SHARD_MAP(fn, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, **kwargs)
+        kwargs["check_vma"] = check
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kwargs)
 
 
 def vma_axes(*arrays, extra=()):
     """The union of mesh axes ``arrays`` are device-varying over, plus
-    ``extra`` — the axes a shard_map scan carry must be cast to.  On jax
-    without the varying-manual-axes type system (no ``jax.typeof``) the
-    answer is just ``extra``: the old ``check_rep`` tracker needs no
-    explicit casts."""
+    ``extra`` — the axes a shard_map scan carry must be cast to."""
     axes = set(extra)
-    typeof = getattr(jax, "typeof", None)
-    if typeof is not None:
-        for a in arrays:
-            axes |= set(getattr(typeof(a), "vma", ()) or ())
+    for a in arrays:
+        axes |= set(jax.typeof(a).vma)
     return tuple(sorted(axes))
 
 
 def pvary(x, axes):
-    """Cast ``x`` to be device-varying over ``axes`` inside shard_map.
-    Identity on jax versions whose shard_map has no varying-axis types
-    (their replication checker infers it, or ``check=False`` skips it)."""
+    """Cast ``x`` to be device-varying over ``axes`` inside shard_map."""
     if not axes:
         return x
-    pcast = getattr(jax.lax, "pcast", None)
-    if pcast is not None:
-        return pcast(x, tuple(axes), to="varying")
-    pv = getattr(jax.lax, "pvary", None)
-    if pv is not None:
-        return pv(x, tuple(axes))
-    return x
+    return jax.lax.pcast(x, tuple(axes), to="varying")
 
 
 def jit_sharded(fn, name, **jit_kwargs):

@@ -91,9 +91,8 @@ def global_devices():
 
 def _coord_client():
     """The jax.distributed coordination-service client, or None.  Its
-    barrier/KV ops are plain gRPC to the coordinator — no XLA program, so
-    they work on backends whose compiler can't span processes (CPU
-    before jaxlib 0.5)."""
+    barrier/KV ops are plain gRPC to the coordinator — no XLA program,
+    so they cost no compile and work before any mesh exists."""
     try:
         from jax._src import distributed
         return distributed.global_state.client

@@ -19,7 +19,7 @@ import numpy as np
 
 from . import ndarray as nd
 from .base import MXNetError
-from .context import cpu
+from .context import current_context
 
 __all__ = ["Predictor"]
 
@@ -29,7 +29,7 @@ class Predictor(object):
 
     def __init__(self, symbol, arg_params, aux_params, input_shapes,
                  ctx=None):
-        ctx = ctx or cpu()
+        ctx = ctx or current_context()
         self._ctx = ctx
         self._input_names = list(input_shapes)
         # kept for the serving tier: bucket-padded AOT variants re-infer
@@ -151,10 +151,9 @@ class _EmbeddedPredictor(object):
         symbol = sym_mod.load_json(symbol_json)
         arg_params, aux_params = split_saved_params(
             nd_utils.load_from_bytes(param_bytes))
-        if dev_type >= 2 and context.num_tpus():
-            ctx = context.tpu(dev_id)
-        else:
-            ctx = context.cpu(dev_id)
+        # reference dev_type codes: 1 = cpu, 2 = gpu (here: accelerator);
+        # an accelerator that is not attached raises, it is not the host
+        ctx = context.tpu(dev_id) if dev_type >= 2 else context.cpu(dev_id)
         shapes = {n: tuple(int(x) for x in s)
                   for n, s in zip(input_names, input_shapes)}
         self._pred = Predictor(symbol, arg_params, aux_params, shapes,

@@ -133,7 +133,8 @@ class PredictProgram:
         # one fixed key for the whole program lifetime: eval-mode graphs
         # are deterministic (dropout is identity), and a per-call key
         # would make identical requests non-reproducible
-        self._key = _random.next_key()
+        import jax
+        self._key = jax.device_put(_random.next_key(), self._dev)
         self._aux_vals = [ex.aux_dict[n]._data for n in ex.aux_names]
         self.buckets = bucket_sizes(max_batch=max_batch, buckets=buckets)
         self.max_batch = self.buckets[-1]
@@ -153,15 +154,21 @@ class PredictProgram:
 
     def _specs_for(self, b):
         """ShapeDtypeStruct specimens of the eval program at bucket *b*
-        — what the AOT lower (and the graftcheck provider) traces."""
+        — what the AOT lower (and the graftcheck provider) traces.  They
+        carry the predictor's device, so the executable is compiled for
+        the context the model was loaded on, whatever jax's default
+        device is."""
         import jax
+        here = jax.sharding.SingleDeviceSharding(self._dev)
         shapes = self._arg_shapes_for(b)
         arg_specs = [jax.ShapeDtypeStruct(tuple(shapes[n]),
-                                          self._ex.arg_dict[n].dtype)
+                                          self._ex.arg_dict[n].dtype,
+                                          sharding=here)
                      for n in self._ex.arg_names]
-        aux_specs = [jax.ShapeDtypeStruct(v.shape, v.dtype)
+        aux_specs = [jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=here)
                      for v in self._aux_vals]
-        key_spec = jax.ShapeDtypeStruct(self._key.shape, self._key.dtype)
+        key_spec = jax.ShapeDtypeStruct(self._key.shape, self._key.dtype,
+                                        sharding=here)
         return arg_specs, aux_specs, key_spec
 
     def _build_variant(self, b):

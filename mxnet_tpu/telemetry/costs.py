@@ -42,13 +42,19 @@ from . import core
 
 __all__ = ["capture", "analyze_compiled", "finalize_step", "peaks",
            "peaks_if_resolved", "refresh_from_env", "machine_balance",
-           "PEAK_TABLE", "ICI_TABLE"]
+           "device_peaks", "PEAK_TABLE", "ICI_TABLE"]
 
 _TRUTHY = ("1", "true", "on", "yes")
 
-# (peak FLOP/s, peak HBM bytes/s) per JAX device, keyed on device_kind.
-# bf16/dense numbers from the published per-chip specs, halved for the
-# two-core-per-chip generations where jax exposes cores as devices.
+# (peak FLOP/s, peak HBM bytes/s) per JAX device, keyed on device_kind
+# exactly as jax reports it — THE peak table of the repo (bench.py and
+# chip_smoke.py read it; there is no second copy).  bf16/dense numbers
+# from the published per-chip specs, halved for the two-core-per-chip
+# generations where jax exposes cores as devices.  The one row measured
+# against so far: a v5e chip reports device_kind "TPU v5 lite"
+# (chip run, PR 21); 197 TFLOP/s bf16 and 819 GB/s HBM are from Google
+# Cloud's "TPU v5e" system-architecture page.  A device_kind missing
+# here is an error (see peaks()), never a default row.
 PEAK_TABLE = {
     "TPU v2":      (22.5e12, 350e9),
     "TPU v3":      (61.5e12, 450e9),
@@ -64,7 +70,6 @@ PEAK_TABLE = {
     # peak); pin MXNET_PEAK_FLOPS for a real CPU MFU
     "cpu":         (1e11, 50e9),
 }
-_FALLBACK = PEAK_TABLE["cpu"]
 
 # peak interconnect bytes/s per JAX device (aggregate over a chip's ICI
 # links) — the denominator for the "comm" leg of the opprof roofline.
@@ -85,7 +90,6 @@ ICI_TABLE = {
     # memcpys, so the "interconnect" placeholder sits below HBM peak
     "cpu":         (10e9,),
 }
-_ICI_FALLBACK = ICI_TABLE["cpu"]
 
 
 def _env_float(name):
@@ -186,22 +190,36 @@ def analyze_compiled(compiled):
 # peaks + step finalization
 # --------------------------------------------------------------------------
 
+def device_peaks(kind):
+    """(FLOP/s, HBM bytes/s, ICI bytes/s) of ONE device of ``kind`` from
+    the tables.  An unknown ``device_kind`` raises: a utilization
+    measured against another device's peak is wrong, not approximate."""
+    if kind not in PEAK_TABLE or kind not in ICI_TABLE:
+        from ..base import MXNetError
+        raise MXNetError(
+            "no peak FLOP/s / bandwidth known for device_kind %r: add a "
+            "sourced row to telemetry.costs.PEAK_TABLE / ICI_TABLE, or pin "
+            "MXNET_PEAK_FLOPS, MXNET_PEAK_HBM_BW and MXNET_PEAK_ICI_BW"
+            % (kind,))
+    return PEAK_TABLE[kind] + ICI_TABLE[kind]
+
+
 def peaks():
     """The aggregate (all local devices) peak FLOP/s and HBM bytes/s this
-    process is measured against, resolved once and cached."""
+    process is measured against, resolved once and cached: the
+    ``MXNET_PEAK_*`` pins where set, else :func:`device_peaks` x devices."""
     global _peaks
     if _peaks is not None:
         return _peaks
-    kind, n_dev = "unknown", 1
-    try:
-        import jax
-        devs = jax.local_devices()
-        n_dev = max(1, len(devs))
-        kind = getattr(devs[0], "device_kind", "unknown") or "unknown"
-    except Exception:
-        pass
-    table_flops, table_bw = PEAK_TABLE.get(kind, _FALLBACK)
-    (table_ici,) = ICI_TABLE.get(kind, _ICI_FALLBACK)
+    import jax
+    devs = jax.local_devices()
+    n_dev = len(devs)
+    kind = devs[0].device_kind
+    # the tables are consulted (and an unknown kind raises) only when
+    # some pin is missing
+    table_flops, table_bw, table_ici = \
+        device_peaks(kind) if None in (_ENV_PEAK_FLOPS, _ENV_PEAK_BW,
+                                       _ENV_PEAK_ICI) else (0.0, 0.0, 0.0)
     flops = _ENV_PEAK_FLOPS if _ENV_PEAK_FLOPS is not None \
         else table_flops * n_dev
     bw = _ENV_PEAK_BW if _ENV_PEAK_BW is not None else table_bw * n_dev

@@ -137,6 +137,30 @@ def test_peak_table_fallback_and_env_override(monkeypatch):
     costs.refresh_from_env()
 
 
+def test_unknown_device_kind_is_an_error(monkeypatch):
+    """A device_kind with no sourced row never borrows the CPU row."""
+    class _Dev:
+        device_kind = "TPU v99"
+
+    for var in ("MXNET_PEAK_FLOPS", "MXNET_PEAK_HBM_BW",
+                "MXNET_PEAK_ICI_BW"):
+        monkeypatch.delenv(var, raising=False)
+    monkeypatch.setattr(jax, "local_devices", lambda: [_Dev()])
+    costs.refresh_from_env()
+    try:
+        with pytest.raises(mx.MXNetError, match="TPU v99"):
+            costs.peaks()
+        # all three pinned from outside: the operator supplied the peaks
+        monkeypatch.setenv("MXNET_PEAK_FLOPS", "1e15")
+        monkeypatch.setenv("MXNET_PEAK_HBM_BW", "2e12")
+        monkeypatch.setenv("MXNET_PEAK_ICI_BW", "3e11")
+        costs.refresh_from_env()
+        assert costs.peaks()["flops"] == 1e15
+    finally:
+        monkeypatch.undo()
+        costs.refresh_from_env()
+
+
 def test_executor_cost_analysis_aot(tel):
     """Per-executor AOT cost: nothing executed, PRNG stream untouched."""
     data = sym.var("data")

@@ -2,7 +2,7 @@
 
 Topology construction (single device, N local devices, faked multi-host),
 the MXNET_MESH_* env selection, spec/sharding round-trips, the
-version-adaptive shard_map entry point, and the bitwise port gate: the
+shard_map entry point, and the bitwise port gate: the
 transformer train steps built through the substrate must match a plain
 ``jax.jit`` of the same math exactly — porting onto the substrate is a
 refactor, not a numerics change.  Also enforces the single-substrate

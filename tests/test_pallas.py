@@ -1,5 +1,10 @@
 """Pallas kernel tests: interpret mode on CPU vs jnp reference (SURVEY §4
-doctrine: interpret-mode Pallas ↔ compiled cross-check)."""
+doctrine: interpret-mode Pallas ↔ compiled cross-check), plus the
+no-chip half of the compiled story: the kernels must LOWER for TPU from
+this CPU sandbox with the package's x64 setting as shipped (Mosaic has no
+64-bit types; chip_smoke.py runs the compiled kernels on the chip)."""
+import functools
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -8,9 +13,6 @@ import pytest
 import mxnet_tpu  # noqa: F401
 from mxnet_tpu.ops import pallas_kernels as pk
 from mxnet_tpu.parallel.ring_attention import local_attention
-
-pytestmark = pytest.mark.skipif(not pk.HAS_PALLAS,
-                                reason="pallas unavailable")
 
 
 def _rand(b, h, s, d, seed=0):
@@ -58,3 +60,30 @@ def test_flash_sm_scale():
     out = pk.flash_attention(q, k, v, False, 0.5, 16, 16, True)
     ref = local_attention(q, k, v, sm_scale=0.5)
     assert np.allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
+
+
+def _lowers_for_tpu(fn, *specs):
+    text = jax.jit(fn).trace(*specs).lower(
+        lowering_platforms=("tpu",)).as_text()
+    assert "tpu_custom_call" in text      # Mosaic, not interpret/jnp
+    return text
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("head_dim", [64, 128])
+@pytest.mark.parametrize("seq,causal", [(128, True), (1000, True),
+                                        (1024, False)])
+def test_flash_lowers_for_tpu_with_x64_on(dtype, head_dim, seq, causal):
+    assert jax.config.jax_enable_x64      # the package's real config
+    spec = jax.ShapeDtypeStruct((1, 2, seq, head_dim), dtype)
+    _lowers_for_tpu(functools.partial(pk.flash_attention, causal=causal),
+                    spec, spec, spec)
+
+
+def test_flash_rejects_untileable_shapes():
+    q = jnp.zeros((1, 1, 256, 192), jnp.float32)
+    with pytest.raises(ValueError, match="head dim"):
+        pk.flash_attention(q, q, q, False, None, 128, 128, True)
+    q = jnp.zeros((1, 1, 512, 64), jnp.float32)
+    with pytest.raises(ValueError, match="block_k"):
+        pk.flash_attention(q, q, q, False, None, 128, 192, True)

@@ -28,6 +28,7 @@ def _scale_body(x_ref, o_ref, *, alpha):
 
 def _register_scale(name="pl_scale", **kw):
     from jax.experimental import pallas as pl
+    kw.setdefault("interpret", True)     # no TPU here: the explicit mode
 
     def pl_scale(x, alpha=2.0, interpret=False):
         return pl.pallas_call(
@@ -152,3 +153,33 @@ def test_force_over_builtin_restored_on_unregister():
     finally:
         mx.pallas.unregister("relu")
     assert OP_REGISTRY["relu"] is original
+
+
+def _registered_fn(name):
+    """The registered op as a pure function of one jax array."""
+    from mxnet_tpu.ops.registry import OP_REGISTRY
+    op = OP_REGISTRY[name]
+    return lambda x, **attrs: op.apply([x], attrs, train_mode=False,
+                                       rng=None)[0]
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_registered_kernel_lowers_for_tpu(_cleanup, dtype):
+    """The mx.pallas docstring kernel compiles with Mosaic under the
+    package's x64 setting: lowered for TPU from this CPU host (the chip
+    run of the same kernel is chip_smoke.py's kernels phase)."""
+    assert jax.config.jax_enable_x64
+    _register_scale("pl_scale_tpu", interpret=False)
+    fn = _registered_fn("pl_scale_tpu")
+    text = jax.jit(functools.partial(fn, alpha=3.0)).trace(
+        jax.ShapeDtypeStruct((8, 128), dtype)).lower(
+            lowering_platforms=("tpu",)).as_text()
+    assert "tpu_custom_call" in text
+
+
+def test_compiled_kernel_without_tpu_raises(_cleanup):
+    """interpret=False is the default and is never swapped for the
+    interpreter behind the caller's back."""
+    _register_scale("pl_scale_compiled", interpret=False)
+    with pytest.raises(Exception, match="[Ii]nterpret"):
+        nd.pl_scale_compiled(nd.ones((8, 128)))

@@ -1,0 +1,88 @@
+"""Worker for tests/test_tpu_bringup.py: compile the Pallas tier for a
+TPU v5e WITHOUT a chip.
+
+libtpu can describe a topology with no hardware behind it
+(``jax.experimental.topologies``); lowering against its devices runs the
+real Mosaic and XLA:TPU compilers.  That catches what lowering alone
+(``lower(lowering_platforms=("tpu",))``) cannot — e.g. an i64 block index
+that only Mosaic's legalizer refuses — for no chip time.  Nothing is
+executed.  Runs in its own process because it loads libtpu.
+
+Prints one ``AOT ok <what>`` line per compiled program; exit 3 means
+libtpu could not describe the topology here (the caller skips).
+"""
+import functools
+import os
+import sys
+
+os.environ["JAX_PLATFORMS"] = "cpu"
+# quiet libtpu's metadata-server probing; there is no TPU VM around us
+os.environ.setdefault("TPU_ACCELERATOR_TYPE", "v5litepod-4")
+os.environ.setdefault("TPU_WORKER_HOSTNAMES", "localhost")
+os.environ.setdefault("TPU_SKIP_MDS_QUERY", "true")
+
+sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                                ".."))
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+from jax.experimental import topologies  # noqa: E402
+from jax.sharding import NamedSharding, SingleDeviceSharding  # noqa: E402
+
+import mxnet_tpu  # noqa: E402,F401 (x64 + cache placement)
+from mxnet_tpu.models import transformer as T  # noqa: E402
+from mxnet_tpu.ops.pallas_kernels import flash_attention  # noqa: E402
+from mxnet_tpu.parallel.mesh import filter_spec, make_mesh  # noqa: E402
+
+
+def main():
+    assert jax.config.jax_enable_x64        # the package's real config
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as exc:                # no usable libtpu here
+        print("no compile-only TPU topology: %r" % (exc,))
+        sys.exit(3)
+    one = SingleDeviceSharding(topo.devices[0])
+
+    for dtype in (jnp.bfloat16, jnp.float32):
+        for seq, dim, causal in ((128, 64, True), (1000, 64, True),
+                                 (1024, 128, False), (4096, 128, True)):
+            spec = jax.ShapeDtypeStruct((1, 2, seq, dim), dtype,
+                                        sharding=one)
+            jax.jit(functools.partial(flash_attention, causal=causal)) \
+                .lower(spec, spec, spec).compile()
+            print("AOT ok flash %s S=%d D=%d causal=%s"
+                  % (jnp.dtype(dtype).name, seq, dim, causal), flush=True)
+
+    # the kernel from mx.pallas's docstring, through the op registry
+    # (same kernel and helper the interpret-mode tests use)
+    from test_pallas_register import _register_scale, _registered_fn
+    _register_scale("aot_scale", interpret=False)
+    jax.jit(functools.partial(_registered_fn("aot_scale"), alpha=3.0)) \
+        .lower(jax.ShapeDtypeStruct((8, 128), jnp.float32, sharding=one)) \
+        .compile()
+    print("AOT ok registered kernel", flush=True)
+
+    # a transformer train step through the kernel, under shard_map on a
+    # data=2 x model=2 mesh (the selector looks at the live backend,
+    # which is the CPU here: force the TPU choice)
+    T._use_flash = lambda s: s >= 128
+    lm = T.TransformerLMConfig(vocab=512, d_model=256, n_heads=4, d_ff=512,
+                               n_layers=1, max_len=1024, dtype=jnp.bfloat16)
+    mesh = make_mesh({"data": 2, "model": 2}, topo.devices)
+    params = {
+        n: jax.ShapeDtypeStruct(shape, lm.dtype, sharding=NamedSharding(
+            mesh, filter_spec(spec, mesh)))
+        for n, (shape, spec) in T._param_specs(lm).items()}
+    tokens = jax.ShapeDtypeStruct(
+        (4, lm.max_len), jnp.int32, sharding=NamedSharding(
+            mesh, filter_spec(T.P("data", "seq"), mesh)))
+    lowered = T.make_train_step(lm, mesh).lower(params, tokens, tokens)
+    assert "tpu_custom_call" in lowered.as_text()
+    lowered.compile()
+    print("AOT ok transformer step on %s" % dict(mesh.shape), flush=True)
+
+
+if __name__ == "__main__":
+    main()

@@ -34,8 +34,6 @@ os.environ["JAX_PLATFORMS"] = "cpu"
 
 import jax  # noqa: E402
 
-jax.config.update("jax_platforms", "cpu")
-
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
 import numpy as np  # noqa: E402
@@ -76,18 +74,7 @@ def main():
 
     rank, world = multihost.init_from_env()
     n = len(jax.devices())
-    mode = "global"
-    try:
-        loss, mesh = _run_step(jax.devices())
-    except Exception as exc:
-        # capability gate: CPU cross-process computations need jaxlib
-        # >= 0.5 (gloo).  Degrade to the same SPMD step per process over
-        # the local mesh — cross-process agreement still proven below
-        # via the coordination-service KV store (host tier, no XLA).
-        if "Multiprocess computations aren't implemented" not in str(exc):
-            raise
-        mode = "local-fallback"
-        loss, mesh = _run_step(jax.local_devices())
+    loss, mesh = _run_step(jax.devices())
     assert np.isfinite(loss), loss
 
     losses = multihost.host_gather_floats("dist_pjit_loss", loss)
@@ -95,8 +82,8 @@ def main():
     assert max(losses) - min(losses) < 1e-6, \
         "ranks disagree on the loss: %r" % (losses,)
     multihost.barrier("dist_pjit_done")
-    print("MULTIHOST rank=%d world=%d ndev=%d mesh=%s mode=%s loss=%.6f"
-          % (rank, world, n, dict(mesh.shape), mode, loss), flush=True)
+    print("MULTIHOST rank=%d world=%d ndev=%d mesh=%s loss=%.6f"
+          % (rank, world, n, dict(mesh.shape), loss), flush=True)
 
 
 if __name__ == "__main__":

@@ -489,13 +489,10 @@ def main(argv=None):
             "max_queue_ms": args.max_queue_ms,
             "queue_wait_over_budget": queue_over_budget,
         }
-        device = None
-        try:
-            import jax
-            device = jax.devices()[0].platform
-        except Exception:
-            pass
-        report["device"] = device
+        # where the served executables live, not what jax defaults to
+        dev = slot.program._dev
+        report["device"] = {"platform": dev.platform,
+                            "kind": dev.device_kind}
         serving.unload(MODEL)
         print(json.dumps(report))
         ok = retraces == 0 and not closed_err and not queue_over_budget
