@@ -42,21 +42,18 @@ def _timed_batch(produce):
     fetch time, inverting the starvation signal for a healthy prefetched
     pipeline.  Off path is two cached-bool checks.
     """
-    if getattr(_io_suppress, "active", False) \
-            or not (_tel.enabled() or _tel.trace_active()):
+    if getattr(_io_suppress, "active", False) or not _tel.trace_active():
         return produce()
     t0 = _tel.now_us()
     _io_suppress.active = True
     try:
-        batch = produce()
+        with _tel.span("data_batch", cat="io"):
+            batch = produce()
     finally:
         _io_suppress.active = False
-    dur = _tel.now_us() - t0
     if _tel.enabled():
         _tel.bump("io_batches")
-        _tel.set_gauge("io_batch_wait_us", dur)
-    if _tel.trace_active():
-        _tel.add_event("data_batch", "io", t0, dur)
+        _tel.set_gauge("io_batch_wait_us", _tel.now_us() - t0)
     return batch
 
 __all__ = ["DataDesc", "DataBatch", "DataIter", "ResizeIter",

@@ -12,6 +12,7 @@ import time
 
 from .. import metric as metric_mod
 from .. import ndarray as nd
+from .. import telemetry as _tel
 from ..checkpoint import hooks as _ckpt_hooks
 from ..initializer import Uniform
 from ..model import BatchEndParam
@@ -158,24 +159,36 @@ class BaseModule:
                          batch_end_callback, monitor):
         """Inner loop of one training epoch over *train_data*."""
         train_metric.reset()
+        # the iterator's next() runs between two fit_batch spans (io.py
+        # books it as data_batch), so it carries neither batch's id
         for nbatch, batch in enumerate(train_data):
-            self.prepare(batch)
-            if monitor is not None:
-                monitor.tic()
-            if monitor is None:
-                self._fit_step(batch)
-            else:
-                self.forward_backward(batch)
-                self.update()
-            self.update_metric(train_metric, batch.label)
-            if monitor is not None:
-                monitor.toc_print()
-            # step boundary (see gluon/trainer.py): checkpoint snapshot
-            # point + pending-SIGTERM honor, with the epoch cursor
-            _ckpt_hooks.note_step_boundary(epoch=epoch, batch=nbatch)
-            _fire(batch_end_callback,
-                  BatchEndParam(epoch=epoch, nbatch=nbatch,
-                                eval_metric=train_metric, locals=locals()))
+            with _tel.span("fit_batch", cat="batch",
+                           args={"epoch": epoch, "nbatch": nbatch}):
+                # the root asked whether anything records; its children
+                # here read the answer (off: one bool test each)
+                rec = _tel.trace_active()
+                self.prepare(batch)
+                if monitor is not None:
+                    monitor.tic()
+                if monitor is None:
+                    self._fit_step(batch)
+                else:
+                    self.forward_backward(batch)
+                    self.update()
+                with _tel.span("fit_update_metric", cat="host") \
+                        if rec else _tel.NO_SPAN:
+                    self.update_metric(train_metric, batch.label)
+                if monitor is not None:
+                    monitor.toc_print()
+                # step boundary (see gluon/trainer.py): checkpoint snapshot
+                # point + pending-SIGTERM honor, with the epoch cursor
+                _ckpt_hooks.note_step_boundary(epoch=epoch, batch=nbatch)
+                with _tel.span("fit_callback", cat="host") \
+                        if rec else _tel.NO_SPAN:
+                    _fire(batch_end_callback,
+                          BatchEndParam(epoch=epoch, nbatch=nbatch,
+                                        eval_metric=train_metric,
+                                        locals=locals()))
 
     def score(self, eval_data, eval_metric, num_batch=None,
               batch_end_callback=None, score_end_callback=None, reset=True,

@@ -124,12 +124,6 @@ def record_op(name, start_us, dur_us):
         _telemetry.add_event(name, "operator", start_us, dur_us)
 
 
-def record_program(name, start_us, dur_us):
-    """Called from Executor forward/backward (any mode)."""
-    if _state["running"]:
-        _telemetry.add_event(name, "program", start_us, dur_us)
-
-
 class Marker(_telemetry.span):
     """User annotation span: ``with profiler.Marker("data-load"): ...``
 

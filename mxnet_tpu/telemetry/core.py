@@ -44,13 +44,19 @@ one cached-bool check (plus, for step/program spans, the one attribute
 compare that keeps the flight recorder's progress clock ticking).  Spans
 also record whenever the classic profiler is running
 (``profiler.set_state('run')``), so existing profiler workflows keep
-working unchanged.
+working unchanged.  A third leg needs no switch of ours: while a
+JAX profiler session is open in this process, whoever opened it, spans
+record — and ONLY spans (ring + ``mxnet_tpu.<name>`` annotation in the
+session's own trace, on the device's clock); the watchdog, cost capture,
+histograms, memory sampling and time series stay as off as they were.
 
 This module is import-light on purpose (stdlib only; jax only touched
-inside memory sampling) — every hot path in the framework imports it.
+inside memory sampling and, lazily, by the profiler-session leg) — every
+hot path in the framework imports it.
 """
 from __future__ import annotations
 
+import contextlib
 import contextvars
 import json
 import logging
@@ -63,7 +69,7 @@ from collections import deque
 from . import flight as _flight
 
 __all__ = ["enabled", "set_enabled", "configure", "trace_active",
-           "span", "now_us", "add_event", "clear_events",
+           "span", "NO_SPAN", "now_us", "add_event", "clear_events",
            "Counter", "Gauge", "Histogram",
            "bump", "counter", "counters", "reset_counters",
            "set_gauge", "gauge", "observe", "histogram",
@@ -172,9 +178,53 @@ def _set_profiler_running(running):
     _PROF_RUNNING = bool(running)
 
 
+# third leg of trace_active(): a JAX profiler session is open in this
+# process (mx.profiler with jax_trace_dir, a harness's start_trace, a capture
+# from a profiler server).  The answer is cached: batch-root spans ask once
+# per batch (_poll_session), every other span reads _SESSION.
+_SESSION = False
+_session_probe = None          # TraceMe.is_enabled, bound on first poll
+_annotation_cls = None         # jax.profiler.TraceAnnotation, ditto
+_ANNOTATION_PREFIX = "mxnet_tpu."
+# batch-root spans open on this process: lets a step span nested in a
+# batch skip its own poll even when nothing records.  A plain int on
+# purpose (no lock, no contextvar on the off path); a wrong value under
+# concurrent fit loops costs one extra or one skipped poll, nothing else
+_BATCH_OPEN = 0
+
+
+def _poll_session():
+    """Ask JAX whether a profiler session is open; caches and returns the
+    answer.  Under 0.1 us (one C++ atomic load).  Binds lazily so this module
+    never imports jax; if jax was never imported nobody can have opened a
+    session, and if this jaxlib has no such call the leg is simply false."""
+    global _SESSION, _session_probe, _annotation_cls
+    probe = _session_probe
+    if probe is None:
+        if "jax" not in sys.modules:
+            return False
+        try:
+            from jax._src.lib import _profiler
+            from jax.profiler import TraceAnnotation
+            probe = _profiler.TraceMe.is_enabled
+            _annotation_cls = TraceAnnotation
+        except Exception:
+            probe = _never
+        _session_probe = probe
+    _SESSION = probe()
+    return _SESSION
+
+
+def _never():
+    return False
+
+
 def trace_active():
-    """True when spans should record trace events."""
-    return _ENABLED or _PROF_RUNNING
+    """True when spans should record trace events.  A cached "session
+    open" is re-asked here, so a session that closed since the last batch
+    root stops the recording at the next span (and no stale flag outlives
+    the session)."""
+    return _ENABLED or _PROF_RUNNING or (_SESSION and _poll_session())
 
 
 # --------------------------------------------------------------------------
@@ -195,11 +245,12 @@ _t0 = time.perf_counter()
 # trace_report's self-time sweep relies on), so the label is chosen at
 # dump time from the highest-priority category the tid hosted.
 _CAT_TRACK = {"operator": "eager-dispatch", "program": "executor",
-              "step": "train-step", "kvstore": "kvstore", "io": "data-io",
+              "step": "train-step", "batch": "train-step",
+              "host": "host-phase", "kvstore": "kvstore", "io": "data-io",
               "compile": "jit-compile", "serving": "serving",
               "rpc": "dist-rpc", "user": "user"}
-_CAT_PRIORITY = ("step", "serving", "program", "kvstore", "io",
-                 "operator", "rpc", "compile", "user")
+_CAT_PRIORITY = ("step", "batch", "serving", "program", "kvstore", "io",
+                 "operator", "rpc", "compile", "host", "user")
 
 
 def now_us():
@@ -209,6 +260,20 @@ def now_us():
 # the flight ring timestamps with this module's clock so its entries line
 # up with the Chrome trace events
 _flight.set_clock(now_us)
+
+
+# os.getpid() is a system call, and a slow one under a sandboxed kernel
+# (~6 us on the chip's host, most of what recording one event cost): read
+# it once, and again in a forked child
+_PID = os.getpid()
+
+
+def _refresh_pid():
+    global _PID
+    _PID = os.getpid()
+
+
+os.register_at_fork(after_in_child=_refresh_pid)
 
 
 def add_event(name, cat, start_us, dur_us, tid=None, args=None):
@@ -222,7 +287,7 @@ def add_event(name, cat, start_us, dur_us, tid=None, args=None):
     if tid is None:
         tid = threading.get_ident() % 10000
     ev = {"name": name, "cat": cat, "ph": "X", "ts": start_us,
-          "dur": dur_us, "pid": os.getpid(), "tid": tid}
+          "dur": dur_us, "pid": _PID, "tid": tid}
     if args:
         ev["args"] = args
     with _lock:
@@ -293,14 +358,35 @@ def new_span_id():
     return os.urandom(4).hex()
 
 
+# What a per-batch hot path enters in place of a child span when its
+# batch root found nothing recording: ``with span(<name>) if rec else
+# NO_SPAN:`` is one bool test and no allocation (a span object costs
+# ~0.5 us to build and enter even when inert).  The call site keeps its
+# span literal, so the static name gate still sees the name.
+NO_SPAN = contextlib.nullcontext()
+
+# the id minted by the batch-root span open on this context (None outside
+# one): a step span nested in a batch carries the batch's id, not its own
+_BATCH_ID = contextvars.ContextVar("mxnet_tpu_batch_id", default=None)
+
+
 class span:
     """Hierarchical timed span: ``with telemetry.span("trainer_step"): ...``
 
     Nesting is carried by a contextvar (so it survives thread-pool hops
     that copy context), and recorded two ways: structurally via
     ``args.parent``/``args.depth``, and visually via time containment on
-    the owning thread's track.  Off path (telemetry off AND profiler
-    stopped) is one bool check.
+    the owning thread's track.  While recording, the span also enters a
+    ``jax.profiler.TraceAnnotation`` named ``mxnet_tpu.<name>``: if a
+    profiler session is open the same span is in its trace, on the
+    device's clock.  Off path (telemetry off, profiler stopped, no
+    session) is one bool check.
+
+    Batch roots — ``cat="batch"`` spans, and ``cat="step"`` spans outside
+    one — ask once whether a profiler session is open; everything under
+    them reads the cached answer.  A session alone records spans and
+    nothing else (no step window, no histogram, no memory sample, no
+    time-series row, the flight ring as with telemetry off).
 
     *hist*: name of a registered histogram to observe with the span's
     duration (µs).  *memory*: sample host/device memory watermarks at span
@@ -309,7 +395,8 @@ class span:
     """
 
     __slots__ = ("_name", "_cat", "_hist", "_memory", "_args",
-                 "_on", "_t0", "_tok", "_parent", "_trace_tok")
+                 "_on", "_t0", "_tok", "_parent", "_trace_tok",
+                 "_batch_tok", "_full", "_window", "_ann")
 
     def __init__(self, name, cat="user", hist=None, memory=False, args=None):
         self._name = name
@@ -319,10 +406,21 @@ class span:
         self._args = args
 
     def __enter__(self):
-        if not trace_active():
+        global _BATCH_OPEN
+        cat = self._cat
+        # trace_active(), inlined — except that a batch root asks where
+        # everything else reads (and re-asks only a cached "open")
+        if cat == "batch":
+            _BATCH_OPEN += 1
+            session = _poll_session()
+        elif cat == "step" and not _BATCH_OPEN:
+            session = _poll_session()
+        else:
+            session = _SESSION and _poll_session()
+        if not (_ENABLED or _PROF_RUNNING or session):
             self._on = False
             self._t0 = None
-            if _DEVICE_TIME and self._cat == "step":
+            if _DEVICE_TIME and cat == "step":
                 # device-time attribution works with the trace buffer
                 # off: the window still opens so sampled programs are
                 # decomposed (the span itself records nothing)
@@ -330,23 +428,50 @@ class span:
                 self._t0 = now_us()
             return self
         self._on = True
+        # the clock starts before, and the annotation opens right after,
+        # the span's own bookkeeping (and at exit the other way round):
+        # a span's cost falls inside the span, so a parent's self time is
+        # code no child covers, not tracer overhead
+        self._t0 = now_us()
+        self._trace_tok = self._batch_tok = None
+        if cat == "batch":
+            trace_id = new_trace_id()
+            self._trace_tok = _TRACE_CTX.set(trace_id)
+            self._batch_tok = _BATCH_ID.set(trace_id)
+        elif cat == "step":
+            # one trace id per step: RPCs issued inside (kvstore push/
+            # pull over dist_ps) inherit it, so --fleet can join the
+            # step's spans across ranks.  Steps are trace ROOTS unless a
+            # batch root is open — mint regardless of the ambient id: one
+            # adopted from an earlier RPC reply (recv sets the contextvar)
+            # must not glue every step of the run into one giant trace
+            trace_id = _BATCH_ID.get() or new_trace_id()
+            self._trace_tok = _TRACE_CTX.set(trace_id)
+        else:
+            trace_id = _TRACE_CTX.get()
+        if _session_probe is None:
+            _poll_session()            # binds the annotation class
+        self._ann = None
+        if _annotation_cls is not None:
+            name = _ANNOTATION_PREFIX + self._name
+            self._ann = _annotation_cls(name) if trace_id is None \
+                else _annotation_cls(name, trace_id=trace_id)
+            self._ann.__enter__()
         stack = _SPAN_STACK.get()
         self._parent = stack[-1] if stack else None
         self._tok = _SPAN_STACK.set(stack + (self._name,))
-        self._trace_tok = None
-        if self._cat == "step":
-            # one trace id per step: RPCs issued inside (kvstore push/
-            # pull over dist_ps) inherit it, so --fleet can join the
-            # step's spans across ranks.  Steps are trace ROOTS — mint
-            # unconditionally: an ambient id adopted from an earlier
-            # RPC reply (recv sets the contextvar) must not glue every
-            # step of the run into one giant trace
-            self._trace_tok = _TRACE_CTX.set(new_trace_id())
+        # everything beyond the span itself needs one of our own switches
+        self._full = full = _ENABLED or _PROF_RUNNING
+        self._window = cat == "step" and (full or _DEVICE_TIME)
+        if self._window:
             _open_step_window()
-        self._t0 = now_us()
         return self
 
-    def __exit__(self, *exc):
+    def __exit__(self, exc_type, exc, tb):
+        global _BATCH_OPEN
+        cat = self._cat
+        if cat == "batch" and _BATCH_OPEN:
+            _BATCH_OPEN -= 1
         if not self._on:
             # telemetry off: the flight recorder's progress clock still
             # ticks for coarse spans (step/program exits are what the
@@ -357,10 +482,9 @@ class span:
             # depth the matching open incremented
             if self._t0 is not None:
                 _close_step_window(now_us() - self._t0)
-            if self._cat in ("step", "program"):
-                _flight.note_span(self._name, self._cat)
+            if cat in ("step", "program"):
+                _flight.note_span(self._name, cat)
             return False
-        dur = now_us() - self._t0
         _SPAN_STACK.reset(self._tok)
         args = {"parent": self._parent,
                 "depth": len(_SPAN_STACK.get())}
@@ -369,12 +493,20 @@ class span:
             args["trace_id"] = trace_id
         if self._args:
             args.update(self._args)
-        add_event(self._name, self._cat, self._t0, dur, args=args)
-        _flight.note_span(self._name, self._cat, dur)
-        if self._cat == "step":
+        dur = now_us() - self._t0
+        add_event(self._name, cat, self._t0, dur, args=args)
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+        if self._full:
+            _flight.note_span(self._name, cat, dur)
+        elif cat in ("step", "program"):
+            _flight.note_span(self._name, cat)
+        if self._window:
             _close_step_window(dur)
-            if self._trace_tok is not None:
-                reset_trace_context(self._trace_tok)
+        if self._trace_tok is not None:
+            reset_trace_context(self._trace_tok)
+        if self._batch_tok is not None:
+            _BATCH_ID.reset(self._batch_tok)
         if self._hist is not None and _ENABLED:
             observe(self._hist, dur)
         if self._memory and _ENABLED:
@@ -644,8 +776,26 @@ HISTOGRAMS = {
 SPANS = {
     "trainer_step": "one Trainer.step (the step-timeline anchor)",
     "data_batch": "one data-iterator batch production (io tier)",
-    "module_train_step": "one Module cached train step",
-    "module_step_program": "the module step's fused program call",
+    "fit_batch": "one Module.fit batch, prepare to the batch-end "
+                 "callback's return (batch root: mints the batch's id)",
+    "fit_update_metric": "the fit loop's metric update for one batch",
+    "fit_callback": "the fit loop's batch-end callbacks for one batch",
+    "metric_wait": "a metric blocked until a device array is ready "
+                   "(the device is still busy: overlap)",
+    "metric_fetch": "a metric's device-to-host copy of a ready array",
+    "module_train_step": "one Module cached train step (host side)",
+    "module_step_feed": "module step: batch into the executor's arg_dict",
+    "module_step_place_batch": "module step: data/label onto the "
+                               "executor's device(s)",
+    "module_step_hyper": "module step: optimizer state check, lr/wd/"
+                         "update-count bookkeeping",
+    "module_step_place_params": "module step: placement checks of "
+                                "params, aux and optimizer state",
+    "module_step_rng": "module step: the per-step PRNG key programs",
+    "module_step_enqueue": "module step: the fused program's call "
+                           "(flatten, hyper transfer, launch)",
+    "module_step_writeback": "module step: new params/aux/state and "
+                             "outputs back into their handles",
     "kvstore_push_pull": "gradient reduce round inside a step",
     "kvstore_bucket_reduce": "one bucketed reduce program (also a "
                              "counter)",
@@ -1274,7 +1424,7 @@ def dump_snapshot(filename):
 
 def reset():
     """Clear events, metrics, and watchdog state (tests / new session)."""
-    global _STEP_WINDOW, _STEP_DEPTH
+    global _STEP_WINDOW, _STEP_DEPTH, _SESSION, _BATCH_OPEN
     clear_events()
     reset_counters()
     with _mlock:
@@ -1287,6 +1437,8 @@ def reset():
     _PROGRAM_COSTS.clear()
     _STEP_WINDOW = None
     _STEP_DEPTH = 0
+    _SESSION = False
+    _BATCH_OPEN = 0
     dev = sys.modules.get("mxnet_tpu.telemetry.device")
     if dev is not None:
         dev.reset()
