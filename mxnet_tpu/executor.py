@@ -273,7 +273,14 @@ class Executor:
         return self._outputs
 
     def _place_rng(self, key):
-        """Hook: sharded executors re-place the PRNG key on their mesh."""
+        """Hook: the PRNG key where this executor's programs run (sharded
+        executors re-place it on their mesh).  A key drawn from
+        ``random.next_key()`` is uncommitted and follows the other
+        arguments; the root key lent by ``random.lend_root_key()`` may be
+        the output of a fused step bound elsewhere, committed there."""
+        dev = self._ctx.jax_device
+        if getattr(key, "committed", False) and key.devices() != {dev}:
+            key = jax.device_put(key, dev)
         return key
 
     def cost_analysis(self):

@@ -8,15 +8,19 @@ is reproducible given a seed, and jitted graphs thread keys explicitly.
 """
 from __future__ import annotations
 
+import contextlib
 import threading
+import types
 
 import jax
 import numpy as np
 
-__all__ = ["seed", "next_key", "current_seed", "key_scope", "host_rng",
-           "get_state", "set_state"]
+__all__ = ["seed", "next_key", "lend_root_key", "current_seed", "key_scope",
+           "host_rng", "get_state", "set_state"]
 
-_lock = threading.Lock()
+# re-entrant: lend_root_key holds it while its borrower traces and launches
+# a program, and a next_key() from that same thread must not deadlock
+_lock = threading.RLock()
 _seed = 0
 _key = None  # lazily created: backend init must not run at import time
 _host_rng = None  # np.random.Generator once seeded (host-side draws)
@@ -57,8 +61,41 @@ def next_key():
     with _lock:
         if _key is None:
             _key = jax.random.PRNGKey(_seed)
+        elif _key.committed:
+            # handed back by a lend_root_key borrower: an output of its
+            # program, committed to that program's device or replicated
+            # over its mesh.  Keys drawn here go to any device, as the
+            # uncommitted ones of PRNGKey/split always have, so bring the
+            # root home once, here, and not at every consumer
+            _key = jax.numpy.asarray(np.asarray(_key))
         _key, sub = jax.random.split(_key)
         return sub
+
+
+@contextlib.contextmanager
+def lend_root_key():
+    """Lend the stream's root key to a program that advances it itself.
+
+    ``with lend_root_key() as loan:`` holds the stream (every other
+    ``next_key()`` waits).  The borrower passes ``loan.key`` into its
+    compiled program, which does in its trace what :func:`next_key` does
+    on the host — ``new_root, sub = jax.random.split(root)`` — and returns
+    ``new_root``; the borrower stores that in ``loan.key`` before the block
+    ends.  The stream is then bit-for-bit where one ``next_key()`` would
+    have left it, and the host launched nothing.  If the block raises,
+    the stream has not moved.
+
+    The key handed out is the one stored: fresh from ``seed``/``next_key``,
+    or the previous borrower's output, still on that program's devices —
+    the borrower places it if it is not already its own.
+    """
+    global _key
+    with _lock:
+        if _key is None:
+            _key = jax.random.PRNGKey(_seed)
+        loan = types.SimpleNamespace(key=_key)
+        yield loan
+        _key = loan.key
 
 
 class key_scope:

@@ -540,6 +540,9 @@ COUNTERS = {
     "optimizer_update": "eager per-slot optimizer updates",
     "trainer_fused_step": "fused whole-model Trainer steps",
     "module_train_step": "Module CachedTrainStep executions",
+    "module_step_carried": "CachedTrainStep executions that took every "
+                           "param, aux and optimizer-state input by "
+                           "identity from the previous step (no _place)",
     "eager_invocations": "eager op dispatches through ndarray.invoke",
     "io_batches": "data batches produced by iterators",
     "jit_compiles": "watched-jit cache misses (traces+compiles)",
@@ -789,9 +792,12 @@ SPANS = {
                                "executor's device(s)",
     "module_step_hyper": "module step: optimizer state check, lr/wd/"
                          "update-count bookkeeping",
-    "module_step_place_params": "module step: placement checks of "
-                                "params, aux and optimizer state",
-    "module_step_rng": "module step: the per-step PRNG key programs",
+    "module_step_place_params": "module step: identity checks of "
+                                "params, aux and optimizer state against "
+                                "the previous step's outputs; _place of "
+                                "any that are not",
+    "module_step_rng": "module step: random's root key taken on loan "
+                       "(placed if it is not the previous step's)",
     "module_step_enqueue": "module step: the fused program's call "
                            "(flatten, hyper transfer, launch)",
     "module_step_writeback": "module step: new params/aux/state and "
