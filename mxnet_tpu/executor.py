@@ -52,6 +52,89 @@ from .symbol.symbol import Symbol, _topo
 __all__ = ["Executor"]
 
 
+class _LazyZeros(NDArray):
+    """A gradient buffer that is zeros until something reads it.  The
+    executor hands one out per grad-bearing argument at bind; a training
+    path that never reads them (the fused Module step consumes gradients
+    inside its program) then holds no device memory for them — at 6 bytes
+    a parameter they are a third of a large model's state."""
+    __slots__ = ("_spec", "_buf")
+
+    def __init__(self, shape, ctx, dtype):
+        self._spec = (tuple(shape), np.dtype(dtype))
+        self._buf = None
+        super().__init__(None, ctx)
+
+    @property
+    def _data(self):
+        if self._buf is None:
+            self._buf = nd_zeros(self._spec[0], ctx=self._ctx,
+                                 dtype=self._spec[1])._data
+        return self._buf
+
+    @_data.setter
+    def _data(self, value):
+        self._buf = value
+
+    @property
+    def shape(self):
+        return self._spec[0] if self._buf is None else tuple(self._buf.shape)
+
+    @property
+    def dtype(self):
+        return self._spec[1] if self._buf is None \
+            else np.dtype(self._buf.dtype)
+
+
+def _mirror_stage(node):
+    """The recomputation segment an op node belongs to, or None.  A node
+    is marked by the reference's own attributes, set through
+    ``AttrScope(force_mirroring="True", mirror_stage="<k>")``: nodes that
+    share a ``mirror_stage`` form one segment."""
+    if node.op is None or str(node.attrs.get(
+            "__force_mirroring__", "")).lower() not in ("true", "1"):
+        return None
+    return str(node.attrs.get("__mirror_stage__", ""))
+
+
+def _execution_units(nodes, heads):
+    """Op nodes in execution order, a recomputation segment's nodes as one
+    unit: [(stage or None, [nodes])].  Without segments this is the
+    topological order itself.  A segment has to be convex: no path may
+    leave it and come back."""
+    stage_of = {id(n): _mirror_stage(n) for n in nodes}
+    members = {}
+    for n in nodes:
+        if stage_of[id(n)] is not None:
+            members.setdefault(stage_of[id(n)], []).append(n)
+    if not members:
+        return [(None, [n]) for n in nodes if n.op is not None]
+    units, state = [], {}
+
+    def visit(node):
+        stage = stage_of[id(node)]
+        key = ("stage", stage) if stage is not None else id(node)
+        if state.get(key) == "done":
+            return
+        if state.get(key) == "open":
+            raise MXNetError(
+                "recomputation segment %r is not convex: a path leaves it "
+                "and comes back (node %s)" % (stage, node.name))
+        state[key] = "open"
+        group = members[stage] if stage is not None else [node]
+        for member in group:
+            for src, _ in member.inputs:
+                if stage is None or stage_of[id(src)] != stage:
+                    visit(src)
+        state[key] = "done"
+        if node.op is not None:
+            units.append((stage, group))
+
+    for node, _ in heads:
+        visit(node)
+    return units
+
+
 def _build_graph_fn(symbol, train_mode):
     """Build pure fn(arg_vals, aux_vals, rng) -> (outputs, new_aux)."""
     nodes = _topo(symbol._outputs)
@@ -74,6 +157,55 @@ def _build_graph_fn(symbol, train_mode):
                     aux_update_src[id(src)] = (node, out_idx)
 
     heads = list(symbol._outputs)
+    # recomputation matters to a backward pass only: the inference
+    # program is the plain walk
+    units = _execution_units(nodes, heads) if train_mode else \
+        [(None, [n]) for n in nodes if n.op is not None]
+    segments = [u for u in units if u[0] is not None]
+    if segments:
+        _telemetry.bump("executor_remat_segments", len(segments))
+    final = [(id(n), oi) for n, oi in heads] + \
+        [(id(n), oi) for n, oi in aux_update_src.values()]
+
+    def segment_plan(group):
+        """(reads, writes): the values a segment takes from outside it and
+        those of its own that something outside it, or the graph's end,
+        takes."""
+        inside = {id(n) for n in group}
+        reads = dict.fromkeys((id(s), oi) for n in group
+                              for s, oi in n.inputs if id(s) not in inside)
+        taken = [(id(s), oi) for n in nodes if id(n) not in inside
+                 for s, oi in n.inputs] + final
+        writes = dict.fromkeys(k for k in taken if k[0] in inside)
+        return list(reads), list(writes)
+
+    plans = {stage: segment_plan(group) for stage, group in segments}
+
+    def run_node(node, env, keys):
+        ins = [env[(id(s), oi)] for s, oi in node.inputs]
+        key = keys[rng_pos[id(node)]] if node.op.needs_rng else None
+        fn = node.op.traceable(node.attrs, train_mode=train_mode, rng=key)
+        outs = fn(*ins)
+        if not isinstance(outs, tuple):
+            outs = (outs,)
+        for i, o in enumerate(outs):
+            env[(id(node), i)] = o
+
+    def run_segment(stage, group, env, keys):
+        """The segment's forward under ``jax.checkpoint``: what it reads
+        from outside is saved, everything inside is computed again in
+        the backward pass."""
+        reads, writes = plans[stage]
+
+        def forward(vals, keys):
+            local = dict(zip(reads, vals))
+            with jax.named_scope("remat_segment_%s" % stage):
+                for n in group:
+                    run_node(n, local, keys)
+            return tuple(local[k] for k in writes)
+
+        outs = jax.checkpoint(forward)(tuple(env[k] for k in reads), keys)
+        env.update(zip(writes, outs))
 
     def graph_fn(arg_vals, aux_vals, rng):
         env = {}
@@ -83,17 +215,11 @@ def _build_graph_fn(symbol, train_mode):
             env[(id(n), 0)] = aux_vals[aux_pos[id(n)]]
         keys = (jax.random.split(rng, len(rng_nodes))
                 if rng_nodes else None)
-        for node in nodes:
-            if node.op is None:
-                continue
-            ins = [env[(id(s), oi)] for s, oi in node.inputs]
-            key = keys[rng_pos[id(node)]] if node.op.needs_rng else None
-            fn = node.op.traceable(node.attrs, train_mode=train_mode, rng=key)
-            outs = fn(*ins)
-            if not isinstance(outs, tuple):
-                outs = (outs,)
-            for i, o in enumerate(outs):
-                env[(id(node), i)] = o
+        for stage, group in units:
+            if stage is None:
+                run_node(group[0], env, keys)
+            else:
+                run_segment(stage, group, env, keys)
         outputs = tuple(env[(id(n), oi)] for n, oi in heads)
         new_aux = tuple(
             env[(id(aux_update_src[id(n)][0]), aux_update_src[id(n)][1])]
@@ -130,9 +256,9 @@ class Executor:
         self.grad_dict = {n: (grad_dict or {}).get(n) for n in self.arg_names}
         for n, req in self.grad_req.items():
             if req != "null" and self.grad_dict[n] is None:
-                self.grad_dict[n] = nd_zeros(self.arg_dict[n].shape,
-                                             ctx=self._ctx,
-                                             dtype=self.arg_dict[n].dtype)
+                self.grad_dict[n] = _LazyZeros(self.arg_dict[n].shape,
+                                               self._ctx,
+                                               self.arg_dict[n].dtype)
         self._grad_names = [n for n in self.arg_names
                             if self.grad_req[n] != "null"]
 
@@ -431,6 +557,13 @@ class Executor:
         else:
             (in_grads,) = self._vjp(grads_in)
         self._write_grads(in_grads)
+
+    def release_grads(self):
+        """Free the gradient buffers this executor allocated; they read
+        as zeros again until a backward pass writes them."""
+        for grad in self.grad_dict.values():
+            if isinstance(grad, _LazyZeros):
+                grad._buf = None
 
     def _write_grads(self, in_grads):
         for n, g in zip(self._grad_names, in_grads):

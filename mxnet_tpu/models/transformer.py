@@ -105,8 +105,8 @@ def init_transformer_params(key, cfg, mesh=None):
 
 
 def _rmsnorm(x, scale):
-    var = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=-1, keepdims=True)
-    return (x * jax.lax.rsqrt(var + 1e-6)).astype(x.dtype) * scale
+    from ..ops.lm import rms_norm       # the registered RMSNorm's own
+    return rms_norm(x, scale, axis=-1, eps=1e-6)
 
 
 def transformer_forward(params, tokens, cfg, mesh=None, seq_axis="seq"):

@@ -156,12 +156,16 @@ class DataParallelExecutorGroup:
                                 allow_extra_params=allow_extra)
 
     def get_params(self, arg_params, aux_params):
+        def mean(block):
+            # one copy needs no host arithmetic (slow in bfloat16)
+            if len(block) == 1:
+                return block[0].asnumpy()
+            return sum(w.asnumpy() for w in block) / len(block)
+
         for name, block in zip(self.param_names, self.param_arrays):
-            weight = sum(w.asnumpy() for w in block) / len(block)
-            arg_params[name] = nd.array(weight, dtype=block[0].dtype)
+            arg_params[name] = nd.array(mean(block), dtype=block[0].dtype)
         for name, block in zip(self.aux_names, self.aux_arrays):
-            weight = sum(w.asnumpy() for w in block) / len(block)
-            aux_params[name] = nd.array(weight, dtype=block[0].dtype)
+            aux_params[name] = nd.array(mean(block), dtype=block[0].dtype)
 
     # -- execution ---------------------------------------------------------
     def _load_data(self, batch):

@@ -1,0 +1,160 @@
+"""Language-model ops: RMSNorm, rotary embedding, power retention and a
+blocked softmax-cross-entropy head.
+
+No reference counterpart: the reference's op corpus predates all four.
+They are registered like every other op so that a language model is a
+``Symbol`` and trains through ``Module.fit``.  Every op accumulates in
+float32 whatever its operands' dtype (``docs/LM_OPS.md`` has the
+equations).
+"""
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+
+from .. import telemetry as _tel
+from .registry import register
+
+
+def rms_norm(x, gamma, axis=-1, eps=1e-6):
+    """x / sqrt(mean(x^2) + eps) in float32, cast back, times gamma."""
+    var = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=axis,
+                   keepdims=True)
+    shape = [1] * x.ndim
+    shape[axis % x.ndim] = x.shape[axis % x.ndim]
+    return (x * jax.lax.rsqrt(var + eps)).astype(x.dtype) * \
+        gamma.reshape(shape)
+
+
+@register("RMSNorm")
+def _rms_norm(data, gamma, axis=-1, eps=1e-6, **kw):
+    """Root-mean-square normalisation over ``axis`` with a learned gain."""
+    return rms_norm(data, gamma, int(axis), float(eps))
+
+
+@register("_contrib_RotaryEmbedding", aliases=["RotaryEmbedding"])
+def _rotary_embedding(data, base=10000.0, offset=0, **kw):
+    """Rotary position embedding, rotate-half convention, on
+    [batch, seq, heads, dim]: position p = offset + index along axis 1,
+    angle p * base^(-2i/dim) for the pair (i, i + dim/2)."""
+    s, dim = data.shape[1], data.shape[-1]
+    half = dim // 2
+    inv = float(base) ** (-jnp.arange(half, dtype=jnp.float32) * 2.0 / dim)
+    ang = (jnp.arange(s, dtype=jnp.float32) + float(offset))[:, None] * inv
+    cos = jnp.cos(ang)[None, :, None, :]
+    sin = jnp.sin(ang)[None, :, None, :]
+    x = data.astype(jnp.float32)
+    x1, x2 = x[..., :half], x[..., half:]
+    out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                          axis=-1)
+    return out.astype(data.dtype)
+
+
+@register("_contrib_PowerRetention", aliases=["PowerRetention"])
+def _power_retention(query, key, value, log_gate, degree=2, chunk=128,
+                     eps=1e-6, **kw):
+    """Causal power retention in the chunked state form: query
+    [B, S, Hq, d], key/value [B, S, Hkv, d], log_gate [B, S, Hkv] (the
+    float32 log of a gate in (0, 1]) -> [B, S, Hq, d].  The forward is
+    the Pallas kernel on a TPU (head size and chunk multiples of 128)
+    and the same algorithm in ``jnp`` elsewhere."""
+    from .pallas_kernels import power_retention
+    if int(degree) != 2:
+        raise ValueError("_contrib_PowerRetention: degree %s is not "
+                         "implemented (2 is)" % degree)
+    chunk = int(chunk)
+    kernel = jax.default_backend() == "tpu" and chunk % 128 == 0 and \
+        query.shape[-1] % 128 == 0 and value.shape[-1] % 128 == 0
+    _tel.bump("power_retention_traced")
+    _tel.bump("power_retention_chunks", -(-query.shape[1] // chunk))
+    return power_retention(query, key, value, log_gate.astype(jnp.float32),
+                           chunk, float(eps), kernel)
+
+
+def _head_blocks(data, label, block):
+    """Tokens flattened and padded to whole blocks: hidden states
+    [n, block, H], labels [n, block] (-1 on the padding)."""
+    h = data.reshape(-1, data.shape[-1])
+    y = label.reshape(-1).astype(jnp.int32)
+    n = -(-h.shape[0] // block)
+    pad = n * block - h.shape[0]
+    if pad:
+        h = jnp.pad(h, [(0, pad), (0, 0)])
+        y = jnp.pad(y, [(0, pad)], constant_values=-1)
+    return h.reshape(n, block, -1), y.reshape(n, block), h.shape[0] - pad
+
+
+def _block_logits(h, weight):
+    return jax.lax.dot_general(h, weight, (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _blocked_ce_value(data, weight, label, block):
+    hs, ys, count = _head_blocks(data, label, block)
+
+    def body(total, xs):
+        h, y = xs
+        logits = _block_logits(h, weight)
+        lse = jax.nn.logsumexp(logits, axis=-1)
+        picked = jnp.take_along_axis(logits, jnp.maximum(y, 0)[:, None],
+                                     axis=-1)[:, 0]
+        return total + jnp.sum(jnp.where(y >= 0, lse - picked, 0.0)), None
+
+    with jax.named_scope("lm_head_loss"):
+        total, _ = jax.lax.scan(body, jnp.zeros((), jnp.float32), (hs, ys))
+    return (total / count).reshape(1)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def blocked_softmax_ce(data, weight, label, block):
+    """Mean next-token negative log-likelihood of softmax(data weight^T)
+    at ``label``, one block of tokens at a time: no [tokens, classes]
+    tensor is ever whole, forward or backward."""
+    return _blocked_ce_value(data, weight, label, block)
+
+
+def _blocked_ce_fwd(data, weight, label, block):
+    return _blocked_ce_value(data, weight, label, block), \
+        (data, weight, label)
+
+
+def _blocked_ce_bwd(block, res, g):
+    data, weight, label = res
+    hs, ys, count = _head_blocks(data, label, block)
+    scale = g.reshape(()).astype(jnp.float32) / count
+
+    def body(dw, xs):
+        h, y = xs
+        logits = _block_logits(h, weight)
+        p = jax.nn.softmax(logits, axis=-1)
+        hit = jax.lax.broadcasted_iota(jnp.int32, p.shape, 1) == y[:, None]
+        dlogits = ((p - hit) * jnp.where(y >= 0, scale, 0.0)[:, None]) \
+            .astype(h.dtype)
+        dh = jnp.dot(dlogits, weight, preferred_element_type=jnp.float32)
+        dw = dw + jax.lax.dot_general(
+            dlogits, h, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        return dw, dh.astype(h.dtype)
+
+    with jax.named_scope("lm_head_loss"):
+        dw, dh = jax.lax.scan(body, jnp.zeros(weight.shape, jnp.float32),
+                              (hs, ys))
+    dh = dh.reshape(-1, dh.shape[-1])[:count].reshape(data.shape)
+    return dh, dw.astype(weight.dtype), jnp.zeros_like(label)
+
+
+blocked_softmax_ce.defvjp(_blocked_ce_fwd, _blocked_ce_bwd)
+
+
+@register("_contrib_BlockedSoftmaxCE", aliases=["BlockedSoftmaxCE"],
+          nondiff_inputs=(2,))
+def _blocked_softmax_ce(data, weight, label, num_hidden=None, block=2048,
+                        **kw):
+    """Language-model head and loss in one op: hidden states [.., H],
+    head weight [classes, H], integer labels [..] (as the label dtype the
+    Module feeds) -> the mean negative log-likelihood, shape (1,), float32.
+    Logits, log-softmax and their gradients are computed ``block`` tokens
+    at a time."""
+    return blocked_softmax_ce(data, weight, label, int(block))

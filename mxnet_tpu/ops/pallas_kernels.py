@@ -34,7 +34,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["flash_attention"]
+__all__ = ["flash_attention", "power_retention"]
 
 _NEG = np.float32(-1e30)
 _TINY = np.float32(1e-30)
@@ -236,3 +236,290 @@ def _bwd(causal, sm_scale, block_q, block_k, interpret, res, do):
 
 
 flash_attention.defvjp(_fwd, _bwd)
+
+
+# --- power retention --------------------------------------------------------
+# o_t = sum_{s<=t} w_ts v_s / (sum_{s<=t} w_ts + eps),
+# w_ts = (scale q_t.k_s)^2 exp(c_t - c_s), c the running sum of the log-gate.
+# The chunked state form carries, per key/value head, S = sum_s decay
+# phi(k_s) v_s^T and z = sum_s decay phi(k_s) with phi(u) = vec(u u^T), so
+# that phi(q).phi(k) = (q.k)^2.  Both are held in the redundant d x d form
+# (twice the d(d+1)/2 distinct entries): S as [i, v, j], z as the matrix
+# Z[i, j] = sum_s decay k_si k_sj, which turns phi(q).z into rowsum((q Z) q).
+
+def _retention_chunk(S, Z, q, k, v, a, *, scale, eps):
+    """One chunk of one key/value head in ``jnp``: state at the chunk's
+    start -> (state at its end, outputs).  S [d, dv, d] and Z [d, d] are
+    float32; q [G, C, d], k [C, d], v [C, dv] keep their dtype as matmul
+    operands with float32 accumulation; a [C] is the float32 log-gate."""
+    f32, cd = jnp.float32, q.dtype
+    prec = jax.lax.Precision.HIGHEST if cd == jnp.float32 else None
+    ein = functools.partial(jnp.einsum, precision=prec,
+                            preferred_element_type=f32)
+    c = jnp.cumsum(a.astype(f32))
+    t = jnp.arange(c.shape[0])
+    diff = jnp.where(t[:, None] >= t[None, :], c[:, None] - c[None, :],
+                     -jnp.inf)
+    s = ein("gtd,sd->gts", q, k) * scale
+    w = s * s * jnp.exp(diff)
+    num = ein("gts,sv->gtv", w.astype(cd), v)
+    den = jnp.sum(w, axis=-1)
+    # what the state at the chunk's start adds, decayed to each token
+    reach = (scale * scale) * jnp.exp(c)
+    pq = q[..., :, None] * q[..., None, :]
+    num = num + ein("gtij,ivj->gtv", pq, S.astype(cd)) * reach[:, None]
+    den = den + jnp.sum(ein("gti,ij->gtj", q, Z.astype(cd)) * q.astype(f32),
+                        axis=-1) * reach
+    o = (num / (den + eps)[..., None]).astype(cd)
+    # advance the state once a chunk
+    to_end = jnp.exp(c[-1] - c)
+    pk = k[:, :, None] * k[:, None, :]
+    vd = (v.astype(f32) * to_end[:, None]).astype(cd)
+    kd = (k.astype(f32) * to_end[:, None]).astype(cd)
+    S_new = jnp.exp(c[-1]) * S + ein("sij,sv->ivj", pk, vd)
+    Z_new = jnp.exp(c[-1]) * Z + ein("si,sj->ij", kd, k)
+    return S_new, Z_new, o
+
+
+def _retention_heads(q, k, v, a, chunk):
+    """[B, S, H*, d] operands -> per key/value head, chunked:
+    q [B*Hkv, n, G, C, d], k/v [B*Hkv, n, C, d], a [B*Hkv, n, C]; the tail
+    is padded with zero keys, values and log-gates, which come after every
+    real token and so reach none."""
+    b, s, hq, d = q.shape
+    hkv = k.shape[2]
+    n = -(-s // chunk)
+    pad = n * chunk - s
+
+    def lay(x):             # [B, S, Hkv, .., last] -> [B*Hkv, n, .., C, last]
+        if pad:
+            x = jnp.pad(x, [(0, 0), (0, pad)] + [(0, 0)] * (x.ndim - 2))
+        x = x.reshape((b, n, chunk) + x.shape[2:])
+        x = jnp.moveaxis(x, 2, -2)          # [B, n, Hkv, .., C, last]
+        x = jnp.moveaxis(x, 1, 2)
+        return x.reshape((b * hkv,) + x.shape[2:])
+
+    return (lay(q.reshape(b, s, hkv, hq // hkv, d)), lay(k), lay(v),
+            lay(a[..., None])[..., 0])
+
+
+def _retention_unlay(o, b, s):
+    """[B*Hkv, n, G, C, dv] -> [B, S, Hq, dv]."""
+    bh, n, g, c, dv = o.shape
+    o = o.reshape(b, bh // b, n, g, c, dv)
+    o = jnp.transpose(o, (0, 2, 4, 1, 3, 5))
+    return o.reshape(b, n * c, (bh // b) * g, dv)[:, :s]
+
+
+def _retention_scan(qh, kh, vh, ah, scale, eps):
+    """The chunked state form in ``jnp``: scan over the chunks of every
+    head at once.  Returns outputs and the state at each chunk's start
+    (in the operands' dtype: the backward's matmul operands)."""
+    bh, n, g, c, d = qh.shape
+    dv = vh.shape[-1]
+    step = jax.vmap(functools.partial(_retention_chunk, scale=scale,
+                                      eps=eps))
+
+    def body(carry, xs):
+        S, Z = carry
+        S1, Z1, o = step(S, Z, *xs)
+        return (S1, Z1), (o, S.astype(qh.dtype), Z.astype(qh.dtype))
+
+    init = (jnp.zeros((bh, d, dv, d), jnp.float32),
+            jnp.zeros((bh, d, d), jnp.float32))
+    xs = tuple(jnp.moveaxis(x, 1, 0) for x in (qh, kh, vh, ah))
+    _, (o, S0, Z0) = jax.lax.scan(body, init, xs)
+    return tuple(jnp.moveaxis(x, 0, 1) for x in (o, S0, Z0))
+
+
+def _retention_kernel(qT_ref, k_ref, kT_ref, vT_ref, crow_ref, ccol_ref,
+                      oT_ref, s0_ref, z0_ref,
+                      st_ref, z_ref, qf_ref, kf_ref, acc_ref, *,
+                      scale, eps, groups):
+    """One (head, chunk) program, feature-major: tokens lie on the lanes,
+    so a slab of phi is a sublane-broadcast multiply and the loop over
+    the d slabs indexes rows.  The state (st/z scratch) carries across
+    the chunk axis, the innermost, sequential one."""
+    f32, cd = jnp.float32, qT_ref.dtype
+    precision = jax.lax.Precision.HIGHEST if cd == jnp.float32 else None
+    dot = functools.partial(jax.lax.dot_general, precision=precision,
+                            preferred_element_type=f32)
+    nn = (((1,), (0,)), ((), ()))
+    nt = (((1,), (1,)), ((), ()))
+    d, c = kT_ref.shape
+
+    @pl.when(pl.program_id(1) == 0)
+    def _init():
+        st_ref[:] = jnp.zeros_like(st_ref)
+        z_ref[:] = jnp.zeros_like(z_ref)
+
+    crow = crow_ref[:]                      # [1, C] running log-gate
+    ccol = ccol_ref[:]                      # [C, 1]
+    c_end = crow[:, c - 1:c]                # [1, 1]
+    reach = np.float32(scale * scale) * jnp.exp(crow)
+    to_end = jnp.exp(c_end - crow)
+    grow = jnp.exp(c_end)
+    s_pos = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
+    t_pos = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
+    decay = jnp.exp(jnp.where(t_pos >= s_pos, crow - ccol, _NEG))   # [s, t]
+    z0_ref[:] = z_ref[:].astype(z0_ref.dtype)
+    z_cd = z_ref[:].astype(cd)
+    qf_ref[:] = qT_ref[:].astype(f32)
+    kf_ref[:] = kT_ref[:].astype(f32)
+
+    # within the chunk: the quadratic form over its own tokens
+    dens = []
+    for g in range(groups):
+        s = dot(k_ref[:], qT_ref[g], nn) * np.float32(scale)        # [s, t]
+        w = s * s * decay
+        acc_ref[g] = dot(vT_ref[:], w.astype(cd), nn)               # [dv, t]
+        zq = dot(z_cd, qT_ref[g], nn)                               # [i, t]
+        dens.append(jnp.sum(w, axis=0, keepdims=True) + reach *
+                    jnp.sum(zq * qf_ref[g], axis=0, keepdims=True))
+    vd = (vT_ref[:].astype(f32) * to_end).astype(cd)                # [dv, s]
+    kd = (kf_ref[:] * to_end).astype(cd)
+    z_ref[:] = z_ref[:] * grow + dot(kd, kT_ref[:], nt)
+
+    # the state: query slab i for every head, then advance slab i
+    def slab(i, carry):
+        s_i = st_ref[i]                                             # [dv, j]
+        s0_ref[i] = s_i.astype(s0_ref.dtype)
+        s_cd = s_i.astype(cd)
+        for g in range(groups):
+            pq = (qf_ref[g] * qf_ref[g, pl.ds(i, 1), :]).astype(cd)  # [j, t]
+            acc_ref[g] += dot(s_cd, pq, nn) * reach
+        pk = (kf_ref[:] * kf_ref[pl.ds(i, 1), :]).astype(cd)        # [j, s]
+        st_ref[i] = s_i * grow + dot(vd, pk, nt)
+        return carry
+
+    jax.lax.fori_loop(np.int32(0), np.int32(d), slab, np.int32(0))
+    for g in range(groups):
+        oT_ref[g] = (acc_ref[g] / (dens[g] + np.float32(eps))) \
+            .astype(oT_ref.dtype)
+
+
+def _retention_pallas(qh, kh, vh, ah, scale, eps, interpret):
+    """The forward in Pallas.  Same operands and results as
+    ``_retention_scan``; grid (head, chunk), the chunk axis sequential."""
+    bh, n, g, c, d = qh.shape
+    dv = vh.shape[-1]
+    if c % _LANES or d % _LANES or dv % _LANES:
+        raise ValueError("power_retention kernel: chunk %d and head sizes "
+                         "%d/%d must be multiples of %d"
+                         % (c, d, dv, _LANES))
+    cs = jnp.cumsum(ah.astype(jnp.float32), axis=-1)        # [bh, n, C]
+    zero = np.int32(0)
+    kernel = functools.partial(_retention_kernel, scale=scale, eps=eps,
+                               groups=g)
+    oT, S0, Z0 = pl.pallas_call(
+        kernel,
+        grid=(bh, n),
+        in_specs=[
+            pl.BlockSpec((None, None, g, d, c),
+                         lambda h, j: (h, j, zero, zero, zero)),
+            pl.BlockSpec((None, None, c, d), lambda h, j: (h, j, zero, zero)),
+            pl.BlockSpec((None, None, d, c), lambda h, j: (h, j, zero, zero)),
+            pl.BlockSpec((None, None, dv, c), lambda h, j: (h, j, zero, zero)),
+            pl.BlockSpec((None, None, 1, c), lambda h, j: (h, j, zero, zero)),
+            pl.BlockSpec((None, None, c, 1), lambda h, j: (h, j, zero, zero)),
+        ],
+        out_specs=[
+            pl.BlockSpec((None, None, g, dv, c),
+                         lambda h, j: (h, j, zero, zero, zero)),
+            pl.BlockSpec((None, None, d, dv, d),
+                         lambda h, j: (h, j, zero, zero, zero)),
+            pl.BlockSpec((None, None, d, d), lambda h, j: (h, j, zero, zero)),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((bh, n, g, dv, c), qh.dtype),
+            jax.ShapeDtypeStruct((bh, n, d, dv, d), qh.dtype),
+            jax.ShapeDtypeStruct((bh, n, d, d), qh.dtype),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((d, dv, d), jnp.float32),
+            pltpu.VMEM((d, d), jnp.float32),
+            pltpu.VMEM((g, d, c), jnp.float32),
+            pltpu.VMEM((d, c), jnp.float32),
+            pltpu.VMEM((g, dv, c), jnp.float32),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=100 * 1024 * 1024),
+        name="power_retention_fwd",
+        interpret=interpret,
+    )(jnp.swapaxes(qh, -1, -2), kh, jnp.swapaxes(kh, -1, -2),
+      jnp.swapaxes(vh, -1, -2), cs[:, :, None, :], cs[..., None])
+    return jnp.swapaxes(oT, -1, -2), S0, Z0
+
+
+def _retention_grads(qh, kh, vh, ah, S0, Z0, do, scale, eps):
+    """Backward of the chunked state form: the chunks in reverse, the
+    state's cotangent carried from each chunk's end to its start, one
+    chunk's own gradients by ``jax.vjp`` of ``_retention_chunk`` from the
+    saved chunk-start state.  One head at a time: a chunk's phi(q) alone
+    is G*C*d*d elements."""
+    f32 = jnp.float32
+    chunk_fn = functools.partial(_retention_chunk, scale=scale, eps=eps)
+
+    def head(xs):
+        def body(carry, ys):
+            S_c, Z_c, q_c, k_c, v_c, a_c, do_c = ys
+            _, vjp = jax.vjp(chunk_fn, S_c.astype(f32), Z_c.astype(f32),
+                             q_c, k_c, v_c, a_c)
+            dS, dZ, dq, dk, dv, da = vjp(carry + (do_c,))
+            return (dS, dZ), (dq, dk, dv, da)
+
+        init = (jnp.zeros(xs[0].shape[1:], f32),
+                jnp.zeros(xs[1].shape[1:], f32))
+        return jax.lax.scan(body, init, xs, reverse=True)[1]
+
+    return jax.lax.map(head, (S0, Z0, qh, kh, vh, ah, do))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+def power_retention(q, k, v, log_gate, chunk=128, eps=1e-6, use_kernel=False,
+                    interpret=False):
+    """Causal power retention of degree 2 in the chunked state form.
+
+    q [B, S, Hq, d], k [B, S, Hkv, d], v [B, S, Hkv, dv], log_gate
+    [B, S, Hkv] (float32, <= 0) -> [B, S, Hq, dv]; query head i reads
+    key/value head i // (Hq // Hkv); q.k is scaled by 1/sqrt(d).
+    ``use_kernel`` runs the forward as the Pallas kernel (TPU, or
+    ``interpret=True``), else as the same algorithm in ``jnp``; the
+    backward scans the chunks in reverse in ``jnp`` either way.
+    """
+    return _retention_fwd(q, k, v, log_gate, chunk, eps, use_kernel,
+                          interpret)[0]
+
+
+def _retention_fwd(q, k, v, log_gate, chunk, eps, use_kernel, interpret):
+    b, s = q.shape[:2]
+    scale = 1.0 / math.sqrt(q.shape[-1])
+    with jax.named_scope("power_retention_fwd"):
+        qh, kh, vh, ah = _retention_heads(q, k, v, log_gate, chunk)
+        if use_kernel:
+            o, S0, Z0 = _retention_pallas(qh, kh, vh, ah, scale, eps,
+                                          interpret)
+        else:
+            o, S0, Z0 = _retention_scan(qh, kh, vh, ah, scale, eps)
+        o = _retention_unlay(o, b, s)
+    return o, (q, k, v, log_gate, S0, Z0)
+
+
+def _retention_bwd(chunk, eps, use_kernel, interpret, res, do):
+    q, k, v, log_gate, S0, Z0 = res
+    b, s = q.shape[:2]
+    scale = 1.0 / math.sqrt(q.shape[-1])
+    with jax.named_scope("power_retention_bwd"):
+        qh, kh, vh, ah = _retention_heads(q, k, v, log_gate, chunk)
+        doh = _retention_heads(do.astype(q.dtype), k, v, log_gate, chunk)[0]
+        dq, dk, dv, da = _retention_grads(qh, kh, vh, ah, S0, Z0, doh,
+                                          scale, eps)
+        dq = _retention_unlay(dq, b, s)
+        dk, dv, da = (_retention_unlay(x[:, :, None], b, s)
+                      for x in (dk, dv, da[..., None]))
+    return (dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype),
+            da[..., 0].astype(log_gate.dtype))
+
+
+power_retention.defvjp(_retention_fwd, _retention_bwd)

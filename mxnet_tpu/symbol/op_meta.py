@@ -25,7 +25,11 @@ HINTS = {
     "Pooling": "pooling", "Activation": "activation", "Dropout": "dropout",
     "SoftmaxOutput": "softmaxoutput", "Embedding": "embedding", "RNN": "rnn",
     "Concat": "concat", "Flatten": "flatten", "Reshape": "reshape",
-    "LeakyReLU": "leakyrelu", "elemwise_add": "_plus", "elemwise_sub": "_minus",
+    "LeakyReLU": "leakyrelu", "RMSNorm": "rmsnorm",
+    "_contrib_PowerRetention": "powerretention",
+    "_contrib_RotaryEmbedding": "rotaryembedding",
+    "_contrib_BlockedSoftmaxCE": "blockedsoftmaxce",
+    "elemwise_add": "_plus", "elemwise_sub": "_minus",
     "elemwise_mul": "_mul", "elemwise_div": "_div",
 }
 
@@ -51,6 +55,12 @@ def op_input_names(op, attrs):
         return ["data", "gamma", "beta"], ["moving_mean", "moving_var"]
     if name in ("InstanceNorm", "LayerNorm"):
         return ["data", "gamma", "beta"], []
+    if name == "RMSNorm":
+        return ["data", "gamma"], []
+    if name == "_contrib_PowerRetention":
+        return ["query", "key", "value", "log_gate"], []
+    if name == "_contrib_BlockedSoftmaxCE":
+        return ["data", "weight", "label"], []
     if name == "Embedding":
         return ["data", "weight"], []
     if name == "RNN":
@@ -144,6 +154,11 @@ def infer_param_shapes(node, in_structs):
         c = dshape[ax]
         out[1] = S((c,))
         out[2] = S((c,))
+    elif name == "RMSNorm":
+        out[1] = S((dshape[int(a.get("axis", -1)) % len(dshape)],))
+    elif name == "_contrib_BlockedSoftmaxCE":
+        out[1] = S((int(a.get("num_hidden")), dshape[-1]))
+        out[2] = jax.ShapeDtypeStruct(dshape[:-1], np.float32)
     elif name == "Embedding":
         out[1] = S((int(a.get("input_dim")), int(a.get("output_dim"))))
     elif name == "LeakyReLU" and a.get("act_type") == "prelu":

@@ -91,6 +91,11 @@ class Symbol:
         hint = HINTS.get(op_name, op_name.lower().replace("_", ""))
         name = _name_mod.current().get(name, hint)
         str_attrs = {k: v for k, v in attrs.items() if v is not None}
+        # the enclosing AttrScope marks every op composed inside it,
+        # operators included (reference: AttrScope.current.get(attr));
+        # the call's own attributes win
+        for k, v in _attr_mod.current().get(None).items():
+            str_attrs.setdefault("__%s__" % k, v)
         inputs = []
         for s in input_syms:
             if len(s._outputs) != 1:

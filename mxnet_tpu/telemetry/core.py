@@ -543,6 +543,13 @@ COUNTERS = {
     "module_step_carried": "CachedTrainStep executions that took every "
                            "param, aux and optimizer-state input by "
                            "identity from the previous step (no _place)",
+    "executor_remat_segments": "recomputation segments (force_mirroring "
+                               "+ mirror_stage) wrapped in jax.checkpoint "
+                               "at bind",
+    "power_retention_traced": "_contrib_PowerRetention ops traced (the "
+                              "chunked state form)",
+    "power_retention_chunks": "chunks a sequence over all traced "
+                              "_contrib_PowerRetention ops",
     "eager_invocations": "eager op dispatches through ndarray.invoke",
     "io_batches": "data batches produced by iterators",
     "jit_compiles": "watched-jit cache misses (traces+compiles)",

@@ -44,6 +44,12 @@ def test_word_language_model_beats_uniform(capsys):
     assert ppl < 64.0          # uniform baseline on the synthetic vocab
 
 
+def test_brumby_lm_trains_through_module_fit(capsys):
+    out = run_example("brumby_lm.py", ["--num-epochs", "6"], capsys)
+    words = out.strip().splitlines()[-1].split()
+    assert float(words[2]) < 0.5 * float(words[4])
+
+
 def test_model_parallel_lstm_group2ctx(capsys):
     out = run_example("model_parallel_lstm.py", ["--num-steps", "40"],
                       capsys)

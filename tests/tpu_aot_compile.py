@@ -31,7 +31,8 @@ from jax.sharding import NamedSharding, SingleDeviceSharding  # noqa: E402
 
 import mxnet_tpu  # noqa: E402,F401 (x64 + cache placement)
 from mxnet_tpu.models import transformer as T  # noqa: E402
-from mxnet_tpu.ops.pallas_kernels import flash_attention  # noqa: E402
+from mxnet_tpu.ops.pallas_kernels import (flash_attention,  # noqa: E402
+                                          power_retention)
 from mxnet_tpu.parallel.mesh import filter_spec, make_mesh  # noqa: E402
 
 
@@ -54,6 +55,26 @@ def main():
                 .lower(spec, spec, spec).compile()
             print("AOT ok flash %s S=%d D=%d causal=%s"
                   % (jnp.dtype(dtype).name, seq, dim, causal), flush=True)
+
+    # power retention at the Brumby widths (5 query heads a key/value
+    # head of 128, chunks of 1024): the Pallas forward, and the chunked
+    # jnp backward through the states it saves
+    for seq, grad in ((4096, False), (3000, True)):
+        q = jax.ShapeDtypeStruct((1, seq, 10, 128), jnp.bfloat16,
+                                 sharding=one)
+        kv = jax.ShapeDtypeStruct((1, seq, 2, 128), jnp.bfloat16,
+                                  sharding=one)
+        gate = jax.ShapeDtypeStruct((1, seq, 2), jnp.float32, sharding=one)
+
+        def retain(q, k, v, a):
+            return power_retention(q, k, v, a, 1024, 1e-6, True)
+        fn = jax.grad(lambda *x: retain(*x).astype(jnp.float32).sum(),
+                      (0, 1, 2, 3)) if grad else retain
+        lowered = jax.jit(fn).lower(q, kv, kv, gate)
+        assert "tpu_custom_call" in lowered.as_text()
+        lowered.compile()
+        print("AOT ok power_retention S=%d grad=%s" % (seq, grad),
+              flush=True)
 
     # the kernel from mx.pallas's docstring, through the op registry
     # (same kernel and helper the interpret-mode tests use)
