@@ -9,6 +9,16 @@ layers").  Re-design notes:
 - Pooling is ``lax.reduce_window``.
 - BatchNorm is a pure function returning updated moving stats as extra
   outputs (``aux_updates``) instead of mutating aux buffers in a kernel.
+  In training an activation narrower than float32 (bfloat16, float16)
+  goes through ``_bn_train``: float32 moments in one pass (``sum(x)`` and
+  ``sum(x*x)`` as siblings of one reduction, which XLA puts into the
+  kernel that produces ``x``) and a hand-derived VJP (``sum(dy)`` and
+  ``sum(dy*xhat)`` together, ``xhat`` recomputed from the input): four
+  per-channel reductions in two rounds.  ``jnp.mean`` + ``jnp.var`` under
+  ``jax.vjp`` compiles to seven in four, two of them passes of their own
+  over the activation (PERF.md section 6, PR 28).  float32 and float64
+  activations keep that form: with no wider type to accumulate in, one
+  pass would cancel in the data's own precision.
 - Dropout takes an explicit PRNG key (``needs_rng``) so it is jit-safe.
 - The fused RNN op is a ``lax.scan`` over time — the XLA-native equivalent of
   cuDNN's fused RNN (``cudnn_rnn-inl.h``).
@@ -20,11 +30,14 @@ Layout: NCHW / TNC defaults, matching the reference's Python API surface.
 """
 from __future__ import annotations
 
+import functools
+
 import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
 
+from .. import telemetry as _tel
 from .registry import register
 from ..base import dtype_np
 
@@ -171,6 +184,56 @@ def _upsampling(*args, scale=1, sample_type="nearest", num_filter=0,
 
 
 # --- BatchNorm --------------------------------------------------------------
+def _bn_axes(data, ax):
+    """(reduced axes, broadcast shape of a per-channel vector, elements a
+    channel)."""
+    return tuple(i for i in range(data.ndim) if i != ax), \
+        tuple(-1 if i == ax else 1 for i in range(data.ndim)), \
+        data.size // data.shape[ax]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _bn_train(data, g, beta, ax, eps):
+    """Training BatchNorm over every axis but *ax*: (out, batch mean,
+    batch variance).  *g* and *beta* come in the accumulation dtype
+    (float32; float64 for float64 data), the statistics leave in it.  The
+    statistics are outputs for the moving averages only: the VJP sends
+    nothing back through them."""
+    return _bn_train_fwd(data, g, beta, ax, eps)[0]
+
+
+def _bn_train_fwd(data, g, beta, ax, eps):
+    red, shape, n = _bn_axes(data, ax)
+    x = data.astype(g.dtype)
+    # one read of the activation: the two sums are siblings of one fusion
+    s1, s2 = jnp.sum(x, axis=red), jnp.sum(x * x, axis=red)
+    mean = s1 / n
+    var = jnp.maximum(s2 / n - mean * mean, 0)
+    inv = lax.rsqrt(var + eps)
+    out = (x - mean.reshape(shape)) * (inv * g).reshape(shape) \
+        + beta.reshape(shape)
+    return (out.astype(data.dtype), mean, var), (data, mean, inv, g)
+
+
+def _bn_train_bwd(ax, eps, res, cts):
+    data, mean, inv, g = res
+    red, shape, n = _bn_axes(data, ax)
+    dy = cts[0].astype(g.dtype)
+    xhat = (data.astype(g.dtype) - mean.reshape(shape)) * inv.reshape(shape)
+    # again one read of cotangent and activation for both sums
+    s_dy, s_dyx = jnp.sum(dy, axis=red), jnp.sum(dy * xhat, axis=red)
+    dx = (g * inv).reshape(shape) * (
+        dy - (s_dy / n).reshape(shape) - xhat * (s_dyx / n).reshape(shape))
+    return dx.astype(data.dtype), s_dyx, s_dy
+
+
+_bn_train.defvjp(_bn_train_fwd, _bn_train_bwd)
+
+
+def _bn_moving(moving, batch, momentum):
+    return (moving * momentum + batch * (1 - momentum)).astype(moving.dtype)
+
+
 @register("BatchNorm", aliases=["BatchNorm_v1", "CuDNNBatchNorm"],
           num_outputs=3, num_visible_outputs=1,
           nondiff_inputs=(3, 4), aux_updates={3: 1, 4: 2}, takes_mode=True)
@@ -184,10 +247,20 @@ def _batch_norm(data, gamma, beta, moving_mean, moving_var, eps=1e-3,
     shape[ax] = data.shape[ax]
     g = jnp.ones_like(gamma) if fix_gamma else gamma
     if train_mode and not use_global_stats:
+        acc = jnp.promote_types(data.dtype, jnp.float32)
+        if data.dtype != acc:
+            # an activation narrower than its accumulator: what
+            # E[x^2] - E[x]^2 cancels in float32 is below the activation's
+            # own rounding, so one pass is enough
+            _tel.bump("batchnorm_onepass_traced")
+            out, mean, var = _bn_train(data, g.astype(acc),
+                                       beta.astype(acc), ax, float(eps))
+            return out, _bn_moving(moving_mean, mean, momentum), \
+                _bn_moving(moving_var, var, momentum)
         mean = jnp.mean(data, axis=red)
         var = jnp.var(data, axis=red)
-        new_mm = moving_mean * momentum + mean * (1 - momentum)
-        new_mv = moving_var * momentum + var * (1 - momentum)
+        new_mm = _bn_moving(moving_mean, mean, momentum)
+        new_mv = _bn_moving(moving_var, var, momentum)
     else:
         mean, var = moving_mean, moving_var
         new_mm, new_mv = moving_mean, moving_var

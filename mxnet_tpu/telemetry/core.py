@@ -550,6 +550,8 @@ COUNTERS = {
                               "chunked state form)",
     "power_retention_chunks": "chunks a sequence over all traced "
                               "_contrib_PowerRetention ops",
+    "batchnorm_onepass_traced": "training BatchNorm ops traced (one-pass "
+                                "float32 moments, hand-derived VJP)",
     "eager_invocations": "eager op dispatches through ndarray.invoke",
     "io_batches": "data batches produced by iterators",
     "jit_compiles": "watched-jit cache misses (traces+compiles)",

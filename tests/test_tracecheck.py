@@ -73,10 +73,12 @@ def test_jx101_quiet_below_threshold():
 
 @pytest.fixture
 def x64():
-    # f64 exists only with x64 enabled; restore so no other test sees it
+    # f64 exists only with x64 enabled; put back what was there (the
+    # package's own setting is on: a worker's later files count on it)
+    was = jax.config.jax_enable_x64
     jax.config.update("jax_enable_x64", True)
     yield
-    jax.config.update("jax_enable_x64", False)
+    jax.config.update("jax_enable_x64", was)
 
 
 def test_jx102_fires_on_widening_from_f32_inputs(x64):
