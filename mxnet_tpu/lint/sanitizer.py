@@ -28,6 +28,7 @@ jax is only touched once a check actually runs.
 from __future__ import annotations
 
 import contextlib
+import functools
 import logging
 import os
 import threading
@@ -145,6 +146,15 @@ def allow_host_sync():
         _sync_tls.depth = depth
 
 
+@functools.lru_cache(maxsize=None)
+def _trace_state_probe():
+    """jax's "no trace is open on this thread" predicate, resolved on the
+    first check.  It is jax-internal: this is the one place that knows
+    where it lives."""
+    from jax._src import core
+    return core.trace_state_clean
+
+
 def check_host_sync(data, what="asnumpy"):
     """Validate one host materialization.  Called from NDArray.asnumpy;
     off mode returns after a single module-bool check."""
@@ -152,10 +162,17 @@ def check_host_sync(data, what="asnumpy"):
         return
     import jax
     try:
-        is_tracer = isinstance(data, jax.core.Tracer)
-        tracing = not jax.core.trace_state_clean()
-    except Exception:       # pragma: no cover - jax internals moved
+        tracing = not _trace_state_probe()()
+    except (ImportError, AttributeError) as exc:
+        # a sanitizer that cannot see says so: returning here would report
+        # every sync under a trace as clean
+        _violation(
+            "sanitizer is blind: jax's trace-state probe cannot be resolved "
+            "on jax %s (%s: %s), so host syncs under a trace go unchecked. "
+            "Repair mxnet_tpu/lint/sanitizer.py:_trace_state_probe."
+            % (jax.__version__, type(exc).__name__, exc), site=("blind",))
         return
+    is_tracer = isinstance(data, jax.core.Tracer)
     if tracing and not is_tracer and getattr(_sync_tls, "depth", 0):
         return              # an allow_host_sync() scope: deliberate read
     if is_tracer:

@@ -319,11 +319,12 @@ def _all_jaxprs(jaxpr):
 # jit out_shardings) are out of scope on purpose: the partitioner emits
 # them uniformly on every rank — divergence risk lives in hand-written
 # shard_map bodies, which is exactly what lowers to these primitives.
-_COLLECTIVE_PRIMS = {"psum", "psum2", "pmax", "pmin", "all_gather",
+_COLLECTIVE_PRIMS = {"psum", "psum_invariant", "pmax", "pmin", "all_gather",
                      "all_to_all", "reduce_scatter", "psum_scatter",
                      "ppermute", "pshuffle", "axis_index"}
-# psum2: what shard_map's replication checker rewrites psum into — the
-# same all-reduce rendezvous under a different primitive name.
+# psum_invariant: what lax.psum/pmean bind inside a shard_map body whose
+# varying axes are checked (check_vma, the default) — the same all-reduce
+# rendezvous under a different primitive name.
 # axis_index is rank-local (no rendezvous): tracked for JX202's declared-
 # axis check but excluded from order/divergence sequences.
 _RENDEZVOUS_PRIMS = _COLLECTIVE_PRIMS - {"axis_index"}
@@ -354,8 +355,9 @@ def _collectives_in(jaxpr):
             axes = _collective_axes(eqn)
             if axes:
                 # one rendezvous, two spellings: sequences must compare
-                # equal whether or not the rep-checker rewrote the prim
-                out.append(("psum" if prim == "psum2" else prim, axes))
+                # equal whether or not the body's varying axes were checked
+                out.append(("psum" if prim == "psum_invariant" else prim,
+                            axes))
     return tuple(out)
 
 
@@ -439,7 +441,8 @@ def _jx102(rec, cfg):
 # JX103 host-callback-in-hot-program
 # ---------------------------------------------------------------------------
 
-_CALLBACK_PRIMS = {"pure_callback", "io_callback", "debug_callback"}
+_CALLBACK_PRIMS = {"pure_callback", "io_callback", "debug_callback",
+                   "debug_print"}
 
 @trace_rule("JX103", "host-callback",
             "pure_callback/io_callback/debug.print compiled into an owned "

@@ -170,6 +170,18 @@ def _causal_attn_local(q, k, v, mesh=None):
     return local_attention(q, k, v, causal=True)
 
 
+def _take_labels(logp, labels):
+    """``logp[b, s, labels[b, s]]`` as one gather on the labels' own
+    dtype.  ``jnp.take_along_axis`` widens int32 indices to int64 under
+    the package's x64 (emulated on a TPU); labels are token ids, in range
+    by construction, so nothing has to wrap or clamp them."""
+    dnums = jax.lax.GatherDimensionNumbers(
+        offset_dims=(), collapsed_slice_dims=(2,), start_index_map=(2,),
+        operand_batching_dims=(0, 1), start_indices_batching_dims=(0, 1))
+    return jax.lax.gather(logp, labels[..., None], dnums, (1, 1, 1),
+                          mode="promise_in_bounds")
+
+
 def _lm_loss_fn(cfg, mesh, seq_axis):
     """Mean next-token NLL in fp32 — the loss shared by every train-step
     builder in this module."""
@@ -177,7 +189,7 @@ def _lm_loss_fn(cfg, mesh, seq_axis):
     def loss_of(params, tokens, labels):
         logits = transformer_forward(params, tokens, cfg, mesh, seq_axis)
         logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-        nll = -jnp.take_along_axis(logp, labels[..., None], axis=-1)
+        nll = -_take_labels(logp, labels)
         return jnp.mean(nll)
 
     return loss_of

@@ -15,8 +15,8 @@ is organised around four questions instead of one:
    :class:`Counter`, last-value :class:`Gauge`, fixed-bucket
    :class:`Histogram` — supersedes the loose ``profiler._counters`` dict.
    ``profiler.bump()/counter()`` remain as shims onto it, and the counter
-   fast path stays a lock+int-add (tests gate perf contracts on deltas of
-   ``xla_program_calls``; that must never get slower or gated).
+   fast path stays a lock+int-add (tests hold program-count contracts to
+   deltas of ``xla_program_calls``; that must never get slower or gated).
 3. **What recompiles?**  The retrace watchdog (:func:`watch_jit`) wraps
    every jit entry point the framework owns.  A wrapped callable whose
    jit cache grows during a call records a compile event (name, wall time,

@@ -47,8 +47,9 @@ __all__ = ["capture", "analyze_compiled", "finalize_step", "peaks",
 _TRUTHY = ("1", "true", "on", "yes")
 
 # (peak FLOP/s, peak HBM bytes/s) per JAX device, keyed on device_kind
-# exactly as jax reports it — THE peak table of the repo (bench.py and
-# chip_smoke.py read it; there is no second copy).  bf16/dense numbers
+# exactly as jax reports it — THE peak table of the package (the
+# benchmark keeps its own v5e row in chipbench/peaks.py on purpose, so a
+# change here cannot move the yardstick).  bf16/dense numbers
 # from the published per-chip specs, halved for the two-core-per-chip
 # generations where jax exposes cores as devices.  The one row measured
 # against so far: a v5e chip reports device_kind "TPU v5 lite"

@@ -1,13 +1,11 @@
 """Hot-op observatory: per-op roofline attribution over the owned
-program ledger, plus the count-keyed device-time budget gate.
+program ledger, from counts alone.
 
-ROADMAP item 2 (the Pallas kernel tier) is measurement-driven: pick the
-2-3 kernels worth hand-writing from *measured* per-op cost, not vibes.
-Whole-program `device_time_us` histograms (PR 14) cannot name which
-fusion inside ``transformer_train_step`` deserves a kernel; this module
-can.  It walks the **optimized HLO text** of every owned program —
-AOT-compiled from the same ``tracecheck_programs()`` specimen ledger
-the JX2xx trace tier and the JX204 memory gate already consume
+ROADMAP item 2 (the Pallas kernel tier) picks the 2-3 kernels worth
+hand-writing from what each program *does*, not vibes.  This module
+walks the **optimized HLO text** of every owned program — AOT-compiled
+from the same ``tracecheck_programs()`` specimen ledger the JX2xx trace
+tier and the JX204 memory gate already consume
 (``tracecheck.compile_record``; zero new jitted entry points, the
 graftcheck ledger is unchanged) — and for each top-level instruction or
 fusion attributes:
@@ -21,33 +19,26 @@ fusion attributes:
   fusion — and the roofline verdict against the ``costs.peaks()``
   tables: arithmetic intensity above the machine balance is
   compute-bound, below is HBM-bound, collectives are comm (ceilinged by
-  the interconnect table, not HBM).
+  the interconnect table, not HBM);
+* **est_us** — the least time the peak table allows the unit (the
+  larger of flops over peak FLOP/s and bytes over peak bytes/s) — and
+  its **share** of the program's total, summing to 1 by construction.
 
-Attribution then fuses with the *measured* per-program device time (the
-compiled specimen executed under the pinned topology, median of N reps)
-to apportion each program's wall time across its units by
-roofline-weighted share — ``est_us`` per unit, shares summing to 1 over
-a program by construction.
+Nothing is executed and no clock is read: FLOPs, bytes and collectives
+per program are exact on any host, and how long a program takes is the
+chip benchmark's to say (``chipbench/``, ``PERF_LEDGER.jsonl``).
 
-Three consumers:
-
-* ``tools/trace_report.py --ops`` renders the ranked hot-op table and
-  the kernel-candidate list from the ``--json`` artifact this module's
-  CLI writes;
-* ``PERF_BASELINE.json`` — count-keyed per-program device-time budgets
-  (digest-gated exactly like MEM_BASELINE) checked by
-  :func:`check_perf` and gated by ``trace_report.py --gate-perf``
-  (0 ok / 3 regressed / 4 unmeasurable / 2 usage, band via
-  ``MXNET_PERF_TOLERANCE``);
-* the introspection server's observe-only ``/profile`` endpoint
-  (:func:`profile_view` via sys.modules delegation).
+Two consumers: ``tools/trace_report.py --ops`` renders the ranked
+hot-op table and the kernel-candidate list from the ``--json`` artifact
+this module's CLI writes; ``tests/test_opprof_clean.py`` holds every
+owned program to compiling and attributing.
 
 Known approximations, accepted on purpose and recorded here so the
 numbers are honest: while-loop bodies are counted once (trip counts are
 runtime values); convolution flops assume dense direct convolution;
-the CPU "device time" is wall time of the compiled executable — on CPU
-the roofline *shares* and the candidate *ranking* are the signal, the
-absolute ceilings become real on TPU metal.
+``est_us`` is a roofline floor under the peak table of the backend the
+sweep ran on, so the *shares* and the candidate *ranking* are the
+signal.
 
 Import-light: jax loads inside functions only, and nothing here runs on
 the step path — the sweep is an offline tool, like the lint driver.
@@ -55,14 +46,10 @@ the step path — the sweep is an offline tool, like the lint driver.
 from __future__ import annotations
 
 import json
-import os
 import re
-import time
 
 __all__ = ["parse_hlo", "analyze_hlo", "analyze_record", "classify",
-           "sweep", "build_report", "kernel_candidates", "check_perf",
-           "perf_tolerance", "load_perf_baseline", "save_perf_baseline",
-           "default_perf_baseline_path", "profile_view", "main"]
+           "sweep", "build_report", "kernel_candidates", "main"]
 
 # --------------------------------------------------------------------------
 # optimized-HLO text parsing
@@ -375,75 +362,25 @@ def analyze_record(rec, peaks):
 
 
 # --------------------------------------------------------------------------
-# measured device time (the compiled specimen, executed)
+# the sweep: every owned specimen, compiled and attributed
 # --------------------------------------------------------------------------
 
-def _materialize(leaf):
-    """Concrete arg for an AOT-compiled call.  Providers hand a mix of
-    ``jax.ShapeDtypeStruct`` skeletons (kvstore) and live arrays
-    already committed to provider-side shardings (transformer) — the
-    executable here was compiled from specs, so committed arrays fail
-    its input-sharding check.  Uncommitted numpy zeros of the declared
-    shape/dtype satisfy every case: the compiled call places them
-    according to its own input shardings."""
-    import numpy as np
-    shape = getattr(leaf, "shape", None)
-    dtype = getattr(leaf, "dtype", None)
-    if shape is None or dtype is None:
-        return leaf
-    return np.zeros(tuple(int(d) for d in shape), dtype)
+def sweep():
+    """Trace, compile and attribute every owned specimen; nothing is
+    executed.  Returns ``(programs, problems)``:
 
-
-def measure_device_time(compiled, args, kwargs, reps=5, warmup=1):
-    """Median wall-µs of the compiled executable over *reps* calls
-    (after *warmup*), or None when execution fails.  On CPU this is
-    wall time; the relative per-program ordering is the budget, the
-    tolerance band absorbs host noise."""
-    import jax
-    try:
-        cargs, ckwargs = jax.tree_util.tree_map(
-            _materialize, (tuple(args), dict(kwargs or {})))
-    except Exception:
-        return None
-    times = []
-    try:
-        for i in range(warmup + reps):
-            t0 = time.perf_counter()
-            out = compiled(*cargs, **ckwargs)
-            jax.block_until_ready(out)
-            dt = (time.perf_counter() - t0) * 1e6
-            if i >= warmup:
-                times.append(dt)
-    except Exception:
-        return None
-    times.sort()
-    return times[len(times) // 2]
-
-
-# --------------------------------------------------------------------------
-# the sweep: every owned specimen, attributed and timed
-# --------------------------------------------------------------------------
-
-def sweep(entries=None, reps=5, progress=None):
-    """Trace, compile, attribute, and time every owned specimen.
-    Returns ``(programs, problems)``:
-
-    * programs: ``{name: {origin, specimens, digest, median_us,
-      measured, flops, bytes, units}}`` — count-keyed per program NAME
-      like measure_programs (k specimens sum their medians and unit
-      lists; dropping a specimen is as visible as growing one);
+    * programs: ``{name: {origin, specimens, compiled, est_us, flops,
+      bytes, units}}`` — keyed per program NAME like measure_programs
+      (k specimens sum their counts and unit lists);
     * problems: provider/trace/compile failures as strings — a specimen
       the sweep cannot see must be reported, never silently skipped.
     """
-    import hashlib
     import importlib
     from ..lint import tracecheck
     from . import costs
     pk = costs.peaks()
     programs, problems = {}, []
-    for group, modpath in tracecheck.ENTRY_POINTS:
-        if entries is not None and group not in entries:
-            continue
+    for _group, modpath in tracecheck.ENTRY_POINTS:
         origin = modpath.replace(".", "/") + ".py"
         try:
             mod = importlib.import_module(modpath)
@@ -454,8 +391,6 @@ def sweep(entries=None, reps=5, progress=None):
         for spec in specs:
             name, fn, args, kwargs = spec[:4]
             meta = spec[4] if len(spec) > 4 else None
-            if progress:
-                progress(name)
             try:
                 rec = tracecheck.trace_program(
                     name, fn, args, kwargs, origin=origin, meta=meta)
@@ -464,40 +399,27 @@ def sweep(entries=None, reps=5, progress=None):
                                 % (name, origin, exc))
                 continue
             entry = programs.setdefault(name, {
-                "origin": origin, "specimens": 0, "digests": [],
-                "median_us": 0.0, "measured": True,
+                "origin": origin, "specimens": 0, "compiled": True,
                 "flops": 0, "bytes": 0, "units": []})
             entry["specimens"] += 1
-            entry["digests"].append(tracecheck.record_digest(rec))
             analysis, compiled = analyze_record(rec, pk)
-            if compiled is None:
-                problems.append("compiling %s failed" % name)
-                entry["measured"] = False
+            if analysis is None:
+                problems.append(("compiling %s failed" if compiled is None
+                                 else "%s compiled to no HLO text") % name)
+                entry["compiled"] = False
                 continue
-            if analysis is not None:
-                tag = "s%d:" % (entry["specimens"] - 1) \
-                    if entry["specimens"] > 1 else ""
-                for u in analysis["units"]:
-                    u = dict(u, unit=tag + u["unit"])
-                    entry["units"].append(u)
-                entry["flops"] += analysis["flops"]
-                entry["bytes"] += analysis["bytes"]
-            med = measure_device_time(compiled, args, kwargs, reps=reps)
-            if med is None:
-                problems.append("executing %s failed" % name)
-                entry["measured"] = False
-            else:
-                entry["median_us"] += med
+            tag = "s%d:" % (entry["specimens"] - 1) \
+                if entry["specimens"] > 1 else ""
+            for u in analysis["units"]:
+                entry["units"].append(dict(u, unit=tag + u["unit"]))
+            entry["flops"] += analysis["flops"]
+            entry["bytes"] += analysis["bytes"]
     for entry in programs.values():
-        digest = hashlib.sha1(
-            ",".join(sorted(entry.pop("digests"))).encode()).hexdigest()
-        entry["digest"] = digest[:12]
-        # renormalize unit shares over the merged specimen set and
-        # apportion the measured program time by roofline share
-        total_est = sum(u["est_us"] for u in entry["units"])
+        # renormalize unit shares over the merged specimen set
+        entry["est_us"] = sum(u["est_us"] for u in entry["units"])
         for u in entry["units"]:
-            u["share"] = (u["est_us"] / total_est) if total_est else 0.0
-            u["attributed_us"] = u["share"] * entry["median_us"]
+            u["share"] = (u["est_us"] / entry["est_us"]) \
+                if entry["est_us"] else 0.0
         entry["units"].sort(key=lambda u: u["share"], reverse=True)
     return programs, problems
 
@@ -506,9 +428,9 @@ def sweep(entries=None, reps=5, progress=None):
 # kernel candidates: the handoff ROADMAP item 2 consumes
 # --------------------------------------------------------------------------
 
-# Pallas-candidate score = global time share × class weight.  Compute
-# classes where a hand kernel can beat XLA rank high; raw elementwise
-# is usually fused already; "other" is plumbing.
+# Pallas-candidate score = global share of the roofline estimate × class
+# weight.  Compute classes where a hand kernel can beat XLA rank high; raw
+# elementwise is usually fused already; "other" is plumbing.
 _CLASS_WEIGHT = {"dot": 1.0, "conv": 1.0, "fusion": 0.9, "reduce": 0.8,
                  "collective": 0.8, "elementwise": 0.5, "other": 0.2}
 _COMPUTE_CLASSES = ("dot", "conv", "fusion", "reduce")
@@ -517,21 +439,21 @@ _COMPUTE_CLASSES = ("dot", "conv", "fusion", "reduce")
 def kernel_candidates(programs, n_compute=3, n_comm=2):
     """Rank Pallas candidates two ways: the top compute units by
     score = global_share × class weight, and the top collective cores
-    ranked within the comm class (their µs are tiny next to the
+    ranked within the comm class (their estimates are tiny next to the
     matmuls, but they own the interconnect ceiling — a fused
     chunk-sum kernel is a latency win the global ranking would hide)."""
-    total_us = sum(p["median_us"] for p in programs.values()) or 1.0
+    total_us = sum(p["est_us"] for p in programs.values()) or 1.0
     pool = []
     for name, p in programs.items():
         for u in p["units"]:
-            gshare = u.get("attributed_us", 0.0) / total_us
+            gshare = u["est_us"] / total_us
             pool.append(dict(
                 kind=None, program=name, unit=u["unit"],
                 opcode=u["opcode"], op_class=u["op_class"],
                 op_name=u["op_name"], bound=u["bound"],
                 intensity=u["intensity"], ceiling=u["ceiling"],
                 ceiling_kind=u["ceiling_kind"],
-                attributed_us=round(u.get("attributed_us", 0.0), 2),
+                est_us=round(u["est_us"], 4),
                 global_share=round(gshare, 6),
                 score=round(gshare * _CLASS_WEIGHT.get(
                     u["op_class"], 0.2), 6)))
@@ -540,7 +462,7 @@ def kernel_candidates(programs, n_compute=3, n_comm=2):
         key=lambda c: c["score"], reverse=True)[:n_compute]
     comm = sorted(
         (c for c in pool if c["op_class"] == "collective"),
-        key=lambda c: (c["attributed_us"], c["score"]),
+        key=lambda c: (c["est_us"], c["score"]),
         reverse=True)[:n_comm]
     for c in compute:
         c["kind"] = "compute"
@@ -550,153 +472,36 @@ def kernel_candidates(programs, n_compute=3, n_comm=2):
 
 
 # --------------------------------------------------------------------------
-# PERF_BASELINE: count-keyed device-time budgets, digest-gated
-# --------------------------------------------------------------------------
-
-def default_perf_baseline_path():
-    from ..lint.core import repo_root
-    return os.path.join(repo_root(), "PERF_BASELINE.json")
-
-
-def perf_tolerance(default=1.5):
-    """The MXNET_PERF_TOLERANCE fractional band (1.5 = +150% headroom —
-    CPU wall time is noisy; a real regression is a multiple, not a
-    percent).  Parsed per call — this only runs in the offline sweep
-    and the gate, never on the step path."""
-    raw = os.environ.get("MXNET_PERF_TOLERANCE", "")  # graftlint: disable=JG006
-    try:
-        val = float(raw) if raw else default
-    except ValueError:
-        return default
-    return val if val >= 0 else default
-
-
-# absolute jitter floor: sub-500µs swings on micro-programs are host
-# scheduling noise, not regressions — the band is fractional, this is µs
-_PERF_SLACK_US = 500.0
-
-
-def load_perf_baseline(path=None):
-    """PERF_BASELINE.json -> dict, or None when absent/unreadable."""
-    path = path or default_perf_baseline_path()
-    if not os.path.exists(path):
-        return None
-    try:
-        with open(path, encoding="utf-8") as f:
-            payload = json.load(f)
-    except (OSError, ValueError):
-        return None
-    if not isinstance(payload.get("programs"), dict):
-        return None
-    return payload
-
-
-def save_perf_baseline(programs, path=None, n_devices=None, reps=5):
-    """Write sweep results as the committed device-time budget."""
-    path = path or default_perf_baseline_path()
-    if n_devices is None:
-        import jax
-        n_devices = len(jax.devices())
-    payload = {
-        "version": 1, "n_devices": int(n_devices),
-        "tolerance": perf_tolerance(), "reps": int(reps),
-        "programs": {
-            name: {"specimens": p["specimens"], "digest": p["digest"],
-                   "median_us": round(p["median_us"], 1)}
-            for name, p in sorted(programs.items()) if p["measured"]}}
-    with open(path, "w", encoding="utf-8") as f:
-        json.dump(payload, f, indent=1, sort_keys=True)
-        f.write("\n")
-    return payload
-
-
-def check_perf(programs, baseline=None, tolerance=None, full=True,
-               n_devices=None):
-    """Measured sweep *programs* vs a loaded PERF_BASELINE payload.
-    Mirrors tracecheck.check_memory: count-keyed, digest-gated (a
-    budget whose trace signature or specimen count no longer matches
-    the program is not a budget — ``unbudgeted``, loud), topology-honest
-    (device-time is a function of the pinned mesh; mismatch means the
-    gate CANNOT compare and must say so, rc 4 downstream)."""
-    tol = perf_tolerance() if tolerance is None else tolerance
-    if n_devices is None:
-        import jax
-        n_devices = len(jax.devices())
-    base_progs = (baseline or {}).get("programs", {})
-    base_dev = (baseline or {}).get("n_devices")
-    topology_match = baseline is not None \
-        and int(base_dev or 0) == int(n_devices)
-    report_programs = []
-    for name in sorted(programs):
-        p = programs[name]
-        entry = {"name": name, "origin": p["origin"],
-                 "specimens": p["specimens"], "digest": p["digest"],
-                 "median_us": round(p["median_us"], 1),
-                 "budget_us": None, "over_budget": False,
-                 "unbudgeted": False}
-        budget = base_progs.get(name) if topology_match else None
-        if not p["measured"]:
-            entry["unbudgeted"] = True
-        elif budget is None:
-            entry["unbudgeted"] = True
-        else:
-            stale = (int(budget.get("specimens", 1)) != p["specimens"]
-                     or budget.get("digest") != p["digest"])
-            if stale:
-                entry["unbudgeted"] = True
-            b_us = float(budget.get("median_us", 0.0))
-            entry["budget_us"] = b_us
-            limit = b_us + max(b_us * tol, _PERF_SLACK_US)
-            if not stale and p["median_us"] > limit:
-                entry["over_budget"] = True
-        report_programs.append(entry)
-    stale_budgets = []
-    if topology_match and full:
-        stale_budgets = sorted(set(base_progs) - set(programs))
-    return {"schema": "opprof-v1", "n_devices": int(n_devices),
-            "tolerance": tol, "slack_us": _PERF_SLACK_US,
-            "baseline_n_devices": base_dev,
-            "baseline_present": baseline is not None,
-            "topology_match": bool(topology_match),
-            "stale_budgets": stale_budgets,
-            "programs": report_programs}
-
-
-# --------------------------------------------------------------------------
-# the artifact + /profile view
+# the artifact
 # --------------------------------------------------------------------------
 
 _UNITS_KEPT = 12          # per program in the artifact; counts recorded
 
-_LAST_REPORT = None       # most recent build_report in this process
 
-
-def build_report(programs, problems, perf, peaks, reps=5):
+def build_report(programs, problems, peaks):
     """The ``--json`` artifact trace_report consumes.  Unit lists are
     capped at the top _UNITS_KEPT per program BY SHARE with the dropped
     tail recorded (units_omitted / share_omitted) — a silent cap would
     read as full coverage."""
-    global _LAST_REPORT
-    total_us = sum(p["median_us"] for p in programs.values())
     out_programs = {}
     for name, p in sorted(programs.items()):
         kept = p["units"][:_UNITS_KEPT]
         omitted = p["units"][_UNITS_KEPT:]
         out_programs[name] = {
             "origin": p["origin"], "specimens": p["specimens"],
-            "digest": p["digest"], "measured": p["measured"],
-            "median_us": round(p["median_us"], 1),
+            "compiled": p["compiled"],
+            "est_us": round(p["est_us"], 4),
             "flops": p["flops"], "bytes": p["bytes"],
             "units": [
-                {k: (round(v, 6 if k in ("share", "intensity") else 2)
+                {k: (round(v, 6 if k in ("share", "intensity") else 4)
                      if isinstance(v, float) else v)
                  for k, v in u.items()} for u in kept],
             "units_total": len(p["units"]),
             "units_omitted": len(omitted),
             "share_omitted": round(sum(u["share"] for u in omitted), 4),
         }
-    report = {
-        "schema": "opprof-ops-v1",
+    return {
+        "schema": "opprof-ops-v2",
         "n_devices": peaks.get("n_devices"),
         "device_kind": peaks.get("device_kind"),
         "peaks": {"flops": peaks["flops"], "hbm_bw": peaks["hbm_bw"],
@@ -704,138 +509,56 @@ def build_report(programs, problems, perf, peaks, reps=5):
         "machine_balance": round(
             peaks["flops"] / peaks["hbm_bw"], 4) if peaks["hbm_bw"]
         else 0.0,
-        "reps": reps,
-        "total_measured_us": round(total_us, 1),
+        "total_est_us": round(
+            sum(p["est_us"] for p in programs.values()), 4),
         "problems": problems,
         "programs": out_programs,
         "candidates": kernel_candidates(programs),
-        "perf": perf,
     }
-    _LAST_REPORT = report
-    return report
-
-
-def profile_view(top=8):
-    """The observe-only ``/profile`` summary: committed budgets + the
-    in-process report when a sweep ran here, trimmed for a browser.
-    Stdlib-only and never triggers a sweep — the endpoint observes."""
-    baseline = load_perf_baseline()
-    view = {"available": _LAST_REPORT is not None,
-            "baseline": None, "candidates": None, "top_programs": None}
-    if baseline is not None:
-        progs = baseline.get("programs", {})
-        ranked = sorted(progs.items(),
-                        key=lambda kv: kv[1].get("median_us", 0),
-                        reverse=True)
-        view["baseline"] = {
-            "n_devices": baseline.get("n_devices"),
-            "programs": len(progs),
-            "top_budgets_us": [
-                {"name": k, "median_us": v.get("median_us")}
-                for k, v in ranked[:top]]}
-    if _LAST_REPORT is not None:
-        view["candidates"] = _LAST_REPORT.get("candidates")
-        ranked = sorted(
-            _LAST_REPORT.get("programs", {}).items(),
-            key=lambda kv: kv[1].get("median_us", 0), reverse=True)
-        view["top_programs"] = [
-            {"name": k, "median_us": v.get("median_us"),
-             "top_unit": (v.get("units") or [{}])[0].get("op_name")
-             or (v.get("units") or [{}])[0].get("unit")}
-            for k, v in ranked[:top]]
-    return view
 
 
 # --------------------------------------------------------------------------
 # CLI
 # --------------------------------------------------------------------------
 
-def _programs_from_artifact(artifact):
-    """Reconstruct the check_perf input from a prior --json artifact
-    (the --from path: re-gate doctored budgets without recompiling)."""
-    out = {}
-    for name, p in artifact.get("programs", {}).items():
-        out[name] = {"origin": p["origin"], "specimens": p["specimens"],
-                     "digest": p["digest"], "measured": p["measured"],
-                     "median_us": float(p["median_us"]),
-                     "flops": p.get("flops", 0),
-                     "bytes": p.get("bytes", 0),
-                     "units": p.get("units", [])}
-    return out
-
-
 def main(argv=None):
     import argparse
     ap = argparse.ArgumentParser(
         prog="python -m mxnet_tpu.telemetry.opprof",
-        description="per-op roofline attribution + device-time budgets "
-                    "over the owned program ledger (run under the "
-                    "pinned topology: JAX_PLATFORMS=cpu XLA_FLAGS="
+        description="per-op roofline attribution over the owned program "
+                    "ledger: FLOPs, bytes and collectives from the "
+                    "optimized HLO, nothing executed (the tests' "
+                    "topology: JAX_PLATFORMS=cpu XLA_FLAGS="
                     "--xla_force_host_platform_device_count=8)")
     ap.add_argument("--json", metavar="PATH",
                     help="write the ops artifact (trace_report --ops)")
-    ap.add_argument("--perf-baseline", metavar="PATH",
-                    help="PERF_BASELINE.json to check against "
-                         "(default: the committed one)")
-    ap.add_argument("--write-perf-baseline", action="store_true",
-                    help="save measured medians as the budget, then "
-                         "self-check against it")
-    ap.add_argument("--from", dest="from_json", metavar="OPSJSON",
-                    help="reuse a prior artifact's measurements instead "
-                         "of sweeping (re-gate without recompiling)")
-    ap.add_argument("--reps", type=int, default=5)
     ap.add_argument("--top", type=int, default=10,
-                    help="units shown in the stdout summary")
+                    help="programs shown in the stdout summary")
     args = ap.parse_args(argv)
 
-    baseline_path = args.perf_baseline or default_perf_baseline_path()
-
-    if args.from_json:
-        try:
-            with open(args.from_json, encoding="utf-8") as f:
-                artifact = json.load(f)
-        except (OSError, ValueError) as exc:
-            ap.error("unreadable --from artifact: %s" % exc)
-        programs = _programs_from_artifact(artifact)
-        problems = artifact.get("problems", [])
-        peaks = dict(artifact.get("peaks", {}),
-                     n_devices=artifact.get("n_devices"),
-                     device_kind=artifact.get("device_kind"))
-        perf = check_perf(programs, load_perf_baseline(baseline_path),
-                          n_devices=artifact.get("n_devices"))
-        report = build_report(programs, problems, perf, peaks,
-                              reps=artifact.get("reps", args.reps))
-    else:
-        from . import costs
-        peaks = costs.peaks()
-        programs, problems = sweep(reps=args.reps)
-        if args.write_perf_baseline:
-            save_perf_baseline(programs, baseline_path, reps=args.reps)
-            print("wrote %s (%d programs)"
-                  % (baseline_path,
-                     sum(1 for p in programs.values() if p["measured"])))
-        perf = check_perf(programs, load_perf_baseline(baseline_path))
-        report = build_report(programs, problems, perf, peaks,
-                              reps=args.reps)
+    from . import costs
+    programs, problems = sweep()
+    report = build_report(programs, problems, costs.peaks())
 
     if args.json:
         with open(args.json, "w", encoding="utf-8") as f:
             json.dump(report, f, indent=1, sort_keys=True)
             f.write("\n")
 
-    # stdout summary: programs by measured time, then the candidates
+    # stdout summary: programs by roofline estimate, then the candidates
     progs = sorted(report["programs"].items(),
-                   key=lambda kv: kv[1]["median_us"], reverse=True)
-    print("opprof: %d programs, %.1f ms measured total, "
-          "machine balance %.2f FLOP/B"
-          % (len(progs), report["total_measured_us"] / 1e3,
+                   key=lambda kv: kv[1]["est_us"], reverse=True)
+    print("opprof: %d programs, %s FLOPs, %s bytes, machine balance "
+          "%.2f FLOP/B"
+          % (len(progs), sum(p["flops"] for _n, p in progs),
+             sum(p["bytes"] for _n, p in progs),
              report["machine_balance"]))
     for name, p in progs[:args.top]:
         top_u = (p["units"] or [{}])[0]
-        print("  %-34s %9.1f us  top: %s %s (%s, share %.2f)"
-              % (name, p["median_us"], top_u.get("op_class", "-"),
-                 top_u.get("unit", "-"), top_u.get("bound", "-"),
-                 top_u.get("share", 0.0)))
+        print("  %-34s %12d FLOPs %12d B  top: %s %s (%s, share %.2f)"
+              % (name, p["flops"], p["bytes"],
+                 top_u.get("op_class", "-"), top_u.get("unit", "-"),
+                 top_u.get("bound", "-"), top_u.get("share", 0.0)))
     print("kernel candidates:")
     for c in report["candidates"]:
         print("  [%s] %s :: %s (%s, %s) share %.4f score %.4f"

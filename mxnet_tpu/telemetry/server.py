@@ -306,23 +306,12 @@ class _Handler(BaseHTTPRequestHandler):
                     self._reply_json(ts.summary())
             elif path == "/profile":
                 # observe-only: the runtime per-program device-time
-                # table plus the opprof hot-op/budget summary, each via
-                # sys.modules — a process that never imported device or
-                # ran an opprof sweep reports what it has, triggers
-                # nothing (opprof is deliberately NOT in telemetry's
-                # import set; absent means None, not an import)
+                # table via sys.modules — a process that never imported
+                # device reports None, triggers nothing
                 dev = sys.modules.get("mxnet_tpu.telemetry.device")
-                opp = sys.modules.get("mxnet_tpu.telemetry.opprof")
-                payload = {
+                self._reply_json({
                     "device": dev.device_report()
-                    if dev is not None else None,
-                    "opprof": None}
-                if opp is not None:
-                    try:
-                        payload["opprof"] = opp.profile_view()
-                    except Exception:
-                        pass
-                self._reply_json(payload)
+                    if dev is not None else None})
             elif path == "/stacks":
                 stacks = flight.thread_stacks()
                 text = "\n".join("--- %s ---\n%s" % (k, "".join(v))

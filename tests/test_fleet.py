@@ -341,6 +341,10 @@ def test_rolling_reload_zero_failed_requests_and_new_weights(
 @pytest.fixture
 def live_server():
     from mxnet_tpu.telemetry import server
+    # /healthz reads the process's counters and step clock: what earlier
+    # files of this worker left there (a sanitizer violation, a step
+    # older than the stall limit) is not this server's health
+    telemetry.reset()
     srv = server.start_server(port=0, sample_ms=100)
     yield srv
     server.stop_server()

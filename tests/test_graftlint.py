@@ -619,6 +619,31 @@ def test_sanitizer_warn_mode_logs_instead(sanitize_raise, caplog):
     assert any("under trace" in r.message for r in caplog.records)
 
 
+@pytest.mark.parametrize("mode", ["raise", "warn"])
+def test_sanitizer_says_when_it_cannot_see(mode, sanitize_raise, monkeypatch,
+                                           caplog):
+    """jax moved the trace-state probe: the check is blind, and that is a
+    violation of its own — not a silent pass of every sync under a trace."""
+    import logging
+
+    def moved():
+        raise AttributeError("module 'jax._src.core' has no attribute "
+                             "'trace_state_clean'")
+
+    monkeypatch.setattr(sanitizer, "_trace_state_probe", moved, raising=False)
+    sanitizer.configure(mode=mode)
+    const = nd.array(np.ones((2, 2)))
+    with caplog.at_level(logging.WARNING, logger="mxnet_tpu.sanitizer"):
+        if mode == "raise":
+            with pytest.raises(sanitizer.SanitizerError, match="blind"):
+                const.asnumpy()
+        else:
+            const.asnumpy()
+            const.asnumpy()
+    blind = [r for r in caplog.records if "blind" in r.getMessage()]
+    assert len(blind) == (1 if mode == "warn" else 0)
+
+
 # ---------------------------------------------------------------------------
 # engine happens-before checker
 # ---------------------------------------------------------------------------

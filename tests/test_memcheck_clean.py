@@ -124,3 +124,32 @@ def test_gate_memory_requires_memory_json(tmp_path):
         [sys.executable, TRACE_REPORT, "--gate-memory"],
         capture_output=True, text=True)
     assert proc.returncode == 2
+
+
+def test_combined_gates_report_every_gate(sweep, tmp_path):
+    """Regression for the silent-degradation bug: when the memory and
+    overlap gates are requested together, BOTH verdict lines print and
+    the exit code is the worse of the two — a failing second gate cannot
+    hide behind a passing first one."""
+    _findings, _names, report = sweep
+    mem_path = tmp_path / "mem.json"
+    mem_path.write_text(json.dumps(report))
+    step = {"wall_us": 100.0, "data_wait_us": 0.0, "host_us": 10.0,
+            "device_us": 60.0, "collective_us": 30.0,
+            "overlap_ratio": 0.2, "overlap_hidden_us": 6.0,
+            "overlap_exposed_us": 24.0}
+    snap_path = tmp_path / "snap.json"
+    snap_path.write_text(json.dumps({"device": {
+        "enabled": True, "sample_period": 1, "timelines": [step],
+        "last_step": step, "programs": {}}}))
+    trace_path = tmp_path / "trace.json"
+    trace_path.write_text(json.dumps({"traceEvents": []}))
+    proc = subprocess.run(
+        [sys.executable, TRACE_REPORT, str(trace_path),
+         "--snapshot", str(snap_path), "--gate-overlap", "0.5",
+         "--memory", str(mem_path), "--gate-memory"],
+        capture_output=True, text=True)
+    both = proc.stdout + proc.stderr
+    assert "gate-memory: ok" in both
+    assert "gate-overlap: FAIL" in both
+    assert proc.returncode == 3

@@ -12,14 +12,9 @@ end-to-end through the real trace->compile->parse path (no mocked HLO):
 * a psum under the substrate's shard_map on the 8-device test mesh —
   must surface a ``collective`` unit bound by ``comm``.
 
-Plus the perf-budget comparison (check_perf) over synthetic measured
-sets, the device->timeseries drift feed, and the bench trajectory tool.
+Plus the candidate ranking over synthetic attributed sets and the
+device->timeseries drift feed.
 """
-import json
-import os
-import subprocess
-import sys
-
 import numpy as np
 import pytest
 
@@ -30,8 +25,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 from mxnet_tpu.lint import tracecheck
 from mxnet_tpu.parallel import mesh as mesh_mod
 from mxnet_tpu.telemetry import costs, opprof
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def analyze(name, fn, args):
@@ -159,130 +152,48 @@ ENTRY %main.9 (a: f32[16,16], t: (s32[], f32[16,16])) -> f32[16,16] {
 
 
 # ---------------------------------------------------------------------------
-# check_perf: the budget comparison
+# kernel candidates
 # ---------------------------------------------------------------------------
-
-def _measured(name="prog", us=1000.0, digest="d0", specimens=1):
-    return {name: {"origin": "o.py", "specimens": specimens,
-                   "digest": digest, "median_us": us, "measured": True,
-                   "flops": 0, "bytes": 0, "units": []}}
-
-
-def _baseline(name="prog", us=1000.0, digest="d0", specimens=1,
-              n_devices=8):
-    return {"version": 1, "n_devices": n_devices, "tolerance": 1.5,
-            "programs": {name: {"specimens": specimens,
-                                "digest": digest, "median_us": us}}}
-
-
-def test_check_perf_within_budget():
-    report = opprof.check_perf(_measured(us=1200.0), _baseline(),
-                               tolerance=1.5, n_devices=8)
-    (p,) = report["programs"]
-    assert not p["over_budget"] and not p["unbudgeted"]
-    assert report["topology_match"]
-
-
-def test_check_perf_flags_regression_beyond_band_and_slack():
-    # budget 1000us, tolerance +150% + 500us slack -> limit 3000us
-    report = opprof.check_perf(_measured(us=3100.0), _baseline(),
-                               tolerance=1.5, n_devices=8)
-    (p,) = report["programs"]
-    assert p["over_budget"]
-
-
-def test_check_perf_slack_floor_absorbs_micro_jitter():
-    # 10us budget: the fractional band is meaningless, the 500us
-    # absolute floor keeps scheduler noise out of the verdict
-    report = opprof.check_perf(_measured(us=400.0),
-                               _baseline(us=10.0),
-                               tolerance=1.5, n_devices=8)
-    (p,) = report["programs"]
-    assert not p["over_budget"]
-
-
-def test_check_perf_digest_mismatch_is_unbudgeted():
-    report = opprof.check_perf(_measured(digest="NEW"), _baseline(),
-                               tolerance=1.5, n_devices=8)
-    (p,) = report["programs"]
-    assert p["unbudgeted"]
-
-
-def test_check_perf_specimen_count_mismatch_is_unbudgeted():
-    report = opprof.check_perf(_measured(specimens=2),
-                               _baseline(specimens=1),
-                               tolerance=1.5, n_devices=8)
-    (p,) = report["programs"]
-    assert p["unbudgeted"]
-
-
-def test_check_perf_topology_mismatch_skips_comparison():
-    report = opprof.check_perf(_measured(), _baseline(n_devices=2),
-                               tolerance=1.5, n_devices=8)
-    assert not report["topology_match"]
-    (p,) = report["programs"]
-    assert p["unbudgeted"] and not p["over_budget"]
-
-
-def test_check_perf_stale_budgets_named():
-    base = _baseline()
-    base["programs"]["gone_program"] = {"specimens": 1, "digest": "x",
-                                        "median_us": 5.0}
-    report = opprof.check_perf(_measured(), base, tolerance=1.5,
-                               n_devices=8)
-    assert report["stale_budgets"] == ["gone_program"]
-
-
-def test_perf_tolerance_env(monkeypatch):
-    monkeypatch.delenv("MXNET_PERF_TOLERANCE", raising=False)
-    assert opprof.perf_tolerance() == 1.5
-    monkeypatch.setenv("MXNET_PERF_TOLERANCE", "0.5")
-    assert opprof.perf_tolerance() == 0.5
-    monkeypatch.setenv("MXNET_PERF_TOLERANCE", "junk")
-    assert opprof.perf_tolerance() == 1.5
-    monkeypatch.setenv("MXNET_PERF_TOLERANCE", "-1")
-    assert opprof.perf_tolerance() == 1.5
-
 
 def test_kernel_candidates_rank_compute_and_comm():
     programs = {
-        "big": {"origin": "o", "specimens": 1, "digest": "a",
-                "median_us": 900.0, "measured": True, "flops": 0,
-                "bytes": 0, "units": [
+        "big": {"origin": "o", "specimens": 1, "compiled": True,
+                "est_us": 10.0, "flops": 0, "bytes": 0, "units": [
                     {"unit": "%dot.1", "opcode": "dot",
                      "op_class": "dot", "op_name": None,
                      "bound": "compute", "intensity": 40.0,
                      "ceiling": 8e11, "ceiling_kind": "flops_per_s",
-                     "est_us": 9.0, "share": 0.9,
-                     "attributed_us": 810.0},
+                     "est_us": 9.0, "share": 0.9},
                     {"unit": "%all-reduce.1", "opcode": "all-reduce",
                      "op_class": "collective", "op_name": None,
                      "bound": "comm", "intensity": 0.1,
                      "ceiling": 8e10, "ceiling_kind": "bytes_per_s",
-                     "est_us": 1.0, "share": 0.1,
-                     "attributed_us": 90.0}]},
-        "tiny": {"origin": "o", "specimens": 1, "digest": "b",
-                 "median_us": 100.0, "measured": True, "flops": 0,
-                 "bytes": 0, "units": [
+                     "est_us": 1.0, "share": 0.1}]},
+        "tiny": {"origin": "o", "specimens": 1, "compiled": True,
+                 "est_us": 2.0, "flops": 0, "bytes": 0, "units": [
                      {"unit": "%collective-permute.1",
                       "opcode": "collective-permute",
                       "op_class": "collective", "op_name": None,
                       "bound": "comm", "intensity": 0.0,
                       "ceiling": 8e10, "ceiling_kind": "bytes_per_s",
-                      "est_us": 1.0, "share": 1.0,
-                      "attributed_us": 100.0}]},
+                      "est_us": 2.0, "share": 1.0}]},
     }
     cands = opprof.kernel_candidates(programs)
     kinds = {c["kind"] for c in cands}
     assert kinds == {"compute", "comm"}
     compute = [c for c in cands if c["kind"] == "compute"]
     assert compute[0]["unit"] == "%dot.1"
+    # shares are of the roofline estimate over every program: 9 of 12
+    assert compute[0]["global_share"] == pytest.approx(0.75)
+    assert compute[0]["score"] == pytest.approx(0.75)
     comm = [c for c in cands if c["kind"] == "comm"]
-    # ranked within the comm class by attributed time: the permute's
-    # 100us beats the all-reduce's 90us even though its global share
-    # is small — the separate tier exists exactly so collective cores
-    # are not buried under the matmuls
-    assert comm[0]["unit"] == "%collective-permute.1"
+    # ranked within the comm class by their own estimate: the permute's
+    # 2us beats the all-reduce's 1us even though both are small next to
+    # the dot — the separate tier exists exactly so collective cores are
+    # not buried under the matmuls
+    assert [c["unit"] for c in comm] == ["%collective-permute.1",
+                                         "%all-reduce.1"]
+    assert comm[0]["score"] == pytest.approx(2 / 12 * 0.8, abs=1e-6)
 
 
 # ---------------------------------------------------------------------------
@@ -334,68 +245,3 @@ def test_opprof_env_parse(monkeypatch):
     monkeypatch.delenv("MXNET_OPPROF", raising=False)
     device.refresh_from_env()
     assert device.opprof_enabled()   # default on
-
-
-# ---------------------------------------------------------------------------
-# bench trajectory tool
-# ---------------------------------------------------------------------------
-
-TRAJECTORY = os.path.join(REPO, "tools", "bench_trajectory.py")
-
-
-def _round_files(tmp_path, rounds):
-    for n, (bench_rc, calls, value) in rounds.items():
-        (tmp_path / ("BENCH_r%02d.json" % n)).write_text(json.dumps({
-            "n": n, "cmd": "x", "rc": bench_rc, "tail": "",
-            "parsed": {"metric": "resnet50_infer", "value": value,
-                       "unit": "img/s", "vs_baseline": None,
-                       "program_calls_per_step": calls,
-                       "overlap_ratio": None, "gate_overlap": None,
-                       "health_gate": None}}))
-        (tmp_path / ("MULTICHIP_r%02d.json" % n)).write_text(json.dumps(
-            {"n_devices": 8, "rc": 0, "ok": True, "skipped": False,
-             "legs": ["train"], "multihost": None, "health": None,
-             "tail": ""}))
-
-
-def _run_traj(tmp_path, *extra):
-    return subprocess.run(
-        [sys.executable, TRAJECTORY, "--root", str(tmp_path), *extra],
-        capture_output=True, text=True)
-
-
-def test_trajectory_merges_rounds(tmp_path):
-    _round_files(tmp_path, {1: (0, 1.0, 100.0), 2: (0, 1.0, 110.0)})
-    proc = _run_traj(tmp_path)
-    assert proc.returncode == 0
-    out = json.loads(proc.stdout)
-    assert [r["round"] for r in out["rounds"]] == [1, 2]
-    assert out["regressions"] == []
-
-
-def test_trajectory_check_flags_calls_per_step_growth(tmp_path):
-    _round_files(tmp_path, {1: (0, 1.0, 100.0), 2: (0, 2.0, 100.0)})
-    proc = _run_traj(tmp_path, "--check")
-    assert proc.returncode == 3
-    assert "program_calls_per_step grew" in proc.stderr
-
-
-def test_trajectory_check_flags_throughput_drop(tmp_path):
-    _round_files(tmp_path, {1: (0, 1.0, 100.0), 2: (0, 1.0, 80.0)})
-    proc = _run_traj(tmp_path, "--check")
-    assert proc.returncode == 3
-    assert "dropped" in proc.stderr
-
-
-def test_trajectory_check_unmeasurable_below_two_rounds(tmp_path):
-    _round_files(tmp_path, {1: (0, 1.0, 100.0)})
-    proc = _run_traj(tmp_path, "--check")
-    assert proc.returncode == 4
-
-
-def test_trajectory_check_ok_on_clean_rounds(tmp_path):
-    _round_files(tmp_path, {1: (0, 1.0, 100.0), 2: (0, 1.0, 99.0),
-                            3: (0, 1.0, 101.0)})
-    proc = _run_traj(tmp_path, "--check")
-    assert proc.returncode == 0
-    assert "trajectory: ok" in proc.stdout

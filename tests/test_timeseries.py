@@ -85,14 +85,13 @@ def test_merge_concatenates_and_sorts_by_step():
     assert merged["series"]["only2"] == [[1, 8.0]]
 
 
-def test_note_step_exit_books_time_and_live_gauges():
+def test_note_step_exit_books_time_and_live_gauges(monkeypatch):
+    # "this run" is this test: the gauge table is the process's, and other
+    # files of the same worker leave theirs set (overlap_ratio)
+    monkeypatch.setattr(tcore, "_gauges", {})
     telemetry.set_gauge("io_batch_wait_us", 17.0)
-    try:
-        ts.note_step_exit(1234.0)
-        ts.note_step_exit(5678.0)
-    finally:
-        with tcore._mlock:
-            tcore._gauges.pop("io_batch_wait_us", None)
+    ts.note_step_exit(1234.0)
+    ts.note_step_exit(5678.0)
     assert ts.series("step_time_us") == [(0, 1234.0), (1, 5678.0)]
     assert ts.series("io_batch_wait_us") == [(0, 17.0), (1, 17.0)]
     # gauges never set this run record nothing (no phantom zeros)

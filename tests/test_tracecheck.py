@@ -366,6 +366,41 @@ def spmd_rules(fn, select, meta=None, name="fixture"):
 
 
 # ---------------------------------------------------------------------------
+# collective extraction: every lax collective, under whichever primitive
+# name the installed jax binds it
+# ---------------------------------------------------------------------------
+
+_PAIR = [(0, 1), (1, 0)]
+# name -> (body over a (4, 64) shard of two, the rendezvous expected)
+_COLLECTIVE_BODIES = {
+    "psum": (lambda s: jax.lax.psum(s, "x"), "psum"),
+    "pmean": (lambda s: jax.lax.pmean(s, "x"), "psum"),
+    "pmax": (lambda s: jax.lax.pmax(s, "x"), "pmax"),
+    "pmin": (lambda s: jax.lax.pmin(s, "x"), "pmin"),
+    "all_gather": (lambda s: jax.lax.all_gather(s, "x"), "all_gather"),
+    "all_to_all": (lambda s: jax.lax.all_to_all(s.reshape(2, 2, 64), "x",
+                                                0, 0), "all_to_all"),
+    "ppermute": (lambda s: jax.lax.ppermute(s, "x", _PAIR), "ppermute"),
+}
+
+
+@pytest.mark.parametrize("check", [True, False],
+                         ids=["check_vma", "unchecked"])
+@pytest.mark.parametrize("name", sorted(_COLLECTIVE_BODIES))
+def test_collectives_in_sees_each_collective(name, check):
+    """One collective over ``x`` in a two-device shard_map body is one
+    rendezvous over ``("x",)``, under the same name whether or not jax
+    checks the body's varying axes (checked, ``lax.psum`` and ``pmean``
+    bind ``psum_invariant``)."""
+    body, rendezvous = _COLLECTIVE_BODIES[name]
+    two = Mesh(np.array(jax.devices()[:2]), ("x",))
+    prog = mesh_mod.shard_map(body, mesh=two, in_specs=P("x", None),
+                              out_specs=P("x"), check=check)
+    rec = trace_program("fixture", jax.jit(prog), (spec((8, 64)),))
+    assert tracecheck._collectives_in(rec.jaxpr) == ((rendezvous, ("x",)),)
+
+
+# ---------------------------------------------------------------------------
 # JX201 collective-divergence
 # ---------------------------------------------------------------------------
 
@@ -376,8 +411,12 @@ def test_jx201_fires_on_collective_under_one_cond_arm(mesh):
     def prog(v):
         def body(s):
             pred = jnp.sum(s) > 0.0
-            return jax.lax.cond(pred, lambda t: jax.lax.psum(t, "x"),
-                                lambda t: t, s)
+            # both arms must have one type: the psum's result, the same
+            # on every rank, is cast back to varying over "x" like ``t``
+            return jax.lax.cond(
+                pred,
+                lambda t: mesh_mod.pvary(jax.lax.psum(t, "x"), ("x",)),
+                lambda t: t, s)
         return _smap(body, mesh)(v)
 
     assert spmd_rules(prog, "JX201") == [("JX201", "cond-divergence")]

@@ -760,19 +760,12 @@ def main(argv=None):
                          "roofline table and kernel candidates of a "
                          "``python -m mxnet_tpu.telemetry.opprof "
                          "--json`` artifact")
-    ap.add_argument("--gate-perf", action="store_true",
-                    help="with --ops: exit 3 when any program exceeds "
-                         "its PERF_BASELINE device-time budget or is "
-                         "unbudgeted, 4 when the report cannot measure "
-                         "(topology mismatch / empty)")
     args = ap.parse_args(argv)
 
     # gates declare their evidence up front: a gate whose input section
     # is missing is a usage error (2), never a silent skip
     if args.gate_memory and args.memory is None:
         ap.error("--gate-memory requires --memory MEMJSON")
-    if args.gate_perf and args.ops is None:
-        ap.error("--gate-perf requires --ops OPSJSON")
     if args.gate_overlap is not None and args.trace is None \
             and args.fleet is None:
         ap.error("--gate-overlap requires a trace file")
@@ -795,10 +788,10 @@ def main(argv=None):
             raise
 
     # every requested section renders; every requested gate runs; the
-    # exit code is the worst gate verdict — combining --gate-overlap /
-    # --gate-memory / --gate-perf must never silently drop one
+    # exit code is the worst gate verdict — combining --gate-overlap and
+    # --gate-memory must never silently drop one
     sections = []               # (key, payload, render thunk)
-    mem_payload = ops_payload = trace_payload = None
+    mem_payload = trace_payload = None
     try:
         if args.memory is not None:
             mem_payload = _load(args.memory, "memory")
@@ -844,8 +837,6 @@ def main(argv=None):
         rcs.append(gate_overlap(trace_payload, args.gate_overlap))
     if args.gate_memory:
         rcs.append(gate_memory(mem_payload))
-    if args.gate_perf:
-        rcs.append(gate_perf(ops_payload.get("perf") or {}))
     return max(rcs) if rcs else 0
 
 
@@ -945,10 +936,10 @@ def _fmt_rate(v, unit):
 def render_ops(report, top=10):
     """The ranked hot-op table and kernel-candidate list of an opprof
     ``--json`` artifact: every owned program's fusions, rooflined."""
-    lines = ["hot ops: %d program(s), %.1f ms measured, machine "
+    lines = ["hot ops: %d program(s), roofline floor %.1f us, machine "
              "balance %.2f FLOP/B (peaks: %s, HBM %s, ICI %s)"
              % (len(report.get("programs", {})),
-                (report.get("total_measured_us") or 0) / 1e3,
+                report.get("total_est_us") or 0,
                 report.get("machine_balance") or 0,
                 _fmt_rate((report.get("peaks") or {}).get("flops"),
                           "FLOP/s"),
@@ -959,13 +950,13 @@ def render_ops(report, top=10):
     rows = []
     for name, p in (report.get("programs") or {}).items():
         for u in p.get("units", ()):
-            rows.append((u.get("attributed_us") or 0.0, name, u))
+            rows.append((u.get("est_us") or 0.0, name, u))
     rows.sort(key=lambda r: -r[0])
     lines.append("  %-26s %-28s %-11s %8s %-7s %10s %7s %9s"
                  % ("program", "unit", "class", "FLOP/B", "bound",
-                    "ceiling", "share", "us"))
+                    "ceiling", "share", "est us"))
     for us, name, u in rows[:top]:
-        lines.append("  %-26s %-28s %-11s %8.2f %-7s %10s %6.1f%% %9.1f"
+        lines.append("  %-26s %-28s %-11s %8.2f %-7s %10s %6.1f%% %9.3f"
                      % (name[:26], u.get("unit", "?")[:28],
                         u.get("op_class", "?"),
                         u.get("intensity") or 0.0,
@@ -990,64 +981,10 @@ def render_ops(report, top=10):
                              "flops_per_s" else "B/s"),
                    100 * (c.get("global_share") or 0.0),
                    c.get("score") or 0.0))
-    perf = report.get("perf") or {}
-    if perf:
-        lines.append("")
-        lines.append("device-time budgets: %d program(s), tolerance "
-                     "+%d%% (slack %dus)"
-                     % (len(perf.get("programs", ())),
-                        int((perf.get("tolerance") or 0) * 100),
-                        int(perf.get("slack_us") or 0)))
-        for p in sorted(perf.get("programs", ()),
-                        key=lambda e: -(e.get("median_us") or 0)):
-            if p.get("over_budget"):
-                verdict = "OVER"
-            elif p.get("unbudgeted"):
-                verdict = "unbudgeted"
-            else:
-                verdict = "ok"
-            lines.append("  %-40s %9.1fus  budget %9s  %s"
-                         % (p.get("name", "?"),
-                            p.get("median_us") or 0.0,
-                            ("%.1fus" % p["budget_us"])
-                            if p.get("budget_us") is not None else "-",
-                            verdict))
     problems = report.get("problems") or []
     for prob in problems:
         lines.append("  problem: %s" % prob)
     return "\n".join(lines)
-
-
-def gate_perf(report):
-    """The --gate-perf exit policy (mirrors --gate-memory over the
-    opprof perf section): 0 when every program is within its
-    device-time budget; 3 when any is over budget or unbudgeted; 4
-    when the comparison cannot measure — topology mismatch or no
-    programs (a gate that cannot measure must fail loudly)."""
-    programs = report.get("programs") or []
-    if not programs or not report.get("topology_match"):
-        why = "no programs in the report" if not programs else \
-            ("baseline n_devices=%s vs live n_devices=%s"
-             % (report.get("baseline_n_devices"),
-                report.get("n_devices")))
-        print("gate-perf: UNMEASURABLE — %s" % why, file=sys.stderr)
-        return 4
-    over = [p["name"] for p in programs if p.get("over_budget")]
-    unbudgeted = [p["name"] for p in programs if p.get("unbudgeted")]
-    if over or unbudgeted:
-        parts = []
-        if over:
-            parts.append("over budget: %s" % ", ".join(sorted(over)))
-        if unbudgeted:
-            parts.append("unbudgeted: %s" % ", ".join(sorted(unbudgeted)))
-        print("gate-perf: FAIL — %s" % "; ".join(parts),
-              file=sys.stderr)
-        return 3
-    print("gate-perf: ok — %d program(s) within device-time budget "
-          "(+%d%% tolerance)" % (len(programs),
-                                 int((report.get("tolerance") or 0)
-                                     * 100)))
-    return 0
 
 
 def gate_overlap(report, threshold):
