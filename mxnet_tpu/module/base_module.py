@@ -96,9 +96,21 @@ class BaseModule:
     def _fit_step(self, data_batch):
         """One fit-loop step: fwd+bwd+update. Subclasses may fuse all
         three into a single compiled program (Module does, when update
-        placement allows)."""
+        placement allows).
+
+        Returns None, or a handle on the step's outputs where they are
+        fresh buffers that the next step neither rewrites nor donates.
+        The fit loop then enqueues the next step before it reads this
+        one's metric, and reads it inside :meth:`_outputs_read_as`."""
         self.forward_backward(data_batch)
         self.update()
+
+    def _outputs_read_as(self, held):
+        """Context manager: while open, ``update_metric`` and
+        ``get_outputs`` read the outputs of the step whose ``_fit_step``
+        returned *held*, whatever step has run since.  Only a module
+        whose ``_fit_step`` returns a handle needs it."""
+        raise _subclass_must_implement("_outputs_read_as")
 
     def fit(self, train_data, eval_data=None, eval_metric="acc",
             epoch_end_callback=None, batch_end_callback=None, kvstore="local",
@@ -157,38 +169,74 @@ class BaseModule:
 
     def _train_one_epoch(self, train_data, train_metric, epoch,
                          batch_end_callback, monitor):
-        """Inner loop of one training epoch over *train_data*."""
+        """Inner loop of one training epoch over *train_data*.
+
+        A one-deep pipeline.  Where ``_fit_step`` hands back its outputs
+        (see there), the batch's metric and callback are *owed*: the next
+        iteration enqueues its own step first and settles them after, so
+        the device runs step N+1 while the host waits for, fetches and
+        folds in step N's outputs; the epoch's end settles the last
+        batch.  Where it hands back nothing (any module but a fused
+        ``Module``, and every step under a monitor) the batch is settled
+        at once: step, metric, callback, as the reference's loop."""
         train_metric.reset()
+
+        def settle(nbatch, batch, held, rec):
+            # batch *nbatch*'s metric, from its own outputs and labels, and
+            # then its callback: in batch order, each after its own step
+            with _tel.span("fit_update_metric", cat="host") \
+                    if rec else _tel.NO_SPAN:
+                if held is None:
+                    self.update_metric(train_metric, batch.label)
+                else:
+                    with self._outputs_read_as(held):
+                        self.update_metric(train_metric, batch.label)
+            if monitor is not None:
+                monitor.toc_print()
+            with _tel.span("fit_callback", cat="host") \
+                    if rec else _tel.NO_SPAN:
+                _fire(batch_end_callback,
+                      BatchEndParam(epoch=epoch, nbatch=nbatch,
+                                    eval_metric=train_metric,
+                                    locals=locals()))
+
+        owed = None             # (nbatch, batch, held) not settled yet
         # the iterator's next() runs between two fit_batch spans (io.py
         # books it as data_batch), so it carries neither batch's id
         for nbatch, batch in enumerate(train_data):
+            # nbatch names the batch whose step this iteration enqueues;
+            # the metric and callback under it are the owed batch's
             with _tel.span("fit_batch", cat="batch",
                            args={"epoch": epoch, "nbatch": nbatch}):
                 # the root asked whether anything records; its children
                 # here read the answer (off: one bool test each)
                 rec = _tel.trace_active()
                 self.prepare(batch)
-                if monitor is not None:
-                    monitor.tic()
                 if monitor is None:
-                    self._fit_step(batch)
+                    held = self._fit_step(batch)
                 else:
+                    monitor.tic()
                     self.forward_backward(batch)
                     self.update()
-                with _tel.span("fit_update_metric", cat="host") \
-                        if rec else _tel.NO_SPAN:
-                    self.update_metric(train_metric, batch.label)
-                if monitor is not None:
-                    monitor.toc_print()
+                    held = None
                 # step boundary (see gluon/trainer.py): checkpoint snapshot
-                # point + pending-SIGTERM honor, with the epoch cursor
+                # point + pending-SIGTERM honor.  Noted with the step the
+                # module's parameters now hold and the cursor of its batch,
+                # whichever batch's callback comes next
                 _ckpt_hooks.note_step_boundary(epoch=epoch, batch=nbatch)
-                with _tel.span("fit_callback", cat="host") \
-                        if rec else _tel.NO_SPAN:
-                    _fire(batch_end_callback,
-                          BatchEndParam(epoch=epoch, nbatch=nbatch,
-                                        eval_metric=train_metric,
-                                        locals=locals()))
+                if owed is not None:
+                    _tel.bump("fit_step_overlapped")
+                    settle(*owed, rec)
+                owed = (nbatch, batch, held)
+                if held is None:
+                    settle(*owed, rec)
+                    owed = None
+        if owed is not None:
+            # the drain: a root of its own, with no step under it
+            with _tel.span("fit_batch", cat="batch",
+                           args={"epoch": epoch, "nbatch": owed[0],
+                                 "drain": True}):
+                settle(*owed, _tel.trace_active())
 
     def score(self, eval_data, eval_metric, num_batch=None,
               batch_end_callback=None, score_end_callback=None, reset=True,

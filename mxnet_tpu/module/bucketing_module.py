@@ -262,7 +262,10 @@ class BucketingModule(BaseModule):
     def _fit_step(self, data_batch):
         """Per-bucket fused step: switch to the batch's bucket, then one
         donated fwd+bwd+update program on that bucket's module (each
-        bucket keeps its own compiled step)."""
+        bucket keeps its own compiled step).  Returns None whatever the
+        active module returned: the next batch may switch the module that
+        ``update_metric`` reads, so the fit loop settles each batch at
+        once."""
         self._require_ready()
         self._switch_for_batch(data_batch)
         self._params_dirty = True

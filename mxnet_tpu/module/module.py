@@ -7,6 +7,7 @@ and the kvstore helpers in ``model.py``.
 """
 from __future__ import annotations
 
+import contextlib
 import logging
 import os
 import warnings
@@ -454,7 +455,14 @@ class Module(BaseModule):
         """fit-loop step. Fast path: fwd+bwd+optimizer as ONE donated
         compiled program (cached_step.CachedTrainStep) when the update
         placement allows — single logical param copy, optimizer on
-        worker. Falls back to forward_backward + update otherwise."""
+        worker. Falls back to forward_backward + update otherwise.
+
+        The fused program's outputs are new handles every step and none
+        of its donated inputs, so they are handed back, with the group
+        that bound them (a later batch of another shape rebinds): the
+        fit loop may enqueue the next step before it reads this one's
+        metric (BaseModule._fit_step).  The fallback, whose outputs stay
+        in its executors (one a device under a kvstore), returns None."""
         self._maybe_reshape(data_batch)
         step = self._get_cached_step()
         if step is not None:
@@ -462,14 +470,28 @@ class Module(BaseModule):
             if data_batch.label:
                 feed.update(zip(self._label_names, data_batch.label))
             try:
-                step.run(feed)
+                outputs = step.run(feed)
                 self._params_dirty = True
-                return
+                return self._exec_group, outputs
             except NotImplementedError:
                 # optimizer has no pure update_step: permanently fall back
                 self._cached_step_unusable = True
                 self._cached_step = None
         super()._fit_step(data_batch)
+
+    @contextlib.contextmanager
+    def _outputs_read_as(self, held):
+        # every reader (the groups' update_metric, which slices the labels
+        # as its own batch was split, get_outputs, a subclass's own) goes
+        # through the bound group and its executor's output list
+        group, outputs = held
+        ex = group.execs[0]
+        newest = self._exec_group, ex._outputs
+        self._exec_group, ex._outputs = group, outputs
+        try:
+            yield
+        finally:
+            self._exec_group, ex._outputs = newest
 
     def _get_cached_step(self):
         from .cached_step import CachedTrainStep, fused_step_enabled

@@ -543,6 +543,9 @@ COUNTERS = {
     "module_step_carried": "CachedTrainStep executions that took every "
                            "param, aux and optimizer-state input by "
                            "identity from the previous step (no _place)",
+    "fit_step_overlapped": "Module.fit batches whose step was enqueued "
+                           "before the previous batch's metric was read "
+                           "(the fit loop's one step of overlap)",
     "executor_remat_segments": "recomputation segments (force_mirroring "
                                "+ mirror_stage) wrapped in jax.checkpoint "
                                "at bind",
@@ -788,10 +791,13 @@ HISTOGRAMS = {
 SPANS = {
     "trainer_step": "one Trainer.step (the step-timeline anchor)",
     "data_batch": "one data-iterator batch production (io tier)",
-    "fit_batch": "one Module.fit batch, prepare to the batch-end "
-                 "callback's return (batch root: mints the batch's id)",
-    "fit_update_metric": "the fit loop's metric update for one batch",
-    "fit_callback": "the fit loop's batch-end callbacks for one batch",
+    "fit_batch": "one Module.fit iteration, prepare to the batch-end "
+                 "callback's return (batch root: mints the batch's id; "
+                 "nbatch is the batch whose step it enqueues)",
+    "fit_update_metric": "the fit loop's metric update for one batch "
+                         "(overlapped: the batch before the root's)",
+    "fit_callback": "the fit loop's batch-end callbacks for one batch "
+                    "(overlapped: the batch before the root's)",
     "metric_wait": "a metric blocked until a device array is ready "
                    "(the device is still busy: overlap)",
     "metric_fetch": "a metric's device-to-host copy of a ready array",

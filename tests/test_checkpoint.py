@@ -381,6 +381,74 @@ def test_module_path_snapshot_restore(tmp_path):
                                       want_arg[k].asnumpy())
 
 
+@pytest.mark.parametrize("order", ["overlapped", "serial"])
+def test_module_mid_epoch_boundary_resumes_exactly(tmp_path, order):
+    """A fused Module's fit runs one step ahead of its metric (ISSUE 30).
+    The boundary after step 3 must still hold three steps' parameters and
+    a cursor past batch 3: restoring it into a fresh module and finishing
+    the epoch replays no step and skips none."""
+    from mxnet_tpu import symbol as sym
+
+    def build():
+        net = sym.var("data")
+        net = sym.FullyConnected(net, num_hidden=8, name="fc1")
+        net = sym.Activation(net, act_type="relu", name="relu1")
+        net = sym.FullyConnected(net, num_hidden=4, name="fc2")
+        mod = mx.mod.Module(sym.SoftmaxOutput(net, name="softmax"),
+                            context=mx.cpu())
+        if order == "serial":
+            fit_step = mod._fit_step
+
+            def serial_step(batch):     # hands nothing back: settled at once
+                fit_step(batch)
+
+            mod._fit_step = serial_step
+        rng = np.random.RandomState(0)
+        train = mx.io.NDArrayIter(rng.randn(48, 6).astype(np.float32),
+                                  rng.randint(0, 4, 48).astype(np.float32),
+                                  batch_size=8, shuffle=True)
+        return mod, train
+
+    def fit(mod, train, callback=None):
+        mod.fit(train, optimizer="sgd", num_epoch=1, eval_metric="acc",
+                optimizer_params={"learning_rate": 0.1, "momentum": 0.9},
+                batch_end_callback=callback)
+        return {k: v.asnumpy() for k, v in mod.get_params()[0].items()}
+
+    mx.random.seed(5)
+    mod, train = build()
+    mgr = checkpoint.CheckpointManager(str(tmp_path), module=mod,
+                                       data_iter=train, every_steps=3,
+                                       keep=10)
+    before = telemetry.counter("fit_step_overlapped")
+    want = fit(mod, train)
+    assert telemetry.counter("fit_step_overlapped") - before \
+        == (5 if order == "overlapped" else 0)
+    mgr.wait()
+    assert mgr.step == 6
+    mgr.close()
+
+    mx.random.seed(99)              # the checkpoint brings its own stream
+    mod2, train2 = build()
+    mod2.bind(data_shapes=train2.provide_data,
+              label_shapes=train2.provide_label, for_training=True)
+    mod2.init_params()
+    mod2.init_optimizer(optimizer="sgd",
+                        optimizer_params={"learning_rate": 0.1,
+                                          "momentum": 0.9})
+    mgr2 = checkpoint.CheckpointManager(str(tmp_path), module=mod2,
+                                        data_iter=train2)
+    assert mgr2.restore(step=3) == 3
+    assert (mgr2._epoch, mgr2._batch) == (0, 2)
+    mgr2.close()
+    seen = []
+    with pytest.warns(UserWarning, match="already initialized"):
+        got = fit(mod2, train2, callback=lambda p: seen.append(p.nbatch))
+    assert seen == [0, 1, 2]        # the epoch's other three batches
+    for name in want:
+        np.testing.assert_array_equal(got[name], want[name], err_msg=name)
+
+
 def test_kvstore_checkpoint_state_string_keyed_updater():
     """update_on_kvstore updaters key by param NAME (kvstore._updater_key
     falls through to the string): the checkpoint blob must round-trip

@@ -116,15 +116,25 @@ def test_fit_under_a_profiler_session_records_every_span(tmp_path):
     events = ring()
     names = collections.Counter(e["name"] for e in events)
     assert set(names) == set(FIT_SPANS)
-    assert names["fit_batch"] == names["module_train_step"] == BATCHES
+    # the fused Module's fit is one step ahead of its metric: a root per
+    # step and one more for the drain of the last batch's metric
+    assert names["module_train_step"] == BATCHES
+    assert names["fit_batch"] == BATCHES + 1
+    assert names["fit_update_metric"] == names["fit_callback"] == BATCHES
     assert names["metric_wait"] == names["metric_fetch"] == 2 * BATCHES
 
-    # one id per batch, shared by everything under the batch's root;
-    # data_batch runs between two roots and carries neither's id
-    roots = [e for e in events if e["name"] == "fit_batch"]
+    # one id per root, shared by everything under it; data_batch runs
+    # between two roots and carries neither's id
+    roots = sorted((e for e in events if e["name"] == "fit_batch"),
+                   key=lambda e: e["ts"])
     ids = [e["args"]["trace_id"] for e in roots]
-    assert len(set(ids)) == BATCHES
-    assert sorted(e["args"]["nbatch"] for e in roots) == list(range(BATCHES))
+    assert len(set(ids)) == BATCHES + 1
+    # nbatch names the batch whose step the root enqueued; the drain names
+    # the batch it settles
+    assert [e["args"]["nbatch"] for e in roots] \
+        == list(range(BATCHES)) + [BATCHES - 1]
+    assert [e["args"].get("drain", False) for e in roots] \
+        == [False] * BATCHES + [True]
     for e in events:
         if e["name"] == "data_batch":
             assert "trace_id" not in e["args"] and e["args"]["depth"] == 0
@@ -135,10 +145,23 @@ def test_fit_under_a_profiler_session_records_every_span(tmp_path):
         if e["name"] != "data_batch":
             by_batch[e["args"]["trace_id"]][e["name"]].append(
                 (e["ts"], e["ts"] + e["dur"]))
-    for spans in by_batch.values():
+    for i, batch_id in enumerate(ids):
+        spans = by_batch[batch_id]
+        # at most one step, one metric update and one callback a root, in
+        # that order: the first root has a step only, the drain no step
+        assert len(spans["module_train_step"]) == (i < BATCHES)
+        assert len(spans["fit_update_metric"]) == (i > 0)
+        assert len(spans["fit_callback"]) == (i > 0)
+        if 0 < i < BATCHES:
+            assert spans["module_train_step"][0][1] \
+                <= spans["fit_update_metric"][0][0]
+        if i > 0:
+            assert spans["fit_update_metric"][0][1] \
+                <= spans["fit_callback"][0][0]
         # the seven children, by name, in every module_train_step, and
         # each child inside its parent in time
-        assert set(STEP_CHILDREN) <= set(spans)
+        if i < BATCHES:
+            assert set(STEP_CHILDREN) <= set(spans)
         for child, parent in PARENT.items():
             for iv in spans[child]:
                 assert inside(iv, spans[parent]), (child, parent)
