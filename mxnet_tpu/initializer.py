@@ -15,6 +15,7 @@ import numpy as np
 from .base import Registry, MXNetError
 from . import ndarray as nd
 from . import random as _random
+from .ops.registry import parse_attr_string
 
 __all__ = ["InitDesc", "Initializer", "register", "create", "Zero", "One",
            "Constant", "Uniform", "Normal", "Orthogonal", "Xavier",
@@ -197,8 +198,17 @@ class Orthogonal(Initializer):
         arr[:] = (self.scale * basis).reshape(arr.shape)
 
 
-def _conv_fans(shape):
-    """(fan_in, fan_out) with trailing spatial dims folded in."""
+def _conv_fans(shape, stacked=False):
+    """(fan_in, fan_out) with trailing spatial dims folded in; of one
+    [in, out] matrix of a *stacked* [count, in, out] tensor (a variable
+    that carries the attribute ``__stacked__``: each expert of a
+    sparse-expert layer is a matrix of its own, not a convolution's
+    spatial tap)."""
+    if stacked:
+        if len(shape) != 3:
+            raise ValueError("a __stacked__ tensor is [count, in, out], "
+                             "got %s" % (tuple(shape),))
+        return shape[1], shape[2]
     spatial = np.prod(shape[2:]) if len(shape) > 2 else 1.0
     return shape[1] * spatial, shape[0] * spatial
 
@@ -225,7 +235,10 @@ class Xavier(Initializer):
             factor_fn = self._FACTORS[self.factor_type]
         except KeyError:
             raise ValueError("Incorrect factor type")
-        sigma = np.sqrt(self.magnitude / factor_fn(*_conv_fans(arr.shape)))
+        stacked = parse_attr_string(
+            getattr(name, "attrs", {}).get("__stacked__", False))
+        sigma = np.sqrt(self.magnitude /
+                        factor_fn(*_conv_fans(arr.shape, stacked)))
         if self.rnd_type == "uniform":
             arr[:] = _random.host_rng().uniform(-sigma, sigma, arr.shape)
         elif self.rnd_type == "gaussian":

@@ -1,4 +1,13 @@
-"""Mixture-of-experts FFN with expert parallelism (EP).
+"""Top-1 switch layer with a capacity, for expert parallelism (EP).
+
+What this is: every token goes to ONE expert (the argmax of a softmax
+router), every expert has room for ``capacity_factor * tokens / experts``
+tokens, and a token over its expert's capacity is DROPPED (it passes
+through the caller's residual only).  The functional SPMD models use it
+outside ``Module``.  The dropless top-k layer — every token keeps all k
+of its experts whatever the load, grouped matrix product, a graph op for
+``Module.fit`` — is ``_contrib_SparseMoE`` in ``ops/moe.py``; the
+expert choice here is that module's ``topk_route`` with k = 1.
 
 Beyond-reference capability (the reference predates MoE): a
 switch-style top-1 routed expert FFN in the Mesh-TensorFlow dispatch
@@ -22,6 +31,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from ..ops.moe import topk_route
 from ..parallel import mesh as mesh_mod
 
 __all__ = ["init_moe_params", "moe_ffn", "moe_param_specs"]
@@ -68,8 +78,8 @@ def _route_top1(logits, capacity):
     """
     n, num_experts = logits.shape
     gates = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    expert_idx = jnp.argmax(gates, axis=-1)                 # [n]
-    onehot = jax.nn.one_hot(expert_idx, num_experts,
+    expert_idx, gate_val = topk_route(gates, 1, normalise=False)
+    onehot = jax.nn.one_hot(expert_idx[:, 0], num_experts,
                             dtype=jnp.float32)              # [n, E]
     # position of each token within its chosen expert's queue
     pos = jnp.cumsum(onehot, axis=0) * onehot               # [n, E], 1-based
@@ -78,8 +88,7 @@ def _route_top1(logits, capacity):
     slot_oh = jax.nn.one_hot(jnp.max(slot, axis=-1), capacity,
                              dtype=jnp.float32)             # [n, C]
     dispatch = (onehot * within)[:, :, None] * slot_oh[:, None, :]
-    gate_val = jnp.sum(gates * onehot, axis=-1)             # [n]
-    combine = dispatch * gate_val[:, None, None]
+    combine = dispatch * gate_val[:, :, None]
     return dispatch, combine
 
 

@@ -8,6 +8,7 @@ from . import random_ops  # noqa: F401
 from . import optim_ops  # noqa: F401
 from . import contrib  # noqa: F401
 from . import lm  # noqa: F401
+from . import moe  # noqa: F401
 from . import custom  # noqa: F401
 from . import ssd  # noqa: F401
 from . import rcnn  # noqa: F401

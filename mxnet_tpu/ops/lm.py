@@ -1,7 +1,8 @@
-"""Language-model ops: RMSNorm, rotary embedding, power retention and a
-blocked softmax-cross-entropy head.
+"""Language-model ops: RMSNorm, rotary embedding, power retention, causal
+grouped-query attention, a gated short convolution and a blocked
+softmax-cross-entropy head (the sparse-expert layer is ``ops/moe.py``).
 
-No reference counterpart: the reference's op corpus predates all four.
+No reference counterpart: the reference's op corpus predates all of them.
 They are registered like every other op so that a language model is a
 ``Symbol`` and trains through ``Module.fit``.  Every op accumulates in
 float32 whatever its operands' dtype (``docs/LM_OPS.md`` has the
@@ -71,6 +72,110 @@ def _power_retention(query, key, value, log_gate, degree=2, chunk=128,
     _tel.bump("power_retention_chunks", -(-query.shape[1] // chunk))
     return power_retention(query, key, value, log_gate.astype(jnp.float32),
                            chunk, float(eps), kernel)
+
+
+def _masked_softmax_attention(q, k, v, scale, causal):
+    """softmax(q k^T scale) v with the scores whole, float32 statistics:
+    q [B, Hq, S, d], k and v [B, Hkv, S, d] -> [B, Hq, S, d]."""
+    b, hq, s, d = q.shape
+    hkv = k.shape[1]
+    qg = q.reshape(b, hkv, hq // hkv, s, d)
+    score = jnp.einsum("bhgqd,bhkd->bhgqk", qg, k,
+                       preferred_element_type=jnp.float32) * scale
+    if causal:
+        keep = jnp.arange(s)[:, None] >= jnp.arange(s)[None, :]
+        score = jnp.where(keep, score, -jnp.inf)
+    p = jax.nn.softmax(score, axis=-1).astype(v.dtype)
+    out = jnp.einsum("bhgqk,bhkd->bhgqd", p, v,
+                     preferred_element_type=jnp.float32)
+    return out.reshape(q.shape).astype(q.dtype)
+
+
+_ATTENTION_TILE = 512      # square tiles of the Pallas forward and backward
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def causal_attention(q, k, v, scale, causal, use_kernel):
+    """Softmax attention over grouped key/value heads: q [B, S, Hq, d],
+    k and v [B, S, Hkv, d] -> [B, S, Hq, d]; query head i reads key/value
+    head i // (Hq // Hkv).  With *use_kernel* forward and backward are the
+    Pallas ``flash_attention`` kernels (tiles of 512), else the masked
+    softmax in ``jnp`` and its ``jax.vjp``."""
+    return _attention_fwd(q, k, v, scale, causal, use_kernel)[0]
+
+
+def _heads_first(*xs):
+    return tuple(x.transpose(0, 2, 1, 3) for x in xs)
+
+
+def _attention_fwd(q, k, v, scale, causal, use_kernel):
+    from . import pallas_kernels as pk
+    with jax.named_scope("causal_attention_fwd"):
+        qh, kh, vh = _heads_first(q, k, v)
+        if use_kernel:
+            out, lse = pk._flash_fwd_impl(qh, kh, vh, causal, scale,
+                                          _ATTENTION_TILE, _ATTENTION_TILE,
+                                          False)
+            saved = (qh, kh, vh, out, lse)
+        else:
+            out = _masked_softmax_attention(qh, kh, vh, scale, causal)
+            saved = (qh, kh, vh)
+    return out.transpose(0, 2, 1, 3), saved
+
+
+def _attention_bwd(scale, causal, use_kernel, saved, do):
+    from . import pallas_kernels as pk
+    with jax.named_scope("causal_attention_bwd"):
+        (doh,) = _heads_first(do)
+        if use_kernel:
+            grads = pk._flash_bwd_impl(*saved, doh, causal, scale,
+                                       _ATTENTION_TILE, False)
+        else:
+            grads = jax.vjp(lambda *x: _masked_softmax_attention(
+                *x, scale, causal), *saved)[1](doh)
+        return _heads_first(*grads)
+
+
+causal_attention.defvjp(_attention_fwd, _attention_bwd)
+
+
+@register("_contrib_CausalAttention", aliases=["CausalAttention"])
+def _causal_attention(query, key, value, scale=None, causal=True, **kw):
+    """Softmax attention with grouped key/value heads: query
+    [B, S, Hq, d], key/value [B, S, Hkv, d] -> [B, S, Hq, d]; scores are
+    scaled by ``scale`` (1/sqrt(d) if None) and masked to s <= t when
+    ``causal``.  The forward is the Pallas kernel on a TPU and the same
+    mathematics in ``jnp`` elsewhere."""
+    d = query.shape[-1]
+    scale = 1.0 / d ** 0.5 if scale is None else float(scale)
+    _tel.bump("causal_attention_traced")
+    return causal_attention(query, key, value, scale, bool(causal),
+                            jax.default_backend() == "tpu")
+
+
+def short_conv(data, weight):
+    """The gated causal short convolution of one layer: data [B, S, 3C]
+    holds (Bg, Cg, u) in that order, weight [C, K] is depthwise and has no
+    bias: c_t = sum_j weight[:, j] (Bg u)_{t-K+1+j}, zeros before the
+    sequence; returns Cg * c, [B, S, C]."""
+    taps = weight.shape[1]
+    with jax.named_scope("short_conv"):
+        bg, cg, u = jnp.split(data, 3, axis=-1)
+        bu = jnp.pad((bg * u).astype(jnp.float32),
+                     [(0, 0), (taps - 1, 0), (0, 0)])
+        s = data.shape[1]
+        w = weight.astype(jnp.float32)
+        c = sum(w[:, j] * bu[:, j:j + s] for j in range(taps))
+        return cg * c.astype(data.dtype)
+
+
+@register("_contrib_ShortConv", aliases=["ShortConv"])
+def _short_conv(data, weight, **kw):
+    """Gated causal depthwise convolution along the sequence: data
+    [B, S, 3C] (the input projection's Bg, Cg, u), weight [C, K] ->
+    [B, S, C] = Cg * conv(Bg * u)."""
+    _tel.bump("short_conv_traced")
+    return short_conv(data, weight)
 
 
 def _head_blocks(data, label, block):

@@ -10,8 +10,10 @@ kernels are Pallas.  This module holds the framework's built-in kernels:
   VMEM while fp32 accumulators persist in scratch across k steps — true
   streaming, O(block·D) VMEM regardless of sequence length.  Causal
   programs whose whole K tile is masked skip compute via ``pl.when``.
-  Differentiable via ``jax.custom_vjp``; the backward recomputes scores in
-  q-row chunks (O(chunk·S) memory, not O(S²)).
+  Grouped key/value heads are an index in the block map, never a repeated
+  tensor.  Differentiable via ``jax.custom_vjp``: the forward also writes
+  the softmax's log-sum-exp, and two Pallas kernels (dq; dk and dv) recompute
+  the probabilities tile by tile from it, O(block²) memory.
 
 The kernels compile with Mosaic (``interpret=False``, the default) and
 that only works on a TPU.  ``interpret=True`` is the explicit CPU-test
@@ -50,8 +52,8 @@ def _lane_cols(x, n):
     return x if reps == 1 else jnp.tile(x, (1, reps))
 
 
-def _attn_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
-                 block_q, block_k, causal, sm_scale, seq_len):
+def _attn_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
+                 *, block_q, block_k, causal, sm_scale, seq_len):
     """One (bh, qi, ki) program. Scratch (acc/m/l) carries across ki —
     the innermost grid axis is sequential on TPU.  Row statistics stay
     2-D ([block_q, 128], every lane equal) end to end: Mosaic lays
@@ -109,12 +111,21 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
 
     @pl.when(ki == num_k - 1)
     def _finalize():
-        denom = _lane_cols(jnp.maximum(l_ref[:], _TINY), head_dim)
-        o_ref[:] = (acc_ref[:] / denom).astype(o_ref.dtype)
+        total = jnp.maximum(l_ref[:], _TINY)
+        o_ref[:] = (acc_ref[:] / _lane_cols(total, head_dim)) \
+            .astype(o_ref.dtype)
+        # log of the softmax's denominator, for the backward kernels
+        lse_ref[:] = m_ref[:] + jnp.log(total)
 
 
 def _flash_fwd_impl(q, k, v, causal, sm_scale, block_q, block_k, interpret):
+    """(output [B, H, S, D], log-sum-exp of the scaled scores [B, H, S])."""
     b, h, s, d = q.shape
+    hkv = k.shape[1]
+    if h % hkv or v.shape[1] != hkv:
+        raise ValueError("flash_attention: %d query heads over %d/%d "
+                         "key/value heads" % (h, hkv, v.shape[1]))
+    group = np.int32(h // hkv)
     if d > _LANES and d % _LANES:
         raise ValueError("flash_attention: head dim %d must be <= %d or a "
                          "multiple of it" % (d, _LANES))
@@ -128,8 +139,8 @@ def _flash_fwd_impl(q, k, v, causal, sm_scale, block_q, block_k, interpret):
         raise ValueError("flash_attention: block_k %d must be <= %d or a "
                          "multiple of it" % (bk, _LANES))
     qf = q.reshape(b * h, s, d)
-    kf = k.reshape(b * h, s, d)
-    vf = v.reshape(b * h, s, d)
+    kf = k.reshape(b * hkv, s, d)
+    vf = v.reshape(b * hkv, s, d)
     # pad K/V to a block multiple: an out-of-bounds block index CLAMPS,
     # silently shifting the tail tile — padded keys are masked by seq_len
     s_pad = ((s + bk - 1) // bk) * bk
@@ -140,17 +151,24 @@ def _flash_fwd_impl(q, k, v, causal, sm_scale, block_q, block_k, interpret):
     kernel = functools.partial(_attn_kernel, block_q=bq, block_k=bk,
                                causal=causal, sm_scale=scale, seq_len=s)
     zero = np.int32(0)      # a bare 0 is an i64 block index under x64
-    out = pl.pallas_call(
+    # query head bh reads key/value head bh // group (heads are the inner
+    # axis of both): the repeat is an index, never a tensor
+    out, lse = pl.pallas_call(
         kernel,
         grid=(b * h, pl.cdiv(s, bq), s_pad // bk),
         in_specs=[
             pl.BlockSpec((None, bq, d), lambda bh, i, t: (bh, i, zero)),
-            pl.BlockSpec((None, bk, d), lambda bh, i, t: (bh, t, zero)),
-            pl.BlockSpec((None, bk, d), lambda bh, i, t: (bh, t, zero)),
+            pl.BlockSpec((None, bk, d),
+                         lambda bh, i, t: (bh // group, t, zero)),
+            pl.BlockSpec((None, bk, d),
+                         lambda bh, i, t: (bh // group, t, zero)),
         ],
-        out_specs=pl.BlockSpec((None, bq, d),
-                               lambda bh, i, t: (bh, i, zero)),
-        out_shape=jax.ShapeDtypeStruct((b * h, s, d), q.dtype),
+        out_specs=[
+            pl.BlockSpec((None, bq, d), lambda bh, i, t: (bh, i, zero)),
+            pl.BlockSpec((None, bq, _LANES), lambda bh, i, t: (bh, i, zero)),
+        ],
+        out_shape=[jax.ShapeDtypeStruct((b * h, s, d), q.dtype),
+                   jax.ShapeDtypeStruct((b * h, s, _LANES), jnp.float32)],
         scratch_shapes=[
             pltpu.VMEM((bq, d), jnp.float32),
             pltpu.VMEM((bq, _LANES), jnp.float32),
@@ -161,78 +179,223 @@ def _flash_fwd_impl(q, k, v, causal, sm_scale, block_q, block_k, interpret):
         name="flash_attention_fwd",
         interpret=interpret,
     )(qf, kf, vf)
-    return out.reshape(b, h, s, d)
+    return out.reshape(b, h, s, d), lse[..., 0].reshape(b, h, s)
 
 
-def _chunked_attn_grads(q, k, v, do, causal, sm_scale, chunk=512):
-    """Recompute backward in q-row chunks: memory O(chunk·S) per step
-    instead of materializing the full S×S score/softmax matrices."""
+def _attn_probs(s, lse, qi, ki, *, block_q, block_k, causal, seq_len):
+    """exp(s - lse) on a [block_q, block_k] tile of scaled scores, zero
+    where the key is padding or (causal) in the query's future."""
+    k_pos = ki * block_k + jax.lax.broadcasted_iota(
+        jnp.int32, (block_q, block_k), 1)
+    valid = k_pos < seq_len
+    if causal:
+        q_pos = qi * block_q + jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, block_k), 0)
+        valid = jnp.logical_and(valid, q_pos >= k_pos)
+    return jnp.where(valid, jnp.exp(s - _lane_cols(lse, block_k)),
+                     np.float32(0.0))
+
+
+def _attn_tile_grads(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qi, ki,
+                     *, sm_scale, **tile):
+    """(p, ds) of one tile: the probabilities recomputed from the saved
+    log-sum-exp, and the scaled scores' cotangent p * (do v^T - delta),
+    both in the operands' dtype for the products that follow."""
+    precision = jax.lax.Precision.HIGHEST \
+        if q_ref.dtype == jnp.float32 else None
+    s = jax.lax.dot_general(q_ref[:], k_ref[:], (((1,), (1,)), ((), ())),
+                            precision=precision,
+                            preferred_element_type=jnp.float32)
+    p = _attn_probs(s * np.float32(sm_scale), lse_ref[:], qi, ki, **tile)
+    dp = jax.lax.dot_general(do_ref[:], v_ref[:], (((1,), (1,)), ((), ())),
+                             precision=precision,
+                             preferred_element_type=jnp.float32)
+    ds = p * (dp - _lane_cols(delta_ref[:], tile["block_k"]))
+    return p.astype(q_ref.dtype), ds.astype(q_ref.dtype), precision
+
+
+def _attn_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
+                    acc_ref, *, sm_scale, **tile):
+    """One (bh, qi, ki) program of dq = scale * ds k, ki sequential."""
+    qi, ki = pl.program_id(1), pl.program_id(2)
+
+    @pl.when(ki == 0)
+    def _init():
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+
+    live = True
+    if tile["causal"]:
+        live = (qi + 1) * tile["block_q"] - 1 >= ki * tile["block_k"]
+
+    @pl.when(live)
+    def _step():
+        _, ds, precision = _attn_tile_grads(
+            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qi, ki,
+            sm_scale=sm_scale, **tile)
+        acc_ref[:] = acc_ref[:] + jax.lax.dot_general(
+            ds, k_ref[:], (((1,), (0,)), ((), ())), precision=precision,
+            preferred_element_type=jnp.float32)
+
+    @pl.when(ki == pl.num_programs(2) - 1)
+    def _finalize():
+        dq_ref[:] = (acc_ref[:] * np.float32(sm_scale)).astype(dq_ref.dtype)
+
+
+def _attn_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
+                     dv_ref, dk_acc, dv_acc, *, sm_scale, **tile):
+    """One (key/value head, ki, query head of its group, qi) program of
+    dv = p^T do and dk = scale * ds^T q; the last two axes sequential, so
+    a key/value head's gradient gathers over the query heads that read
+    it."""
+    ki, gi, qi = pl.program_id(1), pl.program_id(2), pl.program_id(3)
+
+    @pl.when(jnp.logical_and(gi == 0, qi == 0))
+    def _init():
+        dk_acc[:] = jnp.zeros_like(dk_acc)
+        dv_acc[:] = jnp.zeros_like(dv_acc)
+
+    live = True
+    if tile["causal"]:
+        live = (qi + 1) * tile["block_q"] - 1 >= ki * tile["block_k"]
+
+    @pl.when(live)
+    def _step():
+        p, ds, precision = _attn_tile_grads(
+            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qi, ki,
+            sm_scale=sm_scale, **tile)
+        dv_acc[:] = dv_acc[:] + jax.lax.dot_general(
+            p, do_ref[:], (((0,), (0,)), ((), ())), precision=precision,
+            preferred_element_type=jnp.float32)
+        dk_acc[:] = dk_acc[:] + jax.lax.dot_general(
+            ds, q_ref[:], (((0,), (0,)), ((), ())), precision=precision,
+            preferred_element_type=jnp.float32)
+
+    @pl.when(jnp.logical_and(gi == pl.num_programs(2) - 1,
+                             qi == pl.num_programs(3) - 1))
+    def _finalize():
+        dk_ref[:] = (dk_acc[:] * np.float32(sm_scale)).astype(dk_ref.dtype)
+        dv_ref[:] = dv_acc[:].astype(dv_ref.dtype)
+
+
+def _flash_bwd_impl(q, k, v, out, lse, do, causal, sm_scale, block,
+                    interpret):
+    """Gradients of ``_flash_fwd_impl`` by two Pallas kernels over square
+    tiles of *block*: the probabilities are recomputed from the forward's
+    log-sum-exp, causal tiles in a query's future are skipped, and a
+    key/value head's gradient gathers over its group of query heads
+    inside the kernel.  Everything is padded with zeros to whole tiles: a
+    padded query row has do = 0 and delta = 0 and adds nothing."""
     b, h, s, d = q.shape
+    hkv = k.shape[1]
+    group = np.int32(h // hkv)
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(d)
-    c = min(chunk, s)
-    n = (s + c - 1) // c
-    s_pad = n * c
-    f32 = jnp.float32
+    blk = min(block, -(-s // _LANES) * _LANES)
+    n = -(-s // blk)
+    s_pad = n * blk
 
-    def padq(x):
+    def rows(x, heads):
+        x = x.reshape((b * heads, s) + x.shape[3:])
         if s_pad != s:
-            x = jnp.pad(x, [(0, 0), (0, 0), (0, s_pad - s), (0, 0)])
-        return x.astype(f32).reshape(b, h, n, c, d).transpose(2, 0, 1, 3, 4)
+            x = jnp.pad(x, [(0, 0), (0, s_pad - s)] + [(0, 0)] * (x.ndim - 2))
+        return x
 
-    qs, dos = padq(q), padq(do)
-    kf = k.astype(f32)
-    vf = v.astype(f32)
-    k_pos = jnp.arange(s)
+    def lanes(x):           # [b, h, s] -> [b*h, s_pad, 128], every lane equal
+        return jnp.broadcast_to(rows(x, h)[..., None],
+                                (b * h, s_pad, _LANES))
 
-    def body(carry, inp):
-        dk_acc, dv_acc, i = carry
-        q_c, do_c = inp
-        s_c = jnp.einsum("bhqd,bhkd->bhqk", q_c, kf) * scale
-        q_pos = i * c + jnp.arange(c)
-        valid = (q_pos[:, None] < s)
-        if causal:
-            valid = jnp.logical_and(valid, q_pos[:, None] >= k_pos[None, :])
-        s_c = jnp.where(valid, s_c, _NEG)
-        p = jax.nn.softmax(s_c, axis=-1)
-        dv_acc = dv_acc + jnp.einsum("bhqk,bhqd->bhkd", p, do_c)
-        dp = jnp.einsum("bhqd,bhkd->bhqk", do_c, vf)
-        ds = p * (dp - jnp.sum(dp * p, axis=-1, keepdims=True))
-        ds = jnp.where(valid, ds, 0.0)
-        dq_c = jnp.einsum("bhqk,bhkd->bhqd", ds, kf) * scale
-        dk_acc = dk_acc + jnp.einsum("bhqk,bhqd->bhkd", ds, q_c) * scale
-        return (dk_acc, dv_acc, i + 1), dq_c
+    do = do.astype(q.dtype)
+    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
+                    axis=-1)
+    qf, dof, kf, vf = rows(q, h), rows(do, h), rows(k, hkv), rows(v, hkv)
+    lsef, deltaf = lanes(lse), lanes(delta)
+    tile = dict(block_q=blk, block_k=blk, causal=causal, seq_len=s,
+                sm_scale=scale)
+    zero = np.int32(0)
 
-    zeros = jnp.zeros((b, h, s, d), f32)
-    (dk, dv, _), dq_chunks = jax.lax.scan(
-        body, (zeros, zeros, jnp.int32(0)), (qs, dos))
-    dq = dq_chunks.transpose(1, 2, 0, 3, 4).reshape(b, h, s_pad, d)[:, :, :s]
-    return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
+    dq = pl.pallas_call(
+        functools.partial(_attn_dq_kernel, **tile),
+        grid=(b * h, n, n),
+        in_specs=[
+            pl.BlockSpec((None, blk, d), lambda bh, i, t: (bh, i, zero)),
+            pl.BlockSpec((None, blk, d),
+                         lambda bh, i, t: (bh // group, t, zero)),
+            pl.BlockSpec((None, blk, d),
+                         lambda bh, i, t: (bh // group, t, zero)),
+            pl.BlockSpec((None, blk, d), lambda bh, i, t: (bh, i, zero)),
+            pl.BlockSpec((None, blk, _LANES),
+                         lambda bh, i, t: (bh, i, zero)),
+            pl.BlockSpec((None, blk, _LANES),
+                         lambda bh, i, t: (bh, i, zero)),
+        ],
+        out_specs=pl.BlockSpec((None, blk, d),
+                               lambda bh, i, t: (bh, i, zero)),
+        out_shape=jax.ShapeDtypeStruct((b * h, s_pad, d), q.dtype),
+        scratch_shapes=[pltpu.VMEM((blk, d), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name="flash_attention_dq",
+        interpret=interpret,
+    )(qf, kf, vf, dof, lsef, deltaf)
+
+    def of_query(kv, t, g, i):
+        return (kv * group + g, i, zero)
+
+    def of_key(kv, t, g, i):
+        return (kv, t, zero)
+
+    dk, dv = pl.pallas_call(
+        functools.partial(_attn_dkv_kernel, **tile),
+        grid=(b * hkv, n, int(group), n),
+        in_specs=[
+            pl.BlockSpec((None, blk, d), of_query),
+            pl.BlockSpec((None, blk, d), of_key),
+            pl.BlockSpec((None, blk, d), of_key),
+            pl.BlockSpec((None, blk, d), of_query),
+            pl.BlockSpec((None, blk, _LANES), of_query),
+            pl.BlockSpec((None, blk, _LANES), of_query),
+        ],
+        out_specs=[pl.BlockSpec((None, blk, d), of_key),
+                   pl.BlockSpec((None, blk, d), of_key)],
+        out_shape=[jax.ShapeDtypeStruct((b * hkv, s_pad, d), k.dtype),
+                   jax.ShapeDtypeStruct((b * hkv, s_pad, d), v.dtype)],
+        scratch_shapes=[pltpu.VMEM((blk, d), jnp.float32),
+                        pltpu.VMEM((blk, d), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary",
+                                 "arbitrary")),
+        name="flash_attention_dkv",
+        interpret=interpret,
+    )(qf, kf, vf, dof, lsef, deltaf)
+    return (dq[:, :s].reshape(q.shape), dk[:, :s].reshape(k.shape),
+            dv[:, :s].reshape(v.shape))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def flash_attention(q, k, v, causal=False, sm_scale=None, block_q=128,
                     block_k=128, interpret=False):
-    """Tiled flash attention: q, k, v [B, H, S, D] -> [B, H, S, D].
+    """Tiled flash attention: q [B, H, S, D], k and v [B, Hkv, S, D] ->
+    [B, H, S, D]; query head i reads key/value head i // (H // Hkv).
 
     Pallas streaming forward (K/V tiles via the sequential grid axis,
-    causal tile skipping); q-chunked recompute backward.
+    causal tile skipping) and Pallas backward (``flash_attention_dq``,
+    ``flash_attention_dkv``) from the forward's saved log-sum-exp.
     ``interpret=True`` runs the kernel in the Pallas interpreter (CPU
     tests).  Shard batch/head dims with ``shard_map`` before calling —
     pallas_call is opaque to GSPMD.
     """
     return _flash_fwd_impl(q, k, v, causal, sm_scale, block_q, block_k,
-                           interpret)
+                           interpret)[0]
 
 
 def _fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret):
-    out = _flash_fwd_impl(q, k, v, causal, sm_scale, block_q, block_k,
-                          interpret)
-    return out, (q, k, v)
+    out, lse = _flash_fwd_impl(q, k, v, causal, sm_scale, block_q, block_k,
+                               interpret)
+    return out, (q, k, v, out, lse)
 
 
 def _bwd(causal, sm_scale, block_q, block_k, interpret, res, do):
-    q, k, v = res
-    return _chunked_attn_grads(q, k, v, do, causal, sm_scale)
+    return _flash_bwd_impl(*res, do, causal, sm_scale, max(block_q, block_k),
+                           interpret)
 
 
 flash_attention.defvjp(_fwd, _bwd)

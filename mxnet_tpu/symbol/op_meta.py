@@ -29,6 +29,9 @@ HINTS = {
     "_contrib_PowerRetention": "powerretention",
     "_contrib_RotaryEmbedding": "rotaryembedding",
     "_contrib_BlockedSoftmaxCE": "blockedsoftmaxce",
+    "_contrib_CausalAttention": "causalattention",
+    "_contrib_ShortConv": "shortconv",
+    "_contrib_SparseMoE": "sparsemoe",
     "elemwise_add": "_plus", "elemwise_sub": "_minus",
     "elemwise_mul": "_mul", "elemwise_div": "_div",
 }
@@ -61,6 +64,13 @@ def op_input_names(op, attrs):
         return ["query", "key", "value", "log_gate"], []
     if name == "_contrib_BlockedSoftmaxCE":
         return ["data", "weight", "label"], []
+    if name == "_contrib_CausalAttention":
+        return ["query", "key", "value"], []
+    if name == "_contrib_ShortConv":
+        return ["data", "weight"], []
+    if name == "_contrib_SparseMoE":
+        return ["data", "router_weight", "w1_weight", "w3_weight",
+                "w2_weight"], ["expert_bias"]
     if name == "Embedding":
         return ["data", "weight"], []
     if name == "RNN":
@@ -156,6 +166,8 @@ def infer_param_shapes(node, in_structs):
         out[2] = S((c,))
     elif name == "RMSNorm":
         out[1] = S((dshape[int(a.get("axis", -1)) % len(dshape)],))
+    elif name == "_contrib_ShortConv":
+        out[1] = S((dshape[-1] // 3, int(a.get("kernel", 3))))
     elif name == "_contrib_BlockedSoftmaxCE":
         out[1] = S((int(a.get("num_hidden")), dshape[-1]))
         out[2] = jax.ShapeDtypeStruct(dshape[:-1], np.float32)

@@ -553,6 +553,13 @@ COUNTERS = {
                               "chunked state form)",
     "power_retention_chunks": "chunks a sequence over all traced "
                               "_contrib_PowerRetention ops",
+    "sparse_moe_traced": "_contrib_SparseMoE ops traced (dropless top-k "
+                         "routing, grouped matrix product)",
+    "sparse_moe_rows": "routed rows (tokens x experts a token) over all "
+                       "traced _contrib_SparseMoE ops: every one is in "
+                       "a group, none is dropped",
+    "causal_attention_traced": "_contrib_CausalAttention ops traced",
+    "short_conv_traced": "_contrib_ShortConv ops traced",
     "batchnorm_onepass_traced": "training BatchNorm ops traced (one-pass "
                                 "float32 moments, hand-derived VJP)",
     "eager_invocations": "eager op dispatches through ndarray.invoke",

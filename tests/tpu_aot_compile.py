@@ -56,6 +56,42 @@ def main():
             print("AOT ok flash %s S=%d D=%d causal=%s"
                   % (jnp.dtype(dtype).name, seq, dim, causal), flush=True)
 
+    # grouped key/value heads at the LFM2 widths (32 query heads over 8,
+    # head size 64, 8,192 tokens, blocks of 512) through the graph op's
+    # forward and its chunked backward, and the sparse-expert layer at the
+    # published widths (8 of its experts, 2,048 tokens): every grouped
+    # product a kernel — JAX's Pallas gmm/tgmm as on a TPU (the selector
+    # looks at the live backend, which is the CPU here: force its choice),
+    # and XLA:TPU's own behind ragged_dot, never one dense product a group
+    from mxnet_tpu.ops import lm, moe
+    q = jax.ShapeDtypeStruct((1, 8192, 32, 64), jnp.bfloat16, sharding=one)
+    kv = jax.ShapeDtypeStruct((1, 8192, 8, 64), jnp.bfloat16, sharding=one)
+    lowered = jax.jit(jax.value_and_grad(
+        lambda *x: lm.causal_attention(*x, 0.125, True, True)
+        .astype(jnp.float32).sum(), (0, 1, 2))).lower(q, kv, kv)
+    assert "tpu_custom_call" in lowered.as_text()
+    lowered.compile()
+    print("AOT ok causal_attention grouped heads grad", flush=True)
+
+    def spec(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+    def layer(*x):
+        return moe.sparse_moe(*x, num_experts=8, num_experts_per_tok=4)[0] \
+            .astype(jnp.float32).sum()
+    shapes = (spec(2048, 2048), spec(8, 2048), spec(8, 2048, 1792),
+              spec(8, 2048, 1792), spec(8, 1792, 2048),
+              spec(8, dtype=jnp.float32))
+    for on_tpu, kernel in ((True, "gmm"), (False, "ragged-dot-none")):
+        moe._kernel_backend = lambda on_tpu=on_tpu: on_tpu
+        # a fresh function each time: a trace is cached by function
+        text = jax.jit(jax.value_and_grad(functools.partial(layer),
+                                          (0, 1, 2, 3, 4))) \
+            .lower(*shapes).compile().as_text()
+        assert text.count("%" + kernel) >= 9, (kernel, text.count(kernel))
+        assert "convolution-base-dilated" not in text
+        print("AOT ok sparse_moe grad, %s kernels only" % kernel,
+              flush=True)
+
     # power retention at the Brumby widths (5 query heads a key/value
     # head of 128, chunks of 1024): the Pallas forward, and the chunked
     # jnp backward through the states it saves
