@@ -196,16 +196,20 @@ def _block_logits(h, weight):
                                preferred_element_type=jnp.float32)
 
 
+def _block_nll(logits, y):
+    """Sum of -log softmax(logits)[y] over a block's rows with y >= 0."""
+    lse = jax.nn.logsumexp(logits, axis=-1)
+    picked = jnp.take_along_axis(logits, jnp.maximum(y, 0)[:, None],
+                                 axis=-1)[:, 0]
+    return jnp.sum(jnp.where(y >= 0, lse - picked, 0.0))
+
+
 def _blocked_ce_value(data, weight, label, block):
     hs, ys, count = _head_blocks(data, label, block)
 
     def body(total, xs):
         h, y = xs
-        logits = _block_logits(h, weight)
-        lse = jax.nn.logsumexp(logits, axis=-1)
-        picked = jnp.take_along_axis(logits, jnp.maximum(y, 0)[:, None],
-                                     axis=-1)[:, 0]
-        return total + jnp.sum(jnp.where(y >= 0, lse - picked, 0.0)), None
+        return total + _block_nll(_block_logits(h, weight), y), None
 
     with jax.named_scope("lm_head_loss"):
         total, _ = jax.lax.scan(body, jnp.zeros((), jnp.float32), (hs, ys))
@@ -216,23 +220,31 @@ def _blocked_ce_value(data, weight, label, block):
 def blocked_softmax_ce(data, weight, label, block):
     """Mean next-token negative log-likelihood of softmax(data weight^T)
     at ``label``, one block of tokens at a time: no [tokens, classes]
-    tensor is ever whole, forward or backward."""
+    tensor is ever whole.
+
+    Called without differentiation (``is_train=False``, ``score``,
+    ``predict``) it is one scan with one product a block.  Under
+    differentiation the forward rule does all of the work: the loss is the
+    mean over tokens, so d loss / d logits = (softmax - onehot) / count
+    depends on nothing after the op, and one scan forms the loss, ``dh`` and
+    ``dW`` from one logits block (three products a block); the backward
+    rule scales them by the incoming cotangent.  A ``forward(is_train=True)``
+    that no ``backward()`` follows therefore pays for gradients it never
+    reads: ask for the value alone with ``is_train=False``.  Counter
+    ``lm_head_fused_traced``: traces of the forward rule."""
     return _blocked_ce_value(data, weight, label, block)
 
 
 def _blocked_ce_fwd(data, weight, label, block):
-    return _blocked_ce_value(data, weight, label, block), \
-        (data, weight, label)
-
-
-def _blocked_ce_bwd(block, res, g):
-    data, weight, label = res
+    _tel.bump("lm_head_fused_traced")
     hs, ys, count = _head_blocks(data, label, block)
-    scale = g.reshape(()).astype(jnp.float32) / count
+    scale = jnp.ones((), jnp.float32) / count
 
-    def body(dw, xs):
+    def body(carry, xs):
+        total, dw = carry
         h, y = xs
         logits = _block_logits(h, weight)
+        total = total + _block_nll(logits, y)
         p = jax.nn.softmax(logits, axis=-1)
         hit = jax.lax.broadcasted_iota(jnp.int32, p.shape, 1) == y[:, None]
         dlogits = ((p - hit) * jnp.where(y >= 0, scale, 0.0)[:, None]) \
@@ -241,13 +253,21 @@ def _blocked_ce_bwd(block, res, g):
         dw = dw + jax.lax.dot_general(
             dlogits, h, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        return dw, dh.astype(h.dtype)
+        return (total, dw), dh.astype(h.dtype)
 
     with jax.named_scope("lm_head_loss"):
-        dw, dh = jax.lax.scan(body, jnp.zeros(weight.shape, jnp.float32),
-                              (hs, ys))
+        (total, dw), dh = jax.lax.scan(
+            body, (jnp.zeros((), jnp.float32),
+                   jnp.zeros(weight.shape, jnp.float32)), (hs, ys))
     dh = dh.reshape(-1, dh.shape[-1])[:count].reshape(data.shape)
-    return dh, dw.astype(weight.dtype), jnp.zeros_like(label)
+    return (total / count).reshape(1), (dh, dw.astype(weight.dtype), label)
+
+
+def _blocked_ce_bwd(block, res, g):
+    dh, dw, label = res
+    g = g.reshape(()).astype(jnp.float32)
+    return (dh * g).astype(dh.dtype), (dw * g).astype(dw.dtype), \
+        jnp.zeros_like(label)
 
 
 blocked_softmax_ce.defvjp(_blocked_ce_fwd, _blocked_ce_bwd)

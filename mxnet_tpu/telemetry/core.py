@@ -560,6 +560,10 @@ COUNTERS = {
                        "a group, none is dropped",
     "causal_attention_traced": "_contrib_CausalAttention ops traced",
     "short_conv_traced": "_contrib_ShortConv ops traced",
+    "lm_head_fused_traced": "_contrib_BlockedSoftmaxCE forward rules traced "
+                            "(under differentiation: loss, dh and dW in "
+                            "one scan; the undifferentiated op does not "
+                            "count)",
     "batchnorm_onepass_traced": "training BatchNorm ops traced (one-pass "
                                 "float32 moments, hand-derived VJP)",
     "eager_invocations": "eager op dispatches through ndarray.invoke",

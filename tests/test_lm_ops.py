@@ -1,6 +1,7 @@
 """Language-model ops (RMSNorm, rotary embedding, power retention, blocked
 softmax cross-entropy), recomputation segments in the executor and the
 lazily allocated gradient buffers."""
+import functools
 import math
 
 import jax
@@ -11,6 +12,7 @@ import pytest
 import mxnet_tpu as mx
 from mxnet_tpu import telemetry
 from mxnet_tpu.executor import _build_graph_fn
+from mxnet_tpu.ops import lm
 from mxnet_tpu.ops.lm import blocked_softmax_ce, rms_norm
 from mxnet_tpu.ops.pallas_kernels import power_retention
 from mxnet_tpu.symbol.symbol import _topo
@@ -148,6 +150,204 @@ def test_blocked_head_equals_log_softmax_and_gather(tokens, block):
                                    rtol=1e-4, atol=1e-6)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def two_loop_softmax_ce(data, weight, label, block):
+    """The head as it was before its forward rule formed the gradients
+    (PR 31): the value's scan forward, a second scan that computes the
+    logits again backward.  The yardstick of the fused rule: for a
+    cotangent of 1 the two are the same arithmetic in the same order."""
+    return lm._blocked_ce_value(data, weight, label, block)
+
+
+def _two_loop_fwd(data, weight, label, block):
+    return lm._blocked_ce_value(data, weight, label, block), \
+        (data, weight, label)
+
+
+def _two_loop_bwd(block, res, g):
+    data, weight, label = res
+    hs, ys, count = lm._head_blocks(data, label, block)
+    scale = g.reshape(()).astype(jnp.float32) / count
+
+    def body(dw, xs):
+        h, y = xs
+        logits = lm._block_logits(h, weight)
+        p = jax.nn.softmax(logits, axis=-1)
+        hit = jax.lax.broadcasted_iota(jnp.int32, p.shape, 1) == y[:, None]
+        dlogits = ((p - hit) * jnp.where(y >= 0, scale, 0.0)[:, None]) \
+            .astype(h.dtype)
+        dh = jnp.dot(dlogits, weight, preferred_element_type=jnp.float32)
+        dw = dw + jax.lax.dot_general(
+            dlogits, h, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        return dw, dh.astype(h.dtype)
+
+    dw, dh = jax.lax.scan(body, jnp.zeros(weight.shape, jnp.float32),
+                          (hs, ys))
+    dh = dh.reshape(-1, dh.shape[-1])[:count].reshape(data.shape)
+    return dh, dw.astype(weight.dtype), jnp.zeros_like(label)
+
+
+two_loop_softmax_ce.defvjp(_two_loop_fwd, _two_loop_bwd)
+
+
+def head_inputs(tokens, dtype, classes=17, hidden=12):
+    """An embedding / head weight, token ids and labels with two -1."""
+    rng = np.random.RandomState(tokens)
+    w = jnp.asarray(rng.randn(classes, hidden) * 0.5, dtype)
+    ids = jnp.asarray(rng.randint(0, classes, (2, tokens)))
+    h = jnp.asarray(rng.randn(2, tokens, hidden), dtype)
+    y = rng.randint(0, classes, (2, tokens))
+    y[0, 1] = y[1, tokens - 1] = -1
+    return h, w, ids, jnp.asarray(y, jnp.float32)
+
+
+@pytest.mark.parametrize("g", [1.0, 0.5, 3.0])
+@pytest.mark.parametrize("tied", [False, True], ids=["free", "tied"])
+@pytest.mark.parametrize("tokens,block", [(32, 8), (27, 8)],
+                         ids=["whole", "ragged"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+def test_fused_head_gradients(dtype, tokens, block, tied, g):
+    """Loss, dh and dW of the rule that forms them in the forward scan:
+    against log_softmax + gather for every cotangent, and to the bit
+    against the two-loop rule for a cotangent of 1.  ``tied``: the head's
+    weight is also the embedding that made the hidden states, so its
+    gradient is the sum of both uses."""
+    h, w, ids, y = head_inputs(tokens, dtype)
+
+    def hidden(h, w):
+        return jnp.tanh(w[ids]) if tied else h
+
+    def plain(h, w):
+        x, wf = hidden(h, w).astype(jnp.float32), w.astype(jnp.float32)
+        logp = jax.nn.log_softmax(jnp.einsum(
+            "bth,ch->btc", x, wf, precision="highest"), axis=-1)
+        nll = -jnp.take_along_axis(
+            logp, jnp.maximum(y, 0).astype(jnp.int32)[..., None],
+            axis=-1)[..., 0]
+        return g * jnp.where(y >= 0, nll, 0.0).sum() / y.size
+
+    def through(op):
+        return jax.jit(jax.value_and_grad(
+            lambda h, w: g * op(hidden(h, w), w, y, block)[0], (0, 1)))(h, w)
+
+    want, want_grads = jax.value_and_grad(plain, (0, 1))(h, w)
+    got, got_grads = through(blocked_softmax_ce)
+    low = dtype == jnp.bfloat16
+    assert float(got) == pytest.approx(float(want), rel=2e-2 if low else 1e-5)
+    for have, wnt in zip(got_grads, want_grads):
+        assert have.dtype == dtype
+        if tied and have.shape == h.shape:
+            assert not np.asarray(have, np.float32).any()
+            continue
+        have, wnt = np.asarray(have, np.float32), np.asarray(wnt, np.float32)
+        if low:     # dlogits are rounded to bf16 before the two products
+            assert np.abs(have - wnt).max() <= 3e-2 * np.abs(wnt).max()
+        else:
+            np.testing.assert_allclose(have, wnt, rtol=1e-4, atol=1e-6)
+    if g == 1.0:
+        old, old_grads = through(two_loop_softmax_ce)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(old))
+        for have, wnt in zip(got_grads, old_grads):
+            np.testing.assert_array_equal(np.asarray(have, np.float32),
+                                          np.asarray(wnt, np.float32))
+
+
+def primitive_counts(jaxpr, counts=None, avals=None):
+    """How often each primitive occurs in *jaxpr* and every jaxpr under
+    it, and the abstract value of every variable they bind."""
+    counts = {} if counts is None else counts
+    avals = [] if avals is None else avals
+    for eqn in jaxpr.eqns:
+        counts[eqn.primitive.name] = counts.get(eqn.primitive.name, 0) + 1
+        avals.extend(v.aval for v in eqn.outvars)
+        for value in eqn.params.values():
+            inner = getattr(value, "jaxpr", value)
+            if hasattr(inner, "eqns"):
+                primitive_counts(inner, counts, avals)
+    return counts, avals
+
+
+def test_head_makes_three_products_in_one_scan_under_differentiation():
+    h, w, _, y = head_inputs(32, jnp.float32)
+
+    def loss(h, w):
+        return blocked_softmax_ce(h, w, y, 8)[0]
+
+    counts, _ = primitive_counts(
+        jax.make_jaxpr(jax.value_and_grad(loss, (0, 1)))(h, w).jaxpr)
+    assert counts["dot_general"] == 3 and counts["scan"] == 1
+    old, _ = primitive_counts(jax.make_jaxpr(jax.value_and_grad(
+        lambda h, w: two_loop_softmax_ce(h, w, y, 8)[0], (0, 1)))(h, w).jaxpr)
+    assert old["dot_general"] == 4 and old["scan"] == 2
+    # the value alone: one product a block, nothing of the weight's shape
+    counts, avals = primitive_counts(jax.make_jaxpr(loss)(h, w).jaxpr)
+    assert counts["dot_general"] == 1 and counts["scan"] == 1
+    assert not [a for a in avals if getattr(a, "shape", None) == w.shape]
+
+
+def stage(remat, k):
+    """The scope of recomputation segment *k*, or no scope."""
+    return mx.AttrScope(force_mirroring="True", mirror_stage=str(k)) \
+        if remat else mx.AttrScope()
+
+
+def head_symbol(remat=False):
+    """A projection and the head on it, each in a stage of its own when
+    *remat*: the head's op sits inside the last recomputation segment."""
+    x = mx.sym.Variable("data")
+    with stage(remat, 0):
+        h = mx.sym.FullyConnected(x, num_hidden=16, flatten=False, name="fc0")
+        h = mx.sym.Activation(h, act_type="tanh")
+    with stage(remat, 1):
+        h = mx.sym.FullyConnected(h, num_hidden=12, flatten=False, name="fc1")
+        return mx.sym.contrib.BlockedSoftmaxCE(h, num_hidden=17, block=8,
+                                               name="head")
+
+
+def bound_head(sym):
+    """``bound`` at [2, 15, 8] with labels in -1..16."""
+    ex, grads = bound(sym, seed=3, data_shape=(2, 15, 8))
+    ex.arg_dict["head_label"][:] = np.random.RandomState(3).randint(
+        -1, 17, (2, 15)).astype(np.float32)
+    return ex, grads
+
+
+def test_fused_head_counter_counts_forward_rules_only():
+    h, w, _, y = head_inputs(32, jnp.float32)
+    before = telemetry.counter("lm_head_fused_traced")
+    jax.grad(lambda h: blocked_softmax_ce(h, w, y, 8)[0])(h)
+    assert telemetry.counter("lm_head_fused_traced") == before + 1
+    ex, _ = bound_head(head_symbol())
+    want = ex.forward(is_train=False)[0].asnumpy()
+    assert telemetry.counter("lm_head_fused_traced") == before + 1
+    ex.forward_backward()
+    assert telemetry.counter("lm_head_fused_traced") > before + 1
+    np.testing.assert_allclose(ex.outputs[0].asnumpy(), want, rtol=1e-6)
+
+
+def test_fused_head_inside_a_recomputation_segment():
+    plain, plain_grads = bound_head(head_symbol(False))
+    marked, marked_grads = bound_head(head_symbol(True))
+    for ex in (plain, marked):
+        ex.forward_backward()
+    np.testing.assert_array_equal(plain.outputs[0].asnumpy(),
+                                  marked.outputs[0].asnumpy())
+    assert np.abs(plain_grads["fc0_weight"].asnumpy()).max() > 0
+    for name in plain_grads:
+        np.testing.assert_allclose(plain_grads[name].asnumpy(),
+                                   marked_grads[name].asnumpy(), rtol=1e-5,
+                                   atol=1e-7)
+    # a run-time cotangent scales what the forward formed
+    marked.forward(is_train=True)
+    marked.backward(out_grads=[mx.nd.array(np.array([0.5], np.float32))])
+    for name in plain_grads:
+        np.testing.assert_allclose(marked_grads[name].asnumpy(),
+                                   0.5 * plain_grads[name].asnumpy(),
+                                   rtol=1e-5, atol=1e-7)
+
+
 def test_rms_norm_and_rotary_ops():
     rng = np.random.RandomState(0)
     x = rng.randn(2, 5, 3, 8).astype(np.float32)
@@ -199,23 +399,20 @@ def test_symbol_infers_the_new_ops_parameters():
 
 def mlp(remat):
     """Two marked stages around an unmarked node; operators included."""
-    def stage(k):
-        return mx.AttrScope(force_mirroring="True", mirror_stage=str(k)) \
-            if remat else mx.AttrScope()
     x = mx.sym.Variable("data")
-    with stage(0):
+    with stage(remat, 0):
         h = mx.sym.FullyConnected(x, num_hidden=16, name="fc0")
         h = mx.sym.Activation(h, act_type="tanh") * 0.5 + h
     h = mx.sym.BatchNorm(h, name="bn")
-    with stage(1):
+    with stage(remat, 1):
         h = mx.sym.FullyConnected(h, num_hidden=4, name="fc1")
         h = -mx.sym.Activation(-h, act_type="softrelu")
     return mx.sym.sum(h * h)
 
 
-def bound(sym, seed=0):
+def bound(sym, seed=0, data_shape=(5, 8)):
     rng = np.random.RandomState(seed)
-    shapes, _, aux_shapes = sym.infer_shape(data=(5, 8))
+    shapes, _, aux_shapes = sym.infer_shape(data=data_shape)
     args = {n: mx.nd.array(rng.randn(*s).astype(np.float32) * 0.3)
             for n, s in zip(sym.list_arguments(), shapes)}
     aux = {n: mx.nd.array(np.ones(s, np.float32))

@@ -526,6 +526,56 @@ def test_batchnorm_onepass_counter_stays_for_a_graph_without_batchnorm():
     assert telemetry.counter("batchnorm_onepass_traced") == before
 
 
+@pytest.mark.parametrize("model,dtype,head", [
+    ("brumby", "float32", "lm_head_weight"),
+    # XLA:CPU runs no bf16 product inside the retention op's chunk scan, so
+    # the bf16 case is the sparse-expert toy, whose head is its embedding
+    ("lfm2", "bfloat16", "embed_weight")])
+def test_fit_on_the_fused_head_equals_fit_on_the_two_loop_head(
+        monkeypatch, model, dtype, head):
+    """Three batches of an LM toy through ``Module.fit``'s fused step:
+    every loss and the updated head weight are, bit for bit, what the head
+    gives whose backward computes the logits a second time
+    (``tests/test_lm_ops.py`` keeps that rule as the yardstick)."""
+    from mxnet_tpu import telemetry
+    from mxnet_tpu.models.brumby import BRUMBY_TINY, brumby_symbol
+    from mxnet_tpu.models.lfm2 import LFM2_MOE_TINY, lfm2_moe_symbol
+    from mxnet_tpu.ops import lm
+    from tests.test_lm_ops import two_loop_softmax_ce
+    toy, symbol = {"brumby": (BRUMBY_TINY, brumby_symbol),
+                   "lfm2": (LFM2_MOE_TINY, lfm2_moe_symbol)}[model]
+    cfg = dict(toy, dtype=dtype)
+    ids = np.random.RandomState(5).randint(
+        0, cfg["vocab_size"], (6, 17)).astype(np.float32)
+
+    def fit():
+        mx.random.seed(11)
+        it = mx.io.NDArrayIter(ids[:, :-1], ids[:, 1:], batch_size=2,
+                               label_name="softmax_label")
+        mod = mx.mod.Module(symbol(cfg), context=mx.cpu())
+        losses = []
+        mod.fit(it, eval_metric="loss", num_epoch=1,
+                initializer=mx.initializer.Xavier(magnitude=2.0),
+                optimizer="sgd",
+                optimizer_params=(("learning_rate", 0.3), ("momentum", 0.9)),
+                batch_end_callback=lambda p: losses.append(
+                    p.eval_metric.get()[1]))
+        return losses, mod.get_params()[0][head].asnumpy()
+
+    count = lambda: telemetry.counter("lm_head_fused_traced")
+    before = count()
+    losses, weight = fit()
+    fused = count()
+    assert fused > before
+    monkeypatch.setattr(lm, "blocked_softmax_ce", two_loop_softmax_ce)
+    old_losses, old_weight = fit()
+    assert count() == fused
+    assert len(losses) == 3 and losses == old_losses
+    assert losses[0] != losses[2]
+    np.testing.assert_array_equal(weight.astype(np.float32),
+                                  old_weight.astype(np.float32))
+
+
 # ---- symbolic check helpers on ops ----------------------------------------
 def test_check_symbolic_forward_backward():
     a = mx.sym.var("a")
