@@ -185,7 +185,14 @@ def _build_graph_fn(symbol, train_mode):
         ins = [env[(id(s), oi)] for s, oi in node.inputs]
         key = keys[rng_pos[id(node)]] if node.op.needs_rng else None
         fn = node.op.traceable(node.attrs, train_mode=train_mode, rng=key)
-        outs = fn(*ins)
+        # AttrScope(trace_scope="<name>") names a group of plain ops in
+        # the device trace, as an op's own jax.named_scope names a kernel
+        scope = node.attrs.get("__trace_scope__")
+        if scope:
+            with jax.named_scope(str(scope)):
+                outs = fn(*ins)
+        else:
+            outs = fn(*ins)
         if not isinstance(outs, tuple):
             outs = (outs,)
         for i, o in enumerate(outs):

@@ -19,8 +19,10 @@ log-likelihood, shape (1,).  Every layer is one recomputation segment
 """
 from __future__ import annotations
 
-from .. import attribute, initializer
+from .. import initializer
 from .. import symbol as S
+from .lm_blocks import (dense, heads, layer_kinds, layer_scope,
+                        sparse_experts, swiglu, with_probes)
 
 __all__ = ["lfm2_moe_symbol", "LFM2_MOE_TINY"]
 
@@ -57,23 +59,6 @@ def lfm2_moe_symbol(cfg, recompute=True, probes=()):
         raise ValueError("lfm2_moe_symbol: conv_bias is not implemented")
     taken = {}
 
-    def dense(x, width, name):
-        return S.FullyConnected(x, num_hidden=width, flatten=False,
-                                no_bias=True, name=name)
-
-    def heads(x, n):
-        return S.Reshape(x, shape=(0, 0, n, d))
-
-    def swiglu(h, width, pre):
-        act = dense(h, width, pre + "w1")
-        act = act * S.Activation(act, act_type="sigmoid") * \
-            dense(h, width, pre + "w3")
-        return dense(act, hidden, pre + "w2")
-
-    def stacked(name, shape):
-        # [experts, in, out]: Xavier draws each expert's matrix on its own
-        return S.Variable(name, shape=shape, dtype=dtype, __stacked__=True)
-
     def short_conv(h, pre):
         conv_weight = S.Variable(
             pre + "conv_weight", shape=(hidden, cfg["conv_L_cache"]),
@@ -83,53 +68,25 @@ def lfm2_moe_symbol(cfg, recompute=True, probes=()):
                                    weight=conv_weight, name=pre + "conv")
 
     def attention(h, pre):
-        q = S.RMSNorm(heads(dense(h, hq * d, pre + "q"), hq), eps=eps,
+        q = S.RMSNorm(heads(dense(h, hq * d, pre + "q"), hq, d), eps=eps,
                       name=pre + "q_norm")
-        k = S.RMSNorm(heads(dense(h, hkv * d, pre + "k"), hkv), eps=eps,
+        k = S.RMSNorm(heads(dense(h, hkv * d, pre + "k"), hkv, d), eps=eps,
                       name=pre + "k_norm")
         q = S.contrib.RotaryEmbedding(q, base=theta)
         k = S.contrib.RotaryEmbedding(k, base=theta)
-        v = heads(dense(h, hkv * d, pre + "v"), hkv)
+        v = heads(dense(h, hkv * d, pre + "v"), hkv, d)
         o = S.contrib.CausalAttention(q, k, v, scale=d ** -0.5,
                                       name=pre + "attention")
         return S.Reshape(o, shape=(0, 0, -1))
-
-    def sparse_experts(h, pre):
-        moe = S.contrib.SparseMoE(
-            h,
-            router_weight=S.Variable(pre + "router_weight",
-                                     shape=(experts, hidden), dtype=dtype),
-            w1_weight=stacked(pre + "experts_w1_weight",
-                              (experts, hidden, width)),
-            w3_weight=stacked(pre + "experts_w3_weight",
-                              (experts, hidden, width)),
-            w2_weight=stacked(pre + "experts_w2_weight",
-                              (experts, width, hidden)),
-            expert_bias=S.Variable(pre + "expert_bias", shape=(experts,),
-                                   dtype="float32",
-                                   init=initializer.Uniform(0.1)),
-            num_experts=experts,
-            num_experts_per_tok=cfg["num_experts_per_tok"],
-            norm_topk_prob=cfg["norm_topk_prob"],
-            routed_scaling_factor=cfg["routed_scaling_factor"],
-            name=pre + "moe")
-        return moe[0], moe[1]
 
     embed = S.Variable("embed_weight", shape=(cfg["vocab_size"], hidden),
                        dtype=dtype)
     x = S.Embedding(S.Variable("data"), weight=embed,
                     input_dim=cfg["vocab_size"], output_dim=hidden,
                     name="embed")
-    kinds = cfg["layer_types"][:cfg["num_hidden_layers"]]
-    if len(kinds) != cfg["num_hidden_layers"]:
-        raise ValueError("lfm2_moe_symbol: %d layer_types for %d layers"
-                         % (len(kinds), cfg["num_hidden_layers"]))
-    for i, kind in enumerate(kinds):
+    for i, kind in enumerate(layer_kinds(cfg, "lfm2_moe_symbol")):
         pre = "layer%d_" % i
-        scope = attribute.AttrScope(force_mirroring="True",
-                                    mirror_stage=str(i)) if recompute \
-            else attribute.AttrScope()
-        with scope:
+        with layer_scope(i, recompute):
             h = S.RMSNorm(x, eps=eps, name=pre + "op_norm")
             if kind == "conv":
                 op = short_conv(h, pre)
@@ -141,9 +98,14 @@ def lfm2_moe_symbol(cfg, recompute=True, probes=()):
                 raise ValueError("lfm2_moe_symbol: layer type %r" % kind)
             h = S.RMSNorm(x, eps=eps, name=pre + "ffn_norm")
             if i < cfg["num_dense_layers"]:
-                ffn = swiglu(h, cfg["intermediate_size"], pre + "mlp_")
+                ffn = swiglu(h, cfg["intermediate_size"], hidden,
+                             pre + "mlp_")
             else:
-                ffn, taken[pre + "choice"] = sparse_experts(h, pre)
+                ffn, taken[pre + "choice"] = sparse_experts(
+                    h, pre, dtype, hidden, width, experts,
+                    num_experts_per_tok=cfg["num_experts_per_tok"],
+                    norm_topk_prob=cfg["norm_topk_prob"],
+                    routed_scaling_factor=cfg["routed_scaling_factor"])
             x = x + ffn
             taken[pre + "op"], taken[pre + "ffn"] = op, ffn
     x = S.RMSNorm(x, eps=eps, name="final_norm")
@@ -151,9 +113,4 @@ def lfm2_moe_symbol(cfg, recompute=True, probes=()):
         x, weight=embed, label=S.Variable("softmax_label"),
         num_hidden=cfg["vocab_size"], block=cfg["head_block"],
         name="lm_head")
-    missing = [p for p in probes if p not in taken]
-    if missing:
-        raise ValueError("lfm2_moe_symbol: no probe %s (there are %s)"
-                         % (missing, sorted(taken)))
-    return S.Group([loss] + [S.BlockGrad(taken[p], name=p + "_probe")
-                             for p in probes]) if probes else loss
+    return with_probes(loss, taken, probes, "lfm2_moe_symbol")

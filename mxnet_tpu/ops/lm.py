@@ -74,16 +74,20 @@ def _power_retention(query, key, value, log_gate, degree=2, chunk=128,
                            chunk, float(eps), kernel)
 
 
-def _masked_softmax_attention(q, k, v, scale, causal):
+def _masked_softmax_attention(q, k, v, scale, causal, window=None):
     """softmax(q k^T scale) v with the scores whole, float32 statistics:
-    q [B, Hq, S, d], k and v [B, Hkv, S, d] -> [B, Hq, S, d]."""
+    q [B, Hq, S, d], k and v [B, Hkv, S, d] -> [B, Hq, S, d].  *window*
+    (causal only) keeps the keys q_pos - window < k_pos <= q_pos."""
     b, hq, s, d = q.shape
     hkv = k.shape[1]
     qg = q.reshape(b, hkv, hq // hkv, s, d)
     score = jnp.einsum("bhgqd,bhkd->bhgqk", qg, k,
                        preferred_element_type=jnp.float32) * scale
     if causal:
-        keep = jnp.arange(s)[:, None] >= jnp.arange(s)[None, :]
+        behind = jnp.arange(s)[:, None] - jnp.arange(s)[None, :]
+        keep = behind >= 0
+        if window is not None:
+            keep = keep & (behind < window)
         score = jnp.where(keep, score, -jnp.inf)
     p = jax.nn.softmax(score, axis=-1).astype(v.dtype)
     out = jnp.einsum("bhgqk,bhkd->bhgqd", p, v,
@@ -94,45 +98,53 @@ def _masked_softmax_attention(q, k, v, scale, causal):
 _ATTENTION_TILE = 512      # square tiles of the Pallas forward and backward
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def causal_attention(q, k, v, scale, causal, use_kernel):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def causal_attention(q, k, v, scale, causal, use_kernel, window=None):
     """Softmax attention over grouped key/value heads: q [B, S, Hq, d],
     k and v [B, S, Hkv, d] -> [B, S, Hq, d]; query head i reads key/value
-    head i // (Hq // Hkv).  With *use_kernel* forward and backward are the
-    Pallas ``flash_attention`` kernels (tiles of 512), else the masked
-    softmax in ``jnp`` and its ``jax.vjp``."""
-    return _attention_fwd(q, k, v, scale, causal, use_kernel)[0]
+    head i // (Hq // Hkv); with *window* a query sees its *window* newest
+    keys, itself among them.  With *use_kernel* forward and backward are
+    the Pallas ``flash_attention`` kernels (tiles of 512, a banded grid
+    under a window), else the masked softmax in ``jnp`` and its
+    ``jax.vjp``."""
+    return _attention_fwd(q, k, v, scale, causal, use_kernel, window)[0]
 
 
 def _heads_first(*xs):
     return tuple(x.transpose(0, 2, 1, 3) for x in xs)
 
 
-def _attention_fwd(q, k, v, scale, causal, use_kernel):
+def _attention_scope(window, direction):
+    return jax.named_scope("%s_attention_%s" % (
+        "causal" if window is None else "window", direction))
+
+
+def _attention_fwd(q, k, v, scale, causal, use_kernel, window):
     from . import pallas_kernels as pk
-    with jax.named_scope("causal_attention_fwd"):
+    with _attention_scope(window, "fwd"):
         qh, kh, vh = _heads_first(q, k, v)
         if use_kernel:
             out, lse = pk._flash_fwd_impl(qh, kh, vh, causal, scale,
                                           _ATTENTION_TILE, _ATTENTION_TILE,
-                                          False)
+                                          False, window)
             saved = (qh, kh, vh, out, lse)
         else:
-            out = _masked_softmax_attention(qh, kh, vh, scale, causal)
+            out = _masked_softmax_attention(qh, kh, vh, scale, causal,
+                                            window)
             saved = (qh, kh, vh)
     return out.transpose(0, 2, 1, 3), saved
 
 
-def _attention_bwd(scale, causal, use_kernel, saved, do):
+def _attention_bwd(scale, causal, use_kernel, window, saved, do):
     from . import pallas_kernels as pk
-    with jax.named_scope("causal_attention_bwd"):
+    with _attention_scope(window, "bwd"):
         (doh,) = _heads_first(do)
         if use_kernel:
             grads = pk._flash_bwd_impl(*saved, doh, causal, scale,
-                                       _ATTENTION_TILE, False)
+                                       _ATTENTION_TILE, False, window)
         else:
             grads = jax.vjp(lambda *x: _masked_softmax_attention(
-                *x, scale, causal), *saved)[1](doh)
+                *x, scale, causal, window), *saved)[1](doh)
         return _heads_first(*grads)
 
 
@@ -140,17 +152,29 @@ causal_attention.defvjp(_attention_fwd, _attention_bwd)
 
 
 @register("_contrib_CausalAttention", aliases=["CausalAttention"])
-def _causal_attention(query, key, value, scale=None, causal=True, **kw):
+def _causal_attention(query, key, value, scale=None, causal=True,
+                      window=None, **kw):
     """Softmax attention with grouped key/value heads: query
     [B, S, Hq, d], key/value [B, S, Hkv, d] -> [B, S, Hq, d]; scores are
     scaled by ``scale`` (1/sqrt(d) if None) and masked to s <= t when
-    ``causal``.  The forward is the Pallas kernel on a TPU and the same
-    mathematics in ``jnp`` elsewhere."""
+    ``causal``, and to t - window < s <= t with a ``window`` (the token
+    itself among its ``window`` keys; causal only).  The forward is the
+    Pallas kernel on a TPU and the same mathematics in ``jnp``
+    elsewhere."""
     d = query.shape[-1]
     scale = 1.0 / d ** 0.5 if scale is None else float(scale)
-    _tel.bump("causal_attention_traced")
-    return causal_attention(query, key, value, scale, bool(causal),
-                            jax.default_backend() == "tpu")
+    causal = bool(causal)
+    if window is None:
+        _tel.bump("causal_attention_traced")
+    else:
+        window = int(window)
+        if window < 1 or not causal:
+            raise ValueError("_contrib_CausalAttention: window %d needs "
+                             "causal attention and at least one key"
+                             % window)
+        _tel.bump("window_attention_traced")
+    return causal_attention(query, key, value, scale, causal,
+                            jax.default_backend() == "tpu", window)
 
 
 def short_conv(data, weight):

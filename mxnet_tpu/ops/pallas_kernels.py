@@ -9,7 +9,11 @@ kernels are Pallas.  This module holds the framework's built-in kernels:
   (sequential) grid axis, so each program sees ONE [block_k, D] K/V tile in
   VMEM while fp32 accumulators persist in scratch across k steps — true
   streaming, O(block·D) VMEM regardless of sequence length.  Causal
-  programs whose whole K tile is masked skip compute via ``pl.when``.
+  programs whose whole K tile is masked skip compute via ``pl.when``
+  (the tile is still stepped over and fetched); under a sliding
+  ``window`` the innermost axis is only as long as the band of tiles a
+  query tile can see, offset by the block map, so tiles outside the band
+  are never visited.
   Grouped key/value heads are an index in the block map, never a repeated
   tensor.  Differentiable via ``jax.custom_vjp``: the forward also writes
   the softmax's log-sum-exp, and two Pallas kernels (dq; dk and dv) recompute
@@ -52,15 +56,103 @@ def _lane_cols(x, n):
     return x if reps == 1 else jnp.tile(x, (1, reps))
 
 
+def _i32(op, x, n):
+    """``op(x, n)`` on a traced 32-bit scalar and a Python integer (block
+    maps, kernel bodies; under x64 ``jnp``'s own would bring 64-bit
+    constants into Mosaic)."""
+    return op(x, np.int32(n))
+
+
+def _band_of_queries(qi, *, block_q, block_k, window, seq_len, **_):
+    """(first, last) key tile that query tile *qi* of a windowed causal
+    layer sees: keys q_pos - window < k_pos <= q_pos."""
+    first = _i32(jax.lax.div, _i32(
+        jax.lax.max, qi * block_q - (window - 1), 0), block_k)
+    last = _i32(jax.lax.min, _i32(jax.lax.div, (qi + 1) * block_q - 1,
+                                  block_k), -(-seq_len // block_k) - 1)
+    return first, last
+
+
+def _band_of_keys(ki, *, block_q, block_k, window, seq_len, **_):
+    """(first, last) query tile that sees key tile *ki* under a window."""
+    first = _i32(jax.lax.div, ki * block_k, block_q)
+    last = _i32(jax.lax.min, _i32(
+        jax.lax.div, (ki + 1) * block_k + (window - 2), block_q),
+        -(-seq_len // block_q) - 1)
+    return first, last
+
+
+def _band_len(block_outer, block_inner, window, n_inner):
+    """Static length of a banded grid axis: the most inner tiles an outer
+    tile sees.  Its positions and the window - 1 behind (or ahead of)
+    them: ceil((window - 1) / tile) + 1 tiles when both tiles are one
+    size, and for unlike tiles one more than they span at most."""
+    if block_outer == block_inner:
+        tiles = -(-(window - 1) // block_inner) + 1
+    else:
+        tiles = -(-(block_outer + window - 2) // block_inner) + 1
+    return min(tiles, n_inner)
+
+
+def _band_tile(band, outer, step, **tile):
+    """(inner tile at grid step *step*, whether it is inside the band):
+    the band starts at the outer tile's first visible tile; steps past
+    its last one name that last tile again (the block map repeats the
+    block, so nothing is fetched) and are masked off."""
+    first, last = band(outer, **tile)
+    return jax.lax.min(first + step, last), first + step <= last
+
+
+def _key_tile(qi, step, **tile):
+    """(key tile, whether any of it may be visible) of grid step *step*
+    of query tile *qi*: without a window every key tile is stepped over
+    and those in a causal query tile's future are skipped; with one only
+    the band is visited."""
+    if tile.get("window") is not None:
+        return _band_tile(_band_of_queries, qi, step, **tile)
+    live = True
+    if tile["causal"]:
+        # causal: skip K tiles strictly in the future of this q block
+        live = (qi + 1) * tile["block_q"] - 1 >= step * tile["block_k"]
+    return step, live
+
+
+def _query_tile(ki, step, **tile):
+    """The same for the query tiles of key tile *ki* (dk and dv)."""
+    if tile.get("window") is not None:
+        return _band_tile(_band_of_keys, ki, step, **tile)
+    live = True
+    if tile["causal"]:
+        live = (step + 1) * tile["block_q"] - 1 >= ki * tile["block_k"]
+    return step, live
+
+
+def _visible(qi, ki, *, block_q, block_k, causal, seq_len, window=None):
+    """[block_q, block_k] mask of tile (qi, ki): the key is no padding,
+    not in the query's future (causal) and at most window - 1 behind."""
+    k_pos = ki * block_k + jax.lax.broadcasted_iota(
+        jnp.int32, (block_q, block_k), 1)
+    valid = k_pos < seq_len          # mask the padded K tail
+    if causal:
+        q_pos = qi * block_q + jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, block_k), 0)
+        valid = jnp.logical_and(valid, q_pos >= k_pos)
+        if window is not None:
+            valid = jnp.logical_and(valid, q_pos - k_pos < window)
+    return valid
+
+
 def _attn_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
-                 *, block_q, block_k, causal, sm_scale, seq_len):
-    """One (bh, qi, ki) program. Scratch (acc/m/l) carries across ki —
-    the innermost grid axis is sequential on TPU.  Row statistics stay
+                 *, sm_scale, **tile):
+    """One (bh, qi, step) program. Scratch (acc/m/l) carries across the
+    innermost grid axis, which is sequential on TPU: every key tile
+    without a window, the band's tiles with one.  Row statistics stay
     2-D ([block_q, 128], every lane equal) end to end: Mosaic lays
     vectors out on (sublane, lane) tiles and 1-D row vectors have no
     stable layout."""
+    block_q, block_k = tile["block_q"], tile["block_k"]
     qi = pl.program_id(1)
-    ki = pl.program_id(2)
+    step = pl.program_id(2)
     num_k = pl.num_programs(2)
     head_dim = q_ref.shape[-1]
     # f32 in means f32 math: Mosaic's default contraction rounds f32
@@ -68,16 +160,13 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
     precision = jax.lax.Precision.HIGHEST \
         if q_ref.dtype == jnp.float32 else None
 
-    @pl.when(ki == 0)
+    @pl.when(step == 0)
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
         m_ref[:] = jnp.full_like(m_ref, _NEG)
         l_ref[:] = jnp.zeros_like(l_ref)
 
-    # causal: skip K tiles strictly in the future of this q block
-    live = True
-    if causal:
-        live = (qi + 1) * block_q - 1 >= ki * block_k
+    ki, live = _key_tile(qi, step, **tile)
 
     @pl.when(live)
     def _step():
@@ -88,14 +177,7 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
                                 precision=precision,
                                 preferred_element_type=jnp.float32)
         s = s * np.float32(sm_scale)
-        k_pos = ki * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
-        valid = k_pos < seq_len          # mask the padded K tail
-        if causal:
-            q_pos = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            valid = jnp.logical_and(valid, q_pos >= k_pos)
-        s = jnp.where(valid, s, _NEG)
+        s = jnp.where(_visible(qi, ki, **tile), s, _NEG)
 
         m_prev = m_ref[:]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1)[:, None])
@@ -109,7 +191,7 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
                                 precision=precision,
                                 preferred_element_type=jnp.float32)
 
-    @pl.when(ki == num_k - 1)
+    @pl.when(step == num_k - 1)
     def _finalize():
         total = jnp.maximum(l_ref[:], _TINY)
         o_ref[:] = (acc_ref[:] / _lane_cols(total, head_dim)) \
@@ -118,9 +200,23 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
         lse_ref[:] = m_ref[:] + jnp.log(total)
 
 
-def _flash_fwd_impl(q, k, v, causal, sm_scale, block_q, block_k, interpret):
-    """(output [B, H, S, D], log-sum-exp of the scaled scores [B, H, S])."""
+def _flash_fwd_impl(q, k, v, causal, sm_scale, block_q, block_k, interpret,
+                    window=None):
+    """(output [B, H, S, D], log-sum-exp of the scaled scores [B, H, S]).
+
+    Without a *window* the grid steps over every (query tile, key tile)
+    pair: a causal query tile's future key tiles are fetched and skipped
+    (``pl.when``).  With one (causal only: keys q_pos - window < k_pos <=
+    q_pos) the innermost axis is as long as the band, ceil((window - 1) /
+    tile) + 1 key tiles for square tiles, and the block map offsets it by
+    the query tile's first visible key tile: tiles outside the band are
+    never visited, so the kernel's time follows S x window.  Near the
+    sequence's start the band is shorter than the axis; the surplus steps
+    name the band's last tile again, fetch nothing and compute nothing."""
     b, h, s, d = q.shape
+    if window is not None and not causal:
+        raise ValueError("flash_attention: a window of %s keys needs "
+                         "causal=True" % (window,))
     hkv = k.shape[1]
     if h % hkv or v.shape[1] != hkv:
         raise ValueError("flash_attention: %d query heads over %d/%d "
@@ -148,20 +244,26 @@ def _flash_fwd_impl(q, k, v, causal, sm_scale, block_q, block_k, interpret):
         pad = [(0, 0), (0, s_pad - s), (0, 0)]
         kf = jnp.pad(kf, pad)
         vf = jnp.pad(vf, pad)
-    kernel = functools.partial(_attn_kernel, block_q=bq, block_k=bk,
-                               causal=causal, sm_scale=scale, seq_len=s)
+    tile = dict(block_q=bq, block_k=bk, causal=causal, seq_len=s)
+    n_q, n_k = pl.cdiv(s, bq), s_pad // bk
+    if window is not None:
+        tile["window"] = int(window)
+        n_k = _band_len(bq, bk, tile["window"], n_k)
+    kernel = functools.partial(_attn_kernel, sm_scale=scale, **tile)
     zero = np.int32(0)      # a bare 0 is an i64 block index under x64
+
     # query head bh reads key/value head bh // group (heads are the inner
     # axis of both): the repeat is an index, never a tensor
+    def of_key(bh, i, t):
+        return (bh // group, _key_tile(i, t, **tile)[0], zero)
+
     out, lse = pl.pallas_call(
         kernel,
-        grid=(b * h, pl.cdiv(s, bq), s_pad // bk),
+        grid=(b * h, n_q, n_k),
         in_specs=[
             pl.BlockSpec((None, bq, d), lambda bh, i, t: (bh, i, zero)),
-            pl.BlockSpec((None, bk, d),
-                         lambda bh, i, t: (bh // group, t, zero)),
-            pl.BlockSpec((None, bk, d),
-                         lambda bh, i, t: (bh // group, t, zero)),
+            pl.BlockSpec((None, bk, d), of_key),
+            pl.BlockSpec((None, bk, d), of_key),
         ],
         out_specs=[
             pl.BlockSpec((None, bq, d), lambda bh, i, t: (bh, i, zero)),
@@ -182,17 +284,12 @@ def _flash_fwd_impl(q, k, v, causal, sm_scale, block_q, block_k, interpret):
     return out.reshape(b, h, s, d), lse[..., 0].reshape(b, h, s)
 
 
-def _attn_probs(s, lse, qi, ki, *, block_q, block_k, causal, seq_len):
+def _attn_probs(s, lse, qi, ki, **tile):
     """exp(s - lse) on a [block_q, block_k] tile of scaled scores, zero
-    where the key is padding or (causal) in the query's future."""
-    k_pos = ki * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1)
-    valid = k_pos < seq_len
-    if causal:
-        q_pos = qi * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0)
-        valid = jnp.logical_and(valid, q_pos >= k_pos)
-    return jnp.where(valid, jnp.exp(s - _lane_cols(lse, block_k)),
+    where the key is padding, in the query's future (causal) or behind
+    its window."""
+    return jnp.where(_visible(qi, ki, **tile),
+                     jnp.exp(s - _lane_cols(lse, tile["block_k"])),
                      np.float32(0.0))
 
 
@@ -216,16 +313,15 @@ def _attn_tile_grads(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qi, ki,
 
 def _attn_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
                     acc_ref, *, sm_scale, **tile):
-    """One (bh, qi, ki) program of dq = scale * ds k, ki sequential."""
-    qi, ki = pl.program_id(1), pl.program_id(2)
+    """One (bh, qi, step) program of dq = scale * ds k, the key tiles of
+    ``_key_tile`` in sequence."""
+    qi, step = pl.program_id(1), pl.program_id(2)
 
-    @pl.when(ki == 0)
+    @pl.when(step == 0)
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    live = True
-    if tile["causal"]:
-        live = (qi + 1) * tile["block_q"] - 1 >= ki * tile["block_k"]
+    ki, live = _key_tile(qi, step, **tile)
 
     @pl.when(live)
     def _step():
@@ -236,27 +332,25 @@ def _attn_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
             ds, k_ref[:], (((1,), (0,)), ((), ())), precision=precision,
             preferred_element_type=jnp.float32)
 
-    @pl.when(ki == pl.num_programs(2) - 1)
+    @pl.when(step == pl.num_programs(2) - 1)
     def _finalize():
         dq_ref[:] = (acc_ref[:] * np.float32(sm_scale)).astype(dq_ref.dtype)
 
 
 def _attn_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
                      dv_ref, dk_acc, dv_acc, *, sm_scale, **tile):
-    """One (key/value head, ki, query head of its group, qi) program of
+    """One (key/value head, ki, query head of its group, step) program of
     dv = p^T do and dk = scale * ds^T q; the last two axes sequential, so
     a key/value head's gradient gathers over the query heads that read
-    it."""
-    ki, gi, qi = pl.program_id(1), pl.program_id(2), pl.program_id(3)
+    it and over the query tiles of ``_query_tile``."""
+    ki, gi, step = pl.program_id(1), pl.program_id(2), pl.program_id(3)
 
-    @pl.when(jnp.logical_and(gi == 0, qi == 0))
+    @pl.when(jnp.logical_and(gi == 0, step == 0))
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    live = True
-    if tile["causal"]:
-        live = (qi + 1) * tile["block_q"] - 1 >= ki * tile["block_k"]
+    qi, live = _query_tile(ki, step, **tile)
 
     @pl.when(live)
     def _step():
@@ -271,20 +365,24 @@ def _attn_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
             preferred_element_type=jnp.float32)
 
     @pl.when(jnp.logical_and(gi == pl.num_programs(2) - 1,
-                             qi == pl.num_programs(3) - 1))
+                             step == pl.num_programs(3) - 1))
     def _finalize():
         dk_ref[:] = (dk_acc[:] * np.float32(sm_scale)).astype(dk_ref.dtype)
         dv_ref[:] = dv_acc[:].astype(dv_ref.dtype)
 
 
 def _flash_bwd_impl(q, k, v, out, lse, do, causal, sm_scale, block,
-                    interpret):
+                    interpret, window=None):
     """Gradients of ``_flash_fwd_impl`` by two Pallas kernels over square
     tiles of *block*: the probabilities are recomputed from the forward's
-    log-sum-exp, causal tiles in a query's future are skipped, and a
-    key/value head's gradient gathers over its group of query heads
-    inside the kernel.  Everything is padded with zeros to whole tiles: a
-    padded query row has do = 0 and delta = 0 and adds nothing."""
+    log-sum-exp, and a key/value head's gradient gathers over its group
+    of query heads inside the kernel.  Without a *window* both grids step
+    over every tile pair and skip (``pl.when``) the causal tiles in a
+    query's future; with one the innermost axis of each is the band —
+    the key tiles a query tile sees in ``dq``, the query tiles that see a
+    key tile in ``dk``/``dv`` — and tiles outside it are never visited
+    (``_flash_fwd_impl``).  Everything is padded with zeros to whole
+    tiles: a padded query row has do = 0 and delta = 0 and adds nothing."""
     b, h, s, d = q.shape
     hkv = k.shape[1]
     group = np.int32(h // hkv)
@@ -308,27 +406,31 @@ def _flash_bwd_impl(q, k, v, out, lse, do, causal, sm_scale, block,
                     axis=-1)
     qf, dof, kf, vf = rows(q, h), rows(do, h), rows(k, hkv), rows(v, hkv)
     lsef, deltaf = lanes(lse), lanes(delta)
-    tile = dict(block_q=blk, block_k=blk, causal=causal, seq_len=s,
-                sm_scale=scale)
+    tile = dict(block_q=blk, block_k=blk, causal=causal, seq_len=s)
+    n_keys = n_queries = n
+    if window is not None:
+        tile["window"] = int(window)
+        n_keys = n_queries = _band_len(blk, blk, tile["window"], n)
     zero = np.int32(0)
 
+    def of_row(bh, i, t):
+        return (bh, i, zero)
+
+    def of_band_key(bh, i, t):
+        return (bh // group, _key_tile(i, t, **tile)[0], zero)
+
     dq = pl.pallas_call(
-        functools.partial(_attn_dq_kernel, **tile),
-        grid=(b * h, n, n),
+        functools.partial(_attn_dq_kernel, sm_scale=scale, **tile),
+        grid=(b * h, n, n_keys),
         in_specs=[
-            pl.BlockSpec((None, blk, d), lambda bh, i, t: (bh, i, zero)),
-            pl.BlockSpec((None, blk, d),
-                         lambda bh, i, t: (bh // group, t, zero)),
-            pl.BlockSpec((None, blk, d),
-                         lambda bh, i, t: (bh // group, t, zero)),
-            pl.BlockSpec((None, blk, d), lambda bh, i, t: (bh, i, zero)),
-            pl.BlockSpec((None, blk, _LANES),
-                         lambda bh, i, t: (bh, i, zero)),
-            pl.BlockSpec((None, blk, _LANES),
-                         lambda bh, i, t: (bh, i, zero)),
+            pl.BlockSpec((None, blk, d), of_row),
+            pl.BlockSpec((None, blk, d), of_band_key),
+            pl.BlockSpec((None, blk, d), of_band_key),
+            pl.BlockSpec((None, blk, d), of_row),
+            pl.BlockSpec((None, blk, _LANES), of_row),
+            pl.BlockSpec((None, blk, _LANES), of_row),
         ],
-        out_specs=pl.BlockSpec((None, blk, d),
-                               lambda bh, i, t: (bh, i, zero)),
+        out_specs=pl.BlockSpec((None, blk, d), of_row),
         out_shape=jax.ShapeDtypeStruct((b * h, s_pad, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((blk, d), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
@@ -338,14 +440,14 @@ def _flash_bwd_impl(q, k, v, out, lse, do, causal, sm_scale, block,
     )(qf, kf, vf, dof, lsef, deltaf)
 
     def of_query(kv, t, g, i):
-        return (kv * group + g, i, zero)
+        return (kv * group + g, _query_tile(t, i, **tile)[0], zero)
 
     def of_key(kv, t, g, i):
         return (kv, t, zero)
 
     dk, dv = pl.pallas_call(
-        functools.partial(_attn_dkv_kernel, **tile),
-        grid=(b * hkv, n, int(group), n),
+        functools.partial(_attn_dkv_kernel, sm_scale=scale, **tile),
+        grid=(b * hkv, n, int(group), n_queries),
         in_specs=[
             pl.BlockSpec((None, blk, d), of_query),
             pl.BlockSpec((None, blk, d), of_key),
@@ -370,32 +472,34 @@ def _flash_bwd_impl(q, k, v, out, lse, do, causal, sm_scale, block,
             dv[:, :s].reshape(v.shape))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
 def flash_attention(q, k, v, causal=False, sm_scale=None, block_q=128,
-                    block_k=128, interpret=False):
+                    block_k=128, interpret=False, window=None):
     """Tiled flash attention: q [B, H, S, D], k and v [B, Hkv, S, D] ->
     [B, H, S, D]; query head i reads key/value head i // (H // Hkv).
+    With *window* (causal only) a query sees the *window* newest keys,
+    itself among them: q_pos - window < k_pos <= q_pos.
 
     Pallas streaming forward (K/V tiles via the sequential grid axis,
-    causal tile skipping) and Pallas backward (``flash_attention_dq``,
-    ``flash_attention_dkv``) from the forward's saved log-sum-exp.
-    ``interpret=True`` runs the kernel in the Pallas interpreter (CPU
-    tests).  Shard batch/head dims with ``shard_map`` before calling —
-    pallas_call is opaque to GSPMD.
+    causal tile skipping, a banded grid under a window) and Pallas
+    backward (``flash_attention_dq``, ``flash_attention_dkv``) from the
+    forward's saved log-sum-exp.  ``interpret=True`` runs the kernel in
+    the Pallas interpreter (CPU tests).  Shard batch/head dims with
+    ``shard_map`` before calling — pallas_call is opaque to GSPMD.
     """
     return _flash_fwd_impl(q, k, v, causal, sm_scale, block_q, block_k,
-                           interpret)[0]
+                           interpret, window)[0]
 
 
-def _fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret):
+def _fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret, window):
     out, lse = _flash_fwd_impl(q, k, v, causal, sm_scale, block_q, block_k,
-                               interpret)
+                               interpret, window)
     return out, (q, k, v, out, lse)
 
 
-def _bwd(causal, sm_scale, block_q, block_k, interpret, res, do):
+def _bwd(causal, sm_scale, block_q, block_k, interpret, window, res, do):
     return _flash_bwd_impl(*res, do, causal, sm_scale, max(block_q, block_k),
-                           interpret)
+                           interpret, window)
 
 
 flash_attention.defvjp(_fwd, _bwd)

@@ -558,7 +558,14 @@ COUNTERS = {
     "sparse_moe_rows": "routed rows (tokens x experts a token) over all "
                        "traced _contrib_SparseMoE ops: every one is in "
                        "a group, none is dropped",
-    "causal_attention_traced": "_contrib_CausalAttention ops traced",
+    "sparse_moe_held_rows_budget": "rows of one chunk of a partial share's "
+                                   "sorted order (ops/moe.py:_share_rows), "
+                                   "summed over the SparseMoE ops traced "
+                                   "that hold fewer experts than they route",
+    "causal_attention_traced": "_contrib_CausalAttention ops traced without "
+                               "a window",
+    "window_attention_traced": "_contrib_CausalAttention ops traced with a "
+                               "sliding window (banded kernels on a TPU)",
     "short_conv_traced": "_contrib_ShortConv ops traced",
     "lm_head_fused_traced": "_contrib_BlockedSoftmaxCE forward rules traced "
                             "(under differentiation: loss, dh and dW in "

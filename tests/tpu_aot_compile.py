@@ -73,8 +73,38 @@ def main():
     lowered.compile()
     print("AOT ok causal_attention grouped heads grad", flush=True)
 
+    # the banded grids at the Trinity widths (32 query heads over 4, head
+    # size 128, a window of 2,048 in 16,384 tokens): five of 32 tiles on
+    # the innermost axis of all three kernels
+    q = jax.ShapeDtypeStruct((1, 16384, 32, 128), jnp.bfloat16, sharding=one)
+    kv = jax.ShapeDtypeStruct((1, 16384, 4, 128), jnp.bfloat16, sharding=one)
+    lowered = jax.jit(jax.value_and_grad(
+        lambda *x: lm.causal_attention(*x, 128 ** -0.5, True, True, 2048)
+        .astype(jnp.float32).sum(), (0, 1, 2))).lower(q, kv, kv)
+    assert "tpu_custom_call" in lowered.as_text()
+    text = lowered.compile().as_text()
+    assert "window_attention_bwd" in text and \
+        "causal_attention" not in text
+    print("AOT ok window attention banded grad", flush=True)
+
     def spec(*shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    # a quarter share at the Trinity widths (32 of 128 experts of 1,024,
+    # top 8, 4,096 tokens): chunks of 12,288 of the 32,768 routed rows,
+    # every grouped product a Pallas kernel, the loop over later chunks
+    # in the program
+    moe._kernel_backend = lambda: True
+    text = jax.jit(jax.value_and_grad(
+        lambda *x: moe.sparse_moe(*x, num_experts=128, num_experts_per_tok=8)
+        [0].astype(jnp.float32).sum(), (0, 1, 2, 3, 4))).lower(
+        spec(4096, 2048), spec(128, 2048), spec(32, 2048, 1024),
+        spec(32, 2048, 1024), spec(32, 1024, 2048),
+        spec(128, dtype=jnp.float32)).compile().as_text()
+    assert text.count("%gmm") >= 9 and "while" in text
+    assert "bf16[12288,2048]" in text and "bf16[32768,2048]" not in text
+    print("AOT ok sparse_moe quarter share, bounded chunks", flush=True)
+
     def layer(*x):
         return moe.sparse_moe(*x, num_experts=8, num_experts_per_tok=4)[0] \
             .astype(jnp.float32).sum()
