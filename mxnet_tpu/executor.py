@@ -47,6 +47,7 @@ from .context import Context, current_context
 from . import random as _random
 from . import telemetry as _telemetry
 from .ndarray import NDArray, _wrap, zeros as nd_zeros
+from .ops import remat as _remat
 from .symbol.symbol import Symbol, _topo
 
 __all__ = ["Executor"]
@@ -200,8 +201,11 @@ def _build_graph_fn(symbol, train_mode):
 
     def run_segment(stage, group, env, keys):
         """The segment's forward under ``jax.checkpoint``: what it reads
-        from outside is saved, everything inside is computed again in
-        the backward pass."""
+        from outside is saved, and of what it computes the values its ops
+        hand to ``ops/remat.py:keep`` (a flash forward's ``out`` and
+        ``lse``, a routing's indices); everything else inside is computed
+        again in the backward pass.  A segment whose ops keep nothing
+        compiles to the program it would without the policy."""
         reads, writes = plans[stage]
 
         def forward(vals, keys):
@@ -211,7 +215,9 @@ def _build_graph_fn(symbol, train_mode):
                     run_node(n, local, keys)
             return tuple(local[k] for k in writes)
 
-        outs = jax.checkpoint(forward)(tuple(env[k] for k in reads), keys)
+        with _remat.segment():
+            outs = jax.checkpoint(forward, policy=_remat.POLICY)(
+                tuple(env[k] for k in reads), keys)
         env.update(zip(writes, outs))
 
     def graph_fn(arg_vals, aux_vals, rng):

@@ -15,7 +15,9 @@ equations and what is assumed beyond the published ``config.json``):
 and after the last layer RMSNorm, then the blocked head on the tied
 embedding: the graph's output is the mean next-token negative
 log-likelihood, shape (1,).  Every layer is one recomputation segment
-(``force_mirroring``).
+(``force_mirroring``): its backward computes the layer again, except the
+attention kernel's ``out`` and ``lse`` and the routing's indices, which
+the segment keeps (``ops/remat.py``); the experts' rows are replayed.
 """
 from __future__ import annotations
 

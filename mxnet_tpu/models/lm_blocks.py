@@ -63,7 +63,10 @@ def layer_kinds(cfg, who):
 
 
 def layer_scope(i, recompute):
-    """One layer, one recomputation segment."""
+    """One layer, one recomputation segment: the backward pass computes
+    the layer again but for what its ops keep (``ops/remat.py``: the
+    attention kernel's output and row statistic, the routing's
+    indices)."""
     return attribute.AttrScope(force_mirroring="True",
                                mirror_stage=str(i)) if recompute \
         else attribute.AttrScope()

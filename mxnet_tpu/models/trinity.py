@@ -17,7 +17,10 @@ equations and what is assumed beyond the published ``config.json``):
 The input is the embedding times sqrt(H) (``mup_enabled``); after the
 last layer RMSNorm, then the blocked head on an untied ``lm_head_weight``:
 the graph's output is the mean next-token negative log-likelihood, shape
-(1,).  Every layer is one recomputation segment (``force_mirroring``).
+(1,).  Every layer is one recomputation segment (``force_mirroring``):
+its backward computes the layer again, except the attention kernel's
+``out`` and ``lse`` and the routing's indices, which the segment keeps
+(``ops/remat.py``; 136 MB and under 2 MB a layer at 16,384 tokens).
 """
 from __future__ import annotations
 

@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 
 from .. import telemetry as _tel
+from . import remat as _remat
 from .registry import register
 
 
@@ -107,7 +108,7 @@ def causal_attention(q, k, v, scale, causal, use_kernel, window=None):
     the Pallas ``flash_attention`` kernels (tiles of 512, a banded grid
     under a window), else the masked softmax in ``jnp`` and its
     ``jax.vjp``."""
-    return _attention_fwd(q, k, v, scale, causal, use_kernel, window)[0]
+    return _attention(q, k, v, scale, causal, use_kernel, window)[0]
 
 
 def _heads_first(*xs):
@@ -119,7 +120,9 @@ def _attention_scope(window, direction):
         "causal" if window is None else "window", direction))
 
 
-def _attention_fwd(q, k, v, scale, causal, use_kernel, window):
+def _attention(q, k, v, scale, causal, use_kernel, window, keep=None):
+    """(the result, what the backward reads); *keep* is applied to the
+    kernel's ``out`` and ``lse`` before anything reads them."""
     from . import pallas_kernels as pk
     with _attention_scope(window, "fwd"):
         qh, kh, vh = _heads_first(q, k, v)
@@ -127,12 +130,28 @@ def _attention_fwd(q, k, v, scale, causal, use_kernel, window):
             out, lse = pk._flash_fwd_impl(qh, kh, vh, causal, scale,
                                           _ATTENTION_TILE, _ATTENTION_TILE,
                                           False, window)
+            if keep is not None:
+                out, lse = keep(out, lse)
             saved = (qh, kh, vh, out, lse)
         else:
             out = _masked_softmax_attention(qh, kh, vh, scale, causal,
                                             window)
             saved = (qh, kh, vh)
     return out.transpose(0, 2, 1, 3), saved
+
+
+def _attention_fwd(q, k, v, scale, causal, use_kernel, window):
+    """The forward rule.  On the kernel path a recomputation segment keeps
+    ``out`` and ``lse`` (``ops/remat.py``): together one activation of the
+    op's own size plus a float32 row statistic (134 + 2 MB a layer at
+    16,384 tokens, 32 heads of 128), for which the segment's replay would
+    otherwise run the whole Pallas forward again, S x S or S x window
+    work, only to hand the backward kernels what was there already; with
+    or without a window.  The projections that make q, k and v are
+    replayed as before.  The ``jnp`` path keeps nothing: its backward is
+    ``jax.vjp`` of the forward and reads only q, k, v."""
+    return _attention(q, k, v, scale, causal, use_kernel, window,
+                      _remat.keep)
 
 
 def _attention_bwd(scale, causal, use_kernel, window, saved, do):

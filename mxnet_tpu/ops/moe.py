@@ -41,6 +41,7 @@ import jax
 import jax.numpy as jnp
 
 from .. import telemetry as _tel
+from . import remat as _remat
 from .registry import register
 
 _DIMS = jax.lax.RaggedDotDimensionNumbers
@@ -52,12 +53,15 @@ def topk_route(scores, k, bias=None, normalise=True, scale=1.0, eps=1e-6):
     their weights are ``scores`` at those experts (without the bias),
     divided by their sum + *eps* if *normalise*, times *scale*.  Returns
     (expert ids [tokens, k] int32, weights [tokens, k] float32); ties go
-    to the lower id."""
+    to the lower id.  A recomputation segment keeps the ids
+    (``ops/remat.py``), so its replay gathers the weights from scores it
+    computes again but chooses nothing again."""
     _, idx = jax.lax.top_k(scores if bias is None else scores + bias, k)
+    (idx,) = _remat.keep(idx.astype(jnp.int32))
     weight = jnp.take_along_axis(scores, idx, axis=-1)
     if normalise:
         weight = weight / (jnp.sum(weight, axis=-1, keepdims=True) + eps)
-    return idx.astype(jnp.int32), weight * scale
+    return idx, weight * scale
 
 
 _TILE_ROWS = 512
@@ -317,7 +321,18 @@ def sparse_moe(x, router_weight, w1, w3, w2, expert_bias, num_experts,
     [num_experts], added to the scores for the choice only;
     ``routed_scaling_factor`` (a family's ``route_scale``) multiplies the
     weights after their normalisation by the chosen scores' sum +
-    ``norm_topk_eps``."""
+    ``norm_topk_eps``.
+
+    Inside a recomputation segment (``ops/remat.py``) the routing's
+    integers are kept for the backward: the expert ids, ``order``,
+    ``inverse`` and ``sizes``, under 2 MB a layer at 131,072 routed rows,
+    for which the replay would run ``top_k``'s selection and both
+    ``argsort``s again.  The float side (logits, scores, weights) is
+    replayed: the router's gradient needs it and it is one small product.
+    The experts' rows are replayed too, by their bytes: what
+    ``_chunk_fwd`` keeps (``xs``, ``h1``, ``h3``) is 65,536 x (2,048 + 2 x
+    1,792) x 2 B = 738 MB a layer at LFM2's shapes, 2.95 GB over its four
+    expert layers on a chip that stands at 94.7% of its memory."""
     k, held = int(num_experts_per_tok), w1.shape[0]
     if router_weight.shape[0] != int(num_experts) or \
             first_expert + held > int(num_experts):
@@ -350,6 +365,7 @@ def sparse_moe(x, router_weight, w1, w3, w2, expert_bias, num_experts,
         # a compare and a sum: XLA:TPU's scatter-add (bincount) took 3.9 ms
         sizes = jnp.sum(key[:, None] == jnp.arange(held)[None, :], axis=0,
                         dtype=jnp.int32)
+        order, inverse, sizes = _remat.keep(order, inverse, sizes)
     _tel.bump("sparse_moe_traced")
     _tel.bump("sparse_moe_rows", rows)
     chunk = None

@@ -754,6 +754,12 @@ def power_retention(q, k, v, log_gate, chunk=128, eps=1e-6, use_kernel=False,
     ``use_kernel`` runs the forward as the Pallas kernel (TPU, or
     ``interpret=True``), else as the same algorithm in ``jnp``; the
     backward scans the chunks in reverse in ``jnp`` either way.
+
+    A recomputation segment keeps nothing of this op (``ops/remat.py``)
+    and runs its forward again, by the residuals' bytes: at 16,384 tokens,
+    8 key/value heads of 128 and chunks of 1,024 the chunk states are
+    ``S0`` [8, 16, 128, 128, 128] bf16 = 537 MB, ``Z0`` 4 MB and ``o``
+    168 MB a layer, several times the op's output.
     """
     return _retention_fwd(q, k, v, log_gate, chunk, eps, use_kernel,
                           interpret)[0]

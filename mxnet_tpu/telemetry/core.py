@@ -548,7 +548,13 @@ COUNTERS = {
                            "(the fit loop's one step of overlap)",
     "executor_remat_segments": "recomputation segments (force_mirroring "
                                "+ mirror_stage) wrapped in jax.checkpoint "
-                               "at bind",
+                               "at bind: the backward replays each but "
+                               "for the values counted below",
+    "executor_remat_kept": "values that ops traced inside a recomputation "
+                           "segment marked to be kept for the backward "
+                           "instead of replayed (ops/remat.py:keep: two an "
+                           "attention op on the kernel path, four a "
+                           "SparseMoE op), summed over traces",
     "power_retention_traced": "_contrib_PowerRetention ops traced (the "
                               "chunked state form)",
     "power_retention_chunks": "chunks a sequence over all traced "
