@@ -161,6 +161,8 @@ class CachedTrainStep:
         self._carried = ([None] * len(self._pnames),
                          [None] * len(executor.aux_names),
                          [None] * len(self._pnames), None)
+        self._compiled = False     # no run yet: the next one is set-up
+        self.setup_args = {}       # its owner's tag for the set-up span
 
     def _gather(self, names, table, carried):
         """Raw buffers of ``table[name]`` for *names*, and how many went
@@ -191,6 +193,20 @@ class CachedTrainStep:
     def run(self, feed):
         """Execute one step; *feed* maps data/label names to NDArrays."""
         _tel.bump("module_train_step")
+        if not self._compiled:
+            # the run that traces, lowers and compiles (or loads) the step
+            # program: set-up, told apart from every later step and timed
+            # to the end of the program's first run
+            self._compiled = True
+            with _tel.span("module_first_step", cat="setup",
+                           args=dict(self.setup_args,
+                                     params=len(self._pnames))):
+                outputs = self._step(feed)
+                jax.block_until_ready([o._data for o in outputs])
+            return outputs
+        return self._step(feed)
+
+    def _step(self, feed):
         with _tel.span("module_train_step", cat="step",
                        hist="step_time_us", memory=True,
                        args={"params": len(self._pnames)}):

@@ -154,6 +154,20 @@ class FusedExecutorGroup(object):
                              if n in mex.arg_dict]
         return True
 
+    def place_params(self):
+        """Replicate parameters and aux states over the mesh now.  The
+        fused step's build calls it (``Module._get_cached_step``, under
+        ``module_step_build``), so that the first step finds them where
+        its program wants them and times tracing, compiling and running
+        only; without the call the first ``_place`` of each does the same
+        ``device_put``."""
+        ex = self._exec
+        for name in self.param_names:
+            if name in ex.arg_dict:
+                ex._place(name, ex.arg_dict[name])
+        for name in ex.aux_names:
+            ex._place(name, ex.aux_dict[name])
+
     def set_params(self, arg_params, aux_params, allow_extra=False):
         for name, arr in (arg_params or {}).items():
             if name in self._exec.arg_dict:

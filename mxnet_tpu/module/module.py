@@ -19,6 +19,7 @@ from .. import ndarray as nd
 from .. import optimizer as opt
 from ..initializer import Uniform, InitDesc
 from ..io import DataDesc
+from .. import telemetry as _tel
 from ..model import (_create_kvstore, _initialize_kvstore, _update_params,
                      _update_params_on_kvstore, load_checkpoint)
 from .base_module import BaseModule, _check_input_names
@@ -208,11 +209,26 @@ class Module(BaseModule):
         Contract (ref module.py:246): provided dicts win; missing entries fall
         back to the initializer when ``allow_missing``, else raise.
         """
+        if not self._may_init_params(force_init):
+            return
+        with _tel.span("module_init_params", cat="setup",
+                       args=self._setup_args()):
+            with _tel.span("init_params_host", cat="setup"):
+                self._fill_host_params(initializer, arg_params, aux_params,
+                                       allow_missing)
+            with _tel.span("init_params_place", cat="setup"):
+                self._place_host_params(allow_extra)
+
+    def _may_init_params(self, force_init):
         if self.params_initialized and not force_init:
             warnings.warn("init_params ignored: already initialized "
-                          "(pass force_init=True to override)", stacklevel=2)
-            return
+                          "(pass force_init=True to override)", stacklevel=3)
+            return False
         self._require_bound()
+        return True
+
+    def _fill_host_params(self, initializer, arg_params, aux_params,
+                          allow_missing):
         if initializer is None:
             initializer = Uniform(0.01)
         self._alloc_host_params()
@@ -234,6 +250,7 @@ class Module(BaseModule):
                 else:
                     raise RuntimeError("%s is not presented" % name)
 
+    def _place_host_params(self, allow_extra):
         self.params_initialized, self._params_dirty = True, False
         self._exec_group.set_params(
             self._arg_params, self._aux_params, allow_extra=allow_extra)
@@ -241,9 +258,11 @@ class Module(BaseModule):
     def set_params(self, arg_params, aux_params, allow_missing=False,
                    force_init=True, allow_extra=False):
         if not allow_missing:
-            self.init_params(initializer=None, arg_params=arg_params,
-                             aux_params=aux_params, allow_missing=False,
-                             force_init=force_init, allow_extra=allow_extra)
+            # init_params' work without its set-up spans: fit() comes here
+            # at every epoch's end, which is no start
+            if self._may_init_params(force_init):
+                self._fill_host_params(None, arg_params, aux_params, False)
+                self._place_host_params(allow_extra)
             return
         if self.params_initialized and not force_init:
             warnings.warn("set_params ignored: already initialized "
@@ -271,7 +290,21 @@ class Module(BaseModule):
         if self.binded:
             self.logger.warning("Already bound, ignoring bind()")
             return
+        with _tel.span("module_bind", cat="setup",
+                       args=dict(self._setup_args(),
+                                 contexts=len(self._context),
+                                 for_training=bool(for_training))):
+            self._bind(data_shapes, label_shapes, for_training,
+                       inputs_need_grad, shared_module, grad_req)
 
+    def _setup_args(self):
+        """What every set-up span of this module carries: which module it
+        is, so that a reader can tell one trainer's start from another's
+        in the same process."""
+        return {"module": id(self)}
+
+    def _bind(self, data_shapes, label_shapes, for_training,
+              inputs_need_grad, shared_module, grad_req):
         self.for_training = for_training
         self.inputs_need_grad = inputs_need_grad
         self._grad_req = grad_req
@@ -362,6 +395,11 @@ class Module(BaseModule):
         if self.optimizer_initialized and not force_init:
             self.logger.warning("optimizer already initialized, ignoring...")
             return
+        with _tel.span("module_init_optimizer", cat="setup",
+                       args=self._setup_args()):
+            self._init_optimizer(kvstore, optimizer, optimizer_params)
+
+    def _init_optimizer(self, kvstore, optimizer, optimizer_params):
         if self._params_dirty:
             self._sync_params_from_devices()
 
@@ -516,11 +554,19 @@ class Module(BaseModule):
                 and cached._updater is self._updater:
             self._cached_step = cached
             return cached
-        try:
-            cached = CachedTrainStep(ex, self._updater, group.param_names)
-        except ValueError:
-            cached = None
-            self._cached_step_unusable = True
+        with _tel.span("module_step_build", cat="setup",
+                       args=self._setup_args()):
+            try:
+                cached = CachedTrainStep(ex, self._updater,
+                                         group.param_names)
+            except ValueError:
+                cached = None
+                self._cached_step_unusable = True
+            if cached is not None:
+                cached.setup_args = self._setup_args()
+                place = getattr(group, "place_params", None)
+                if place is not None:
+                    place()     # the SPMD group: over the mesh, once
         group._cached_train_step = cached
         self._cached_step = cached
         return cached

@@ -17,12 +17,17 @@ is organised around four questions instead of one:
    ``profiler.bump()/counter()`` remain as shims onto it, and the counter
    fast path stays a lock+int-add (tests hold program-count contracts to
    deltas of ``xla_program_calls``; that must never get slower or gated).
-3. **What recompiles?**  The retrace watchdog (:func:`watch_jit`) wraps
-   every jit entry point the framework owns.  A wrapped callable whose
-   jit cache grows during a call records a compile event (name, wall time,
-   cache size) and, past ``MXNET_TELEMETRY_RETRACE_LIMIT`` compiles for one
-   name, logs ONE structured retrace-storm warning — the signature of a
-   shape-unstable input pipeline silently recompiling every step.
+3. **What compiles, and what did a start cost?**  One compile ledger,
+   always on, fed by JAX's own monitoring events: every backend compile
+   in the process — the framework's, a user's own jit, an eager op's — is
+   one row of :func:`compile_events` (JAX's ``fun_name``, the enclosing
+   :func:`watch_jit` name and program span if any, seconds tracing,
+   lowering and in the backend, and whether the persistent cache served
+   it).  The listeners fire only when JAX compiles, so a steady-state
+   step never reaches them.  With telemetry on, past
+   ``MXNET_TELEMETRY_RETRACE_LIMIT`` compiles for one watched name the
+   watchdog logs ONE structured retrace-storm warning — the signature of
+   a shape-unstable input pipeline silently recompiling every step.
 4. **How do I read it?**  Exporters: :func:`dump_chrome_trace` (merged
    trace + ``ph:"M"`` track-name metadata), :func:`prometheus_text`
    (text exposition), :func:`snapshot`/:func:`dump_snapshot` (JSON),
@@ -39,7 +44,10 @@ and compile events here), :mod:`..server` (the ``MXNET_TELEMETRY_HTTP``
 introspection endpoints), :mod:`..costs` (MFU/roofline accounting).
 
 Gating: ``MXNET_TELEMETRY=1`` enables spans/histograms/watchdog/memory
-sampling.  Counters are ALWAYS on; with telemetry off every other hook is
+sampling.  Counters and the compile ledger are ALWAYS on, and so are the
+spans of category ``setup`` (bind, parameter init, optimizer init, the
+step's build and its first run: once a bind, never once a batch); with
+telemetry off every other hook is
 one cached-bool check (plus, for step/program spans, the one attribute
 compare that keeps the flight recorder's progress clock ticking).  Spans
 also record whenever the classic profiler is running
@@ -51,8 +59,9 @@ session's own trace, on the device's clock); the watchdog, cost capture,
 histograms, memory sampling and time series stay as off as they were.
 
 This module is import-light on purpose (stdlib only; jax only touched
-inside memory sampling and, lazily, by the profiler-session leg) — every
-hot path in the framework imports it.
+inside memory sampling and, lazily, by the profiler-session leg and the
+compile ledger's two listeners) — every hot path in the framework
+imports it.
 """
 from __future__ import annotations
 
@@ -203,6 +212,7 @@ def _poll_session():
     if probe is None:
         if "jax" not in sys.modules:
             return False
+        _bind_compile_listeners()
         try:
             from jax._src.lib import _profiler
             from jax.profiler import TraceAnnotation
@@ -248,9 +258,9 @@ _CAT_TRACK = {"operator": "eager-dispatch", "program": "executor",
               "step": "train-step", "batch": "train-step",
               "host": "host-phase", "kvstore": "kvstore", "io": "data-io",
               "compile": "jit-compile", "serving": "serving",
-              "rpc": "dist-rpc", "user": "user"}
+              "rpc": "dist-rpc", "setup": "set-up", "user": "user"}
 _CAT_PRIORITY = ("step", "batch", "serving", "program", "kvstore", "io",
-                 "operator", "rpc", "compile", "host", "user")
+                 "operator", "rpc", "setup", "compile", "host", "user")
 
 
 def now_us():
@@ -382,6 +392,11 @@ class span:
     device's clock.  Off path (telemetry off, profiler stopped, no
     session) is one bool check.
 
+    ``cat="setup"`` spans record whatever the switches say: they run once
+    a bind (``module_bind`` ... ``module_first_step``), a dozen times a
+    process, and are how a start is read afterwards (the compile
+    ledger's rows name the one they happened under).
+
     Batch roots — ``cat="batch"`` spans, and ``cat="step"`` spans outside
     one — ask once whether a profiler session is open; everything under
     them reads the cached answer.  A session alone records spans and
@@ -417,7 +432,7 @@ class span:
             session = _poll_session()
         else:
             session = _SESSION and _poll_session()
-        if not (_ENABLED or _PROF_RUNNING or session):
+        if not (_ENABLED or _PROF_RUNNING or session or cat == "setup"):
             self._on = False
             self._t0 = None
             if _DEVICE_TIME and cat == "step":
@@ -581,7 +596,14 @@ COUNTERS = {
                                 "float32 moments, hand-derived VJP)",
     "eager_invocations": "eager op dispatches through ndarray.invoke",
     "io_batches": "data batches produced by iterators",
-    "jit_compiles": "watched-jit cache misses (traces+compiles)",
+    "jit_compiles": "backend compiles (cache loads included) that "
+                    "happened inside a watched jit's call: rows of the "
+                    "compile ledger that carry a watch name",
+    "compile_cache_hits": "backend compiles the persistent compilation "
+                          "cache served (ledger rows with cache == hit)",
+    "compile_cache_misses": "backend compiles XLA ran with the persistent "
+                            "cache on (ledger rows with cache == miss): 0 "
+                            "over a start is a warm start",
     "retrace_storms": "watched callables that crossed the retrace limit",
     "trace_events_dropped": "spans evicted from the bounded trace ring",
     "sanitizer_violations": "footguns caught at runtime by MXNET_SANITIZE "
@@ -786,7 +808,8 @@ _PCT_BUCKETS = (10.0, 25.0, 50.0, 75.0, 90.0, 100.0)
 HISTOGRAMS = {
     "step_time_us": ("trainer/module step wall time", _US_BUCKETS),
     "eager_dispatch_us": ("eager op dispatch latency", _US_BUCKETS),
-    "jit_compile_us": ("watched-jit trace+compile wall time", _US_BUCKETS),
+    "jit_compile_us": ("trace + lowering + backend time of a compile "
+                       "ledger row", _US_BUCKETS),
     "bucket_bytes": ("kvstore bucket payload sizes", _BYTE_BUCKETS),
     "serving_latency_us": ("predict request latency, submit to result",
                            _US_BUCKETS),
@@ -825,6 +848,23 @@ SPANS = {
     "metric_wait": "a metric blocked until a device array is ready "
                    "(the device is still busy: overlap)",
     "metric_fetch": "a metric's device-to-host copy of a ready array",
+    "module_bind": "set-up: Module.bind, executors made for the input "
+                   "shapes (category setup: recorded whatever the switch)",
+    "module_init_params": "set-up: Module.init_params, host fill and "
+                          "placement",
+    "init_params_host": "set-up: the initializer (or the given dicts' "
+                        "copies) over the host arrays",
+    "init_params_place": "set-up: the host arrays onto the executors' "
+                         "devices (exec group set_params)",
+    "module_init_optimizer": "set-up: Module.init_optimizer (kvstore, "
+                             "optimizer, updater)",
+    "module_step_build": "set-up: the fused train step made for an "
+                         "executor group (CachedTrainStep; on the SPMD "
+                         "group also the parameters' placement over the "
+                         "mesh)",
+    "module_first_step": "set-up: the CachedTrainStep.run that finds no "
+                         "compiled step — trace, lower, compile or cache "
+                         "load, and the first run to its end",
     "module_train_step": "one Module cached train step (host side)",
     "module_step_feed": "module step: batch into the executor's arg_dict",
     "module_step_place_batch": "module step: data/label onto the "
@@ -999,33 +1039,191 @@ def observe(name, value):
 
 
 # --------------------------------------------------------------------------
-# retrace watchdog
+# compile ledger + retrace watchdog
 # --------------------------------------------------------------------------
+#
+# JAX tells a start in events (jax 0.9.0: jax/_src/dispatch.py, compiler.py,
+# compilation_cache.py), on the thread that compiles and in this order:
+#   duration  /jax/core/compile/jaxpr_trace_duration          fun_name=f
+#             (inner jits first, then the function that holds them; the
+#             lowering rules that follow trace too, inside the next event)
+#   duration  /jax/core/compile/jaxpr_to_mlir_module_duration fun_name=jit(f)
+#   event     /jax/compilation_cache/compile_requests_use_cache
+#   on a hit: event .../cache_hits, duration .../compile_time_saved_sec,
+#             duration .../cache_retrieval_time_sec
+#   duration  /jax/core/compile/backend_compile_duration      fun_name=jit(f)
+#             (XLA's compile, or on a hit the retrieval)
+# The backend event closes one row; what came before it on the thread is
+# that row's.  Nothing here is reached by a call that compiles nothing.
+
+_EV_TRACE = "/jax/core/compile/jaxpr_trace_duration"
+_EV_LOWER = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_EV_BACKEND = "/jax/core/compile/backend_compile_duration"
+_EV_CACHE_USED = "/jax/compilation_cache/compile_requests_use_cache"
+_EV_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+_EV_CACHE_SAVED = "/jax/compilation_cache/compile_time_saved_sec"
+
+_MAX_COMPILE_ROWS = 16384      # newest rows win; the per-name sums keep all
 
 _compile_lock = threading.Lock()
-_compiles = {}                 # name -> {"count", "total_us", "last_size"}
-_compile_log = []              # [{name, wall_us, cache_size, ts}]
+_compiles = {}                 # name -> sums (see _new_sums)
+_compile_log = deque(maxlen=_MAX_COMPILE_ROWS)
 _storm_warned = set()
+_pending = threading.local()   # this thread's open row + rows it closed
+_LISTENING = False
+
+
+def _bind_compile_listeners():
+    """Register the ledger's two listeners with ``jax.monitoring``, once.
+    Called at import, and again from the lazy binders, so this module still
+    imports (and a process that never loads jax still runs) without it."""
+    global _LISTENING
+    if _LISTENING or "jax" not in sys.modules:
+        return
+    try:
+        from jax import monitoring
+        monitoring.register_event_duration_secs_listener(_on_duration)
+        monitoring.register_event_listener(_on_event)
+        _LISTENING = True
+    except Exception:          # no monitoring: the ledger stays empty
+        pass
+
+
+def _on_event(event, **kwargs):
+    if event == _EV_CACHE_USED:
+        _pending.cache = "miss"        # until a hit says otherwise
+    elif event == _EV_CACHE_HIT:
+        _pending.cache = "hit"
+
+
+def _on_duration(event, duration, **kwargs):
+    if event == _EV_BACKEND:
+        _close_row(kwargs.get("fun_name"), duration)
+    elif event == _EV_TRACE:
+        # inner jits report before the function that holds them, and
+        # lowering rules trace too: keep them by name for the lowering
+        # event to pick its own
+        _pending.__dict__.setdefault("traces", {})[
+            kwargs.get("fun_name")] = duration
+    elif event == _EV_LOWER:
+        pend = _pending.__dict__
+        name = kwargs.get("fun_name") or ""
+        traces = pend.pop("traces", None) or {}
+        # "jit(f)" was traced as "f"
+        pend.setdefault("lowered", {})[name] = (
+            traces.get(name[name.find("(") + 1:-1], 0.0), duration)
+    elif event == _EV_CACHE_SAVED:
+        _pending.saved_s = duration
+
+
+def _watch_on_stack():
+    """Name of the innermost watched jit whose call is on this thread's
+    stack.  Walked at a compile only, so that a watched call that compiles
+    nothing pays nothing for the answer."""
+    code = _WatchedJit.__call__.__code__
+    frame = sys._getframe(2)
+    while frame is not None:
+        if frame.f_code is code:
+            return frame.f_locals["self"]._name
+        frame = frame.f_back
+    return None
+
+
+def _new_sums():
+    return {"count": 0, "trace_s": 0.0, "lower_s": 0.0, "backend_s": 0.0,
+            "saved_s": 0.0, "hit": 0, "miss": 0, "off": 0}
+
+
+def _total_ms(rec):
+    return (rec["trace_s"] + rec["lower_s"] + rec["backend_s"]) * 1e3
+
+
+def _close_row(fun_name, backend_s):
+    """One backend compile (or cache load) ended on this thread: book the
+    row, the sums per name, the counters and — while tracing — the
+    ``compile:<name>`` ring event."""
+    pend = _pending.__dict__
+    trace_s, lower_s = pend.get("lowered", {}).pop(fun_name, (0.0, 0.0))
+    row = {"fun_name": fun_name, "watch": _watch_on_stack(),
+           "span": current_span(),
+           "trace_s": trace_s, "lower_s": lower_s,
+           "backend_s": backend_s,
+           "cache": pend.pop("cache", "off"),
+           "saved_s": pend.pop("saved_s", 0.0),
+           "ts": now_us() - backend_s * 1e6}
+    pend["rows"] = pend.get("rows", 0) + 1
+    name = row["watch"] or fun_name or "?"
+    total_s = row["trace_s"] + row["lower_s"] + backend_s
+    with _compile_lock:
+        rec = _compiles.get(name)
+        if rec is None:
+            rec = _compiles[name] = _new_sums()
+        rec["count"] += 1
+        for key in ("trace_s", "lower_s", "backend_s", "saved_s"):
+            rec[key] += row[key]
+        rec[row["cache"]] += 1
+        count = rec["count"]
+        _compile_log.append(row)
+        # the watchdog's part: a storm is a WATCHED name past the limit
+        storm = _ENABLED and row["watch"] is not None \
+            and count > _RETRACE_LIMIT and name not in _storm_warned
+        if storm:
+            _storm_warned.add(name)
+            storm_ms = _total_ms(rec)
+    if row["watch"] is not None:
+        bump("jit_compiles")
+    if row["cache"] == "hit":
+        bump("compile_cache_hits")
+    elif row["cache"] == "miss":
+        bump("compile_cache_misses")
+    if _ENABLED:
+        observe("jit_compile_us", total_s * 1e6)
+        _flight.record("compile", name, wall_us=round(total_s * 1e6, 1),
+                       cache=row["cache"], compiles=count)
+    if trace_active():
+        add_event("compile:%s" % name, "compile", row["ts"],
+                  backend_s * 1e6,
+                  args={"fun_name": fun_name, "span": row["span"],
+                        "cache": row["cache"], "compiles": count,
+                        "trace_s": row["trace_s"],
+                        "lower_s": row["lower_s"]})
+        if _annotation_cls is not None:
+            # the compile is over by the time JAX says so: what an open
+            # profiler session gets is a marker at its end that carries
+            # the seconds
+            with _annotation_cls(_ANNOTATION_PREFIX + "compile:%s" % name,
+                                 backend_s=backend_s, cache=row["cache"]):
+                pass
+    if storm:
+        bump("retrace_storms")
+        _LOG.warning(
+            "retrace-storm %s",
+            json.dumps({"callable": name, "compiles": count,
+                        "limit": _RETRACE_LIMIT,
+                        "total_compile_ms": round(storm_ms, 3),
+                        "hint": "inputs keep changing shape/dtype/structure;"
+                                " pad or bucket them so the compiled program"
+                                " is reused"}, sort_keys=True))
 
 
 class _WatchedJit:
-    """Wrap a jitted callable; a call during which the jit cache grows is a
-    trace+compile and gets recorded against *name*.
+    """Wrap a jitted callable and give it a name in the compile ledger: a
+    backend compile that happens while its call is on the stack carries
+    ``watch=<name>``, telemetry on or off (the ledger's listener looks for
+    this frame; the call itself does nothing for it).
 
-    The compiled-program cache key itself is jax-internal; the observable
-    is the (name, cache-size) pair — enough to see WHAT keeps recompiling
-    and how much wall time each recompile costs.  Attribute access
+    With telemetry on the wrapper also acts on a call that compiled — the
+    ledger closed a row on this thread meanwhile — by capturing the
+    program's cost and running the trace checks.  Attribute access
     (``_cache_size``, ``lower`` ...) proxies to the wrapped callable so
     cache-size contract tests keep working against the wrapper.
     """
 
-    __slots__ = ("_fn", "_name", "_seen_lock", "_max_seen")
+    __slots__ = ("_fn", "_name")
 
     def __init__(self, fn, name):
         self._fn = fn
         self._name = name
-        self._seen_lock = threading.Lock()
-        self._max_seen = 0
 
     def __call__(self, *args, **kwargs):
         # MXNET_TRACECHECK and MXNET_DEVICE_TIME ride the same wrapper
@@ -1033,46 +1231,26 @@ class _WatchedJit:
         # and counters are always on)
         if not (_ENABLED or _TRACECHECK or _DEVICE_TIME):
             return self._fn(*args, **kwargs)
-        size_fn = getattr(self._fn, "_cache_size", None)
-        if size_fn is None:
-            return self._fn(*args, **kwargs)
-        before = size_fn()
+        pend = _pending.__dict__
+        before = pend.get("rows", 0)
         t0 = now_us()
         out = self._fn(*args, **kwargs)
-        after = size_fn()
-        if _DEVICE_TIME and after == before:
+        compiled = pend.get("rows", 0) != before
+        if _DEVICE_TIME and not compiled:
             # sampled device timing: block on the outputs so the wall
             # delta ≈ dispatch + device execution.  Fresh-compile calls
             # are excluded (trace+compile wall would pollute the
             # device-time series), and no extra XLA program ever runs —
             # block_until_ready only waits.
             _device().maybe_time(self._name, t0, out)
-        if after > before:
-            # dedupe concurrent observers of one compile: only the call
-            # that advances the high-water cache size books it
-            with self._seen_lock:
-                fresh = after > self._max_seen
-                if fresh:
-                    self._max_seen = after
-            if fresh:
-                wall = now_us() - t0
-                # cost capture pays an AOT lower+compile (partially
-                # cache-absorbed, still real): cap it at the first few
-                # variants per name so a retrace STORM — many compiles,
-                # exactly when extra compile time hurts most — stops
-                # paying after variant 3
-                # (skipped entirely on the MXNET_TRACECHECK-only path:
-                # the captured flops/bytes are only ever read by step
-                # spans, which need telemetry on — don't pay a second
-                # XLA compile for numbers nobody will consume)
-                cost = None
-                if _ENABLED and (after <= 3
-                                 or self._name not in _PROGRAM_COSTS):
-                    cost = _capture_cost(self._fn, self._name,
-                                         args, kwargs)
-                _record_compile(self._name, wall, after, cost)
-                if _TRACECHECK:
-                    _run_tracecheck(self._name, self._fn, args, kwargs)
+        if compiled:
+            # the captured flops/bytes are only ever read by step spans,
+            # which need telemetry on: the MXNET_TRACECHECK-only path
+            # skips the capture
+            if _ENABLED:
+                _capture_cost(self._fn, self._name, args, kwargs)
+            if _TRACECHECK:
+                _run_tracecheck(self._name, self._fn, args, kwargs)
         # cost window: a step span is open on this process — attribute
         # this program execution's FLOPs/bytes to it (dict .get + two
         # float adds; the window is None outside step spans)
@@ -1090,6 +1268,7 @@ class _WatchedJit:
 
 def watch_jit(fn, name):
     """Register *fn* (a ``jax.jit`` product) with the retrace watchdog."""
+    _bind_compile_listeners()
     return _WatchedJit(fn, name)
 
 
@@ -1214,46 +1393,15 @@ def _sample_engine_pending():
         pass
 
 
-def _record_compile(name, wall_us, cache_size, cost=None):
-    with _compile_lock:
-        rec = _compiles.setdefault(
-            name, {"count": 0, "total_us": 0.0, "last_size": 0})
-        rec["count"] += 1
-        rec["total_us"] += wall_us
-        rec["last_size"] = cache_size
-        count = rec["count"]
-        total_ms = rec["total_us"] / 1e3
-        _compile_log.append({"name": name, "wall_us": wall_us,
-                             "cache_size": cache_size, "ts": now_us()})
-        storm = count > _RETRACE_LIMIT and name not in _storm_warned
-        if storm:
-            _storm_warned.add(name)
-    bump("jit_compiles")
-    observe("jit_compile_us", wall_us)
-    _flight.record("compile", name, wall_us=round(wall_us, 1),
-                   cache_size=cache_size, compiles=count)
-    if trace_active():
-        t_end = now_us()
-        cargs = {"cache_size": cache_size, "compiles": count}
-        if cost is not None:
-            cargs["flops"] = cost[0]
-            cargs["bytes_accessed"] = cost[1]
-        add_event("compile:%s" % name, "compile", t_end - wall_us, wall_us,
-                  args=cargs)
-    if storm:
-        bump("retrace_storms")
-        _LOG.warning(
-            "retrace-storm %s",
-            json.dumps({"callable": name, "compiles": count,
-                        "limit": _RETRACE_LIMIT,
-                        "total_compile_ms": round(total_ms, 3),
-                        "hint": "inputs keep changing shape/dtype/structure;"
-                                " pad or bucket them so the compiled program"
-                                " is reused"}, sort_keys=True))
-
-
 def compile_events():
-    """The raw compile log: [{name, wall_us, cache_size, ts}, ...]."""
+    """The compile ledger, oldest row first: one dict a backend compile —
+    ``fun_name`` (JAX's, ``jit(f)``), ``watch`` (the enclosing watched
+    jit's name or None), ``span`` (the innermost open program span or
+    None: a compile outside every span is not the trainer's), ``trace_s``,
+    ``lower_s``, ``backend_s`` (XLA's compile, or the retrieval on a
+    hit), ``cache`` (``hit`` / ``miss`` / ``off``), ``saved_s`` (JAX's
+    estimate of compile time a hit saved) and ``ts``, the backend phase's
+    start on :func:`now_us`, the spans' clock."""
     with _compile_lock:
         return [dict(e) for e in _compile_log]
 
@@ -1268,25 +1416,37 @@ def _acquire(lock, timeout):
 
 
 def retrace_report(lock_timeout=None):
-    """Per-callable compile accounting for exporters / trace_report.
+    """Per-name compile accounting for exporters / trace_report: the
+    ledger's sums by watch name, or by JAX's ``fun_name`` for a compile
+    outside every watched jit.
 
     *lock_timeout*: crash-dump callers pass a bound; on timeout the
     report is built from an unlocked best-effort copy (the holder is the
     very thread a signal interrupted — it will never release)."""
     locked = _acquire(_compile_lock, lock_timeout)
     try:
-        items = list(_compiles.items())
+        items = [(name, dict(rec)) for name, rec in _compiles.items()]
         warned = set(_storm_warned)
     except RuntimeError:          # unlocked copy raced a resize
         return {}
     finally:
         if locked:
             _compile_lock.release()
-    return {name: {"count": rec["count"],
-                   "total_ms": rec["total_us"] / 1e3,
-                   "cache_size": rec["last_size"],
-                   "storm": name in warned}
-            for name, rec in items}
+    report = {}
+    for name, rec in items:
+        rec["total_ms"] = _total_ms(rec)
+        rec["storm"] = name in warned
+        report[name] = rec
+    return report
+
+
+def _compile_totals(report):
+    """The ledger summed over names: what a start paid, and to whom."""
+    total = _new_sums()
+    for rec in report.values():
+        for key in total:
+            total[key] += rec[key]
+    return total
 
 
 # --------------------------------------------------------------------------
@@ -1446,12 +1606,14 @@ def snapshot(lock_timeout=None):
             _mlock.release()
     costs_ = {"programs": program_costs(),
               "peaks": _costs().peaks_if_resolved()}
+    retraces = retrace_report(lock_timeout)
     snap = {"enabled": _ENABLED,
             "retrace_limit": _RETRACE_LIMIT,
             "counters": counters_,
             "gauges": gauges_,
             "histograms": hists_,
-            "retraces": retrace_report(lock_timeout),
+            "retraces": retraces,
+            "compiles": _compile_totals(retraces),
             "costs": costs_}
     if _DEVICE_TIME:
         try:
@@ -1491,3 +1653,6 @@ def reset():
     if ts is not None:
         ts.reset()
     _flight.reset()
+
+
+_bind_compile_listeners()

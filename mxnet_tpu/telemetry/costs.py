@@ -141,7 +141,12 @@ def _spec(leaf):
     if shape is None or dtype is None:
         return leaf              # python scalar etc: trace as-is
     import jax
-    return jax.ShapeDtypeStruct(shape, dtype)
+    # a committed array's placement is part of the jit's cache key: with
+    # it the re-lower below finds the lowering (and the executable) the
+    # call itself just built, and nothing compiles a second time
+    sharding = leaf.sharding if getattr(leaf, "committed", False) else None
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding,
+                                weak_type=getattr(leaf, "weak_type", False))
 
 
 def _normalize(analysis):
@@ -161,8 +166,9 @@ def _normalize(analysis):
 def capture(fn, args, kwargs, force=False):
     """(flops, bytes_accessed) of *fn* compiled for *args*/*kwargs*, or
     None.  Called by the watchdog ON COMPILE EVENTS ONLY — the re-lower
-    here re-traces the function once, which is noise next to the XLA
-    compile that just happened, and buys shape-safe AOT introspection.
+    here finds the trace, the lowering and the executable the call just
+    made (the specs carry the arguments' placement, the rest of jit's
+    cache key), and buys shape-safe AOT introspection.
     *force* bypasses the ``MXNET_COST_ANALYSIS`` gate for explicit API
     calls (``Executor.cost_analysis``).
     """

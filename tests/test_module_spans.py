@@ -31,6 +31,18 @@ PARENT = dict({c: "module_train_step" for c in STEP_CHILDREN},
               fit_callback="fit_batch", metric_wait="fit_update_metric",
               metric_fetch="fit_update_metric")
 BATCHES = 4
+# what a start leaves whatever the switches say (ISSUE 35;
+# tests/test_setup_trace.py holds their nesting): the set-up spans, and
+# while something records the ring's copy of each compile ledger row
+START_CATS = ("setup", "compile")
+SETUP_SPANS = ["module_bind", "module_init_params", "init_params_host",
+               "init_params_place", "module_init_optimizer",
+               "module_step_build", "module_first_step"]
+
+
+def of_the_start(annotation):
+    name = annotation[len("mxnet_tpu."):]
+    return name in SETUP_SPANS or name.startswith("compile:")
 
 
 @pytest.fixture(autouse=True)
@@ -79,9 +91,10 @@ class Session:
     def __exit__(self, *exc):
         jax.profiler.stop_trace()
 
-    def host_events(self):
+    def host_events(self, start=False):
         """[(line name, event name, start_ns, end_ns, stats)] of every
-        ``mxnet_tpu.*`` annotation in the session's ``.xplane.pb``."""
+        per-batch ``mxnet_tpu.*`` annotation in the session's
+        ``.xplane.pb``; with *start* the start's own instead."""
         from jax.profiler import ProfileData
         found = glob.glob(os.path.join(self.dir, "plugins", "profile", "*",
                                        "*.xplane.pb"))
@@ -90,16 +103,19 @@ class Session:
         for plane in ProfileData.from_file(found[0]).planes:
             for line in plane.lines:
                 for ev in line.events:
-                    if ev.name.startswith("mxnet_tpu."):
+                    if ev.name.startswith("mxnet_tpu.") \
+                            and of_the_start(ev.name) == start:
                         out.append((line.name, ev.name[len("mxnet_tpu."):],
                                     ev.start_ns, ev.start_ns + ev.duration_ns,
                                     dict(ev.stats)))
         return out
 
 
-def ring():
+def ring(start=False):
+    """The ring's per-batch spans; with *start* the start's own events
+    (categories ``setup`` and ``compile``) instead."""
     return [e for e in telemetry.chrome_trace_payload()["traceEvents"]
-            if e["ph"] == "X"]
+            if e["ph"] == "X" and (e["cat"] in START_CATS) == start]
 
 
 def inside(child, parents):
@@ -166,7 +182,14 @@ def test_fit_under_a_profiler_session_records_every_span(tmp_path):
             for iv in spans[child]:
                 assert inside(iv, spans[parent]), (child, parent)
     for e in events:
-        assert e["args"]["parent"] == PARENT.get(e["name"]), e
+        first = e["name"] == "module_train_step" \
+            and e["args"]["trace_id"] == ids[0]
+        # the fit's first step runs under the set-up span that times it
+        assert e["args"]["parent"] == ("module_first_step" if first
+                                       else PARENT.get(e["name"])), e
+    start = {e["name"]: e["args"]["parent"] for e in ring(start=True)
+             if e["cat"] == "setup"}
+    assert start["module_first_step"] == "fit_batch"
 
     # the same spans in the session's own trace, prefixed, nested in time
     # on one host line, each carrying its batch's id
@@ -196,7 +219,13 @@ def test_fit_without_a_session_records_and_constructs_nothing(monkeypatch):
 
     monkeypatch.setattr(core, "_annotation_cls", Counting)
     toy_fit()
-    assert ring() == [] and built == []
+    # only the start's own spans were recorded and annotated: one of each,
+    # once a bind, and nothing for any of the batches
+    assert ring() == []
+    assert sorted(e["name"] for e in ring(start=True)) == sorted(SETUP_SPANS)
+    assert sorted(args[0] for args in built) \
+        == sorted("mxnet_tpu." + name for name in SETUP_SPANS)
+    del built[:]
     assert not core._SESSION and not telemetry.trace_active()
     # the counting stub does count when something records
     telemetry.set_enabled(True)
@@ -216,8 +245,12 @@ def test_a_session_alone_turns_on_nothing_but_spans(tmp_path, monkeypatch):
     with Session(tmp_path):
         toy_fit()               # a fresh Module: its step program compiles
     assert len(ring()) > 0
-    assert telemetry.compile_events() == []
-    assert telemetry.counter("jit_compiles") == 0
+    # the compile ledger is on whatever the switches say: the step
+    # program's row is there, under its watch and its set-up span
+    assert [(r["watch"], r["span"]) for r in telemetry.compile_events()
+            if r["watch"]] == [("module_cached_step", "module_step_enqueue")]
+    assert telemetry.counter("jit_compiles") == 1
+    assert telemetry.histogram("jit_compile_us").count == 0
     assert timeseries.names() == []
     assert telemetry.histogram("step_time_us").count == 0
     assert telemetry.gauge("host_rss_peak_bytes", None) is None

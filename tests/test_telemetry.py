@@ -169,17 +169,28 @@ def test_stable_shapes_do_not_storm(tel, caplog):
     assert tel.retrace_report()["optimizer_update_step"]["count"] == 1
 
 
-def test_watch_jit_off_path_is_passthrough():
-    """Telemetry off: the watchdog neither times nor records, and cache
-    introspection still proxies to the jitted callable."""
+def test_watch_jit_off_path_is_passthrough(monkeypatch):
+    """Telemetry off: the wrapper neither times, captures nor checks, and
+    cache introspection still proxies to the jitted callable.  The
+    compile itself is in the ledger all the same, under the watch's name:
+    JAX's event put it there, not the wrapper."""
     import jax
+    from mxnet_tpu.telemetry import core
+
+    def acted(*args, **kwargs):
+        raise AssertionError("the wrapper acted on a call, telemetry off")
+
     telemetry.reset()
     telemetry.set_enabled(False)
+    monkeypatch.setattr(core, "_capture_cost", acted)
+    monkeypatch.setattr(core, "_run_tracecheck", acted)
     fn = telemetry.watch_jit(jax.jit(lambda x: x + 1), "passthrough_test")
     np.testing.assert_allclose(np.asarray(fn(np.ones(3))), 2 * np.ones(3))
     assert fn._cache_size() == 1                  # proxied attribute
-    assert "passthrough_test" not in telemetry.retrace_report()
-    assert telemetry.counter("jit_compiles") == 0
+    assert telemetry.retrace_report()["passthrough_test"]["count"] == 1
+    assert telemetry.counter("jit_compiles") == 1
+    assert telemetry.program_costs() == {}
+    assert telemetry.histogram("jit_compile_us").count == 0
 
 
 # ---- metrics registry ----------------------------------------------------
