@@ -12,9 +12,10 @@ equations and what is assumed beyond the published ``config.json``):
 
 and after the last layer RMSNorm, then the blocked head: the graph's
 output is the mean next-token negative log-likelihood, shape (1,).  Every
-layer is one recomputation segment (``force_mirroring``) that keeps nothing
-of its own: the retention op's chunk states are several times its output
-(``ops/pallas_kernels.py:power_retention``), so its forward runs again.
+layer is one recomputation segment (``force_mirroring``) that keeps the
+retention op's output and nothing else: the op's backward remakes the
+chunk states it reads (``ops/pallas_kernels.py:power_retention``), so the
+segment's replay runs the projections again and no retention forward.
 """
 from __future__ import annotations
 

@@ -40,6 +40,9 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .. import telemetry as _tel
+from . import remat as _remat
+
 __all__ = ["flash_attention", "power_retention"]
 
 _NEG = np.float32(-1e30)
@@ -514,15 +517,21 @@ flash_attention.defvjp(_fwd, _bwd)
 # (twice the d(d+1)/2 distinct entries): S as [i, v, j], z as the matrix
 # Z[i, j] = sum_s decay k_si k_sj, which turns phi(q).z into rowsum((q Z) q).
 
+def _f32_acc(product, cd):
+    """*product* (``jnp.einsum``, ``lax.dot_general``) over operands of
+    dtype *cd* with float32 accumulation."""
+    prec = jax.lax.Precision.HIGHEST if cd == jnp.float32 else None
+    return functools.partial(product, precision=prec,
+                             preferred_element_type=jnp.float32)
+
+
 def _retention_chunk(S, Z, q, k, v, a, *, scale, eps):
     """One chunk of one key/value head in ``jnp``: state at the chunk's
     start -> (state at its end, outputs).  S [d, dv, d] and Z [d, d] are
     float32; q [G, C, d], k [C, d], v [C, dv] keep their dtype as matmul
     operands with float32 accumulation; a [C] is the float32 log-gate."""
     f32, cd = jnp.float32, q.dtype
-    prec = jax.lax.Precision.HIGHEST if cd == jnp.float32 else None
-    ein = functools.partial(jnp.einsum, precision=prec,
-                            preferred_element_type=f32)
+    ein = _f32_acc(jnp.einsum, cd)
     c = jnp.cumsum(a.astype(f32))
     t = jnp.arange(c.shape[0])
     diff = jnp.where(t[:, None] >= t[None, :], c[:, None] - c[None, :],
@@ -538,14 +547,21 @@ def _retention_chunk(S, Z, q, k, v, a, *, scale, eps):
     den = den + jnp.sum(ein("gti,ij->gtj", q, Z.astype(cd)) * q.astype(f32),
                         axis=-1) * reach
     o = (num / (den + eps)[..., None]).astype(cd)
-    # advance the state once a chunk
+    return _retention_advance(S, Z, k, v, c) + (o,)
+
+
+def _retention_advance(S, Z, k, v, c):
+    """The state once a chunk: at the chunk's start -> at its end, from
+    the keys, the values and the running log-gate c [C] alone."""
+    f32, cd = jnp.float32, k.dtype
+    ein = _f32_acc(jnp.einsum, cd)
     to_end = jnp.exp(c[-1] - c)
     pk = k[:, :, None] * k[:, None, :]
     vd = (v.astype(f32) * to_end[:, None]).astype(cd)
     kd = (k.astype(f32) * to_end[:, None]).astype(cd)
     S_new = jnp.exp(c[-1]) * S + ein("sij,sv->ivj", pk, vd)
     Z_new = jnp.exp(c[-1]) * Z + ein("si,sj->ij", kd, k)
-    return S_new, Z_new, o
+    return S_new, Z_new
 
 
 def _retention_heads(q, k, v, a, chunk):
@@ -578,85 +594,124 @@ def _retention_unlay(o, b, s):
     return o.reshape(b, n * c, (bh // b) * g, dv)[:, :s]
 
 
+def _retention_zero_state(kh, vh):
+    bh, d, dv = kh.shape[0], kh.shape[-1], vh.shape[-1]
+    return (jnp.zeros((bh, d, dv, d), jnp.float32),
+            jnp.zeros((bh, d, d), jnp.float32))
+
+
 def _retention_scan(qh, kh, vh, ah, scale, eps):
     """The chunked state form in ``jnp``: scan over the chunks of every
-    head at once.  Returns outputs and the state at each chunk's start
-    (in the operands' dtype: the backward's matmul operands)."""
-    bh, n, g, c, d = qh.shape
-    dv = vh.shape[-1]
+    head at once.  Returns the outputs."""
     step = jax.vmap(functools.partial(_retention_chunk, scale=scale,
                                       eps=eps))
 
     def body(carry, xs):
-        S, Z = carry
-        S1, Z1, o = step(S, Z, *xs)
-        return (S1, Z1), (o, S.astype(qh.dtype), Z.astype(qh.dtype))
+        S1, Z1, o = step(*carry, *xs)
+        return (S1, Z1), o
 
-    init = (jnp.zeros((bh, d, dv, d), jnp.float32),
-            jnp.zeros((bh, d, d), jnp.float32))
     xs = tuple(jnp.moveaxis(x, 1, 0) for x in (qh, kh, vh, ah))
-    _, (o, S0, Z0) = jax.lax.scan(body, init, xs)
-    return tuple(jnp.moveaxis(x, 0, 1) for x in (o, S0, Z0))
+    o = jax.lax.scan(body, _retention_zero_state(kh, vh), xs)[1]
+    return jnp.moveaxis(o, 0, 1)
 
 
-def _retention_kernel(qT_ref, k_ref, kT_ref, vT_ref, crow_ref, ccol_ref,
-                      oT_ref, s0_ref, z0_ref,
-                      st_ref, z_ref, qf_ref, kf_ref, acc_ref, *,
-                      scale, eps, groups):
-    """One (head, chunk) program, feature-major: tokens lie on the lanes,
-    so a slab of phi is a sublane-broadcast multiply and the loop over
-    the d slabs indexes rows.  The state (st/z scratch) carries across
-    the chunk axis, the innermost, sequential one."""
-    f32, cd = jnp.float32, qT_ref.dtype
-    precision = jax.lax.Precision.HIGHEST if cd == jnp.float32 else None
-    dot = functools.partial(jax.lax.dot_general, precision=precision,
-                            preferred_element_type=f32)
-    nn = (((1,), (0,)), ((), ()))
-    nt = (((1,), (1,)), ((), ()))
-    d, c = kT_ref.shape
+def _retention_states_scan(kh, vh, ah):
+    """The state at each chunk's start in ``jnp``, S0 [bh, n, d, dv, d]
+    and Z0 [bh, n, d, d] in the operands' dtype (the backward's matmul
+    operands): ``_retention_scan``'s advance and nothing else."""
+    step = jax.vmap(_retention_advance)
 
+    def body(carry, xs):
+        k, v, a = xs
+        c = jnp.cumsum(a.astype(jnp.float32), axis=-1)
+        return step(*carry, k, v, c), tuple(x.astype(kh.dtype)
+                                            for x in carry)
+
+    xs = tuple(jnp.moveaxis(x, 1, 0) for x in (kh, vh, ah))
+    states = jax.lax.scan(body, _retention_zero_state(kh, vh), xs)[1]
+    return tuple(jnp.moveaxis(x, 0, 1) for x in states)
+
+
+_NN = (((1,), (0,)), ((), ()))
+_NT = (((1,), (1,)), ((), ()))
+
+
+# What the forward kernel and the states kernel share: the float32 state
+# (st [i, v, j] and z scratch) carried across the chunk axis, the
+# innermost, sequential one, and advanced by the same operations in the
+# same order, so that both hold the same bits at every chunk's start.
+
+def _state_enter(st_ref, z_ref, kf_ref, kT_ref, crow_ref):
+    """Zero state at a head's first chunk, the keys in float32; -> the
+    running log-gate [1, C], the decay from each token to the chunk's
+    end [1, C] and over the whole chunk [1, 1]."""
     @pl.when(pl.program_id(1) == 0)
     def _init():
         st_ref[:] = jnp.zeros_like(st_ref)
         z_ref[:] = jnp.zeros_like(z_ref)
 
-    crow = crow_ref[:]                      # [1, C] running log-gate
+    c = kT_ref.shape[1]
+    crow = crow_ref[:]
+    c_end = crow[:, c - 1:c]
+    kf_ref[:] = kT_ref[:].astype(jnp.float32)
+    return crow, jnp.exp(c_end - crow), jnp.exp(c_end)
+
+
+def _state_advance_z(z_ref, kf_ref, kT_ref, vT_ref, to_end, grow):
+    """z to the chunk's end; -> the values decayed to it, [dv, s]."""
+    f32, cd = jnp.float32, kT_ref.dtype
+    vd = (vT_ref[:].astype(f32) * to_end).astype(cd)
+    kd = (kf_ref[:] * to_end).astype(cd)
+    z_ref[:] = z_ref[:] * grow + _f32_acc(jax.lax.dot_general, cd)(
+        kd, kT_ref[:], _NT)
+    return vd
+
+
+def _state_advance_slab(st_ref, kf_ref, i, s_i, vd, grow):
+    """Slab i of the state, read as s_i [dv, j], to the chunk's end."""
+    pk = (kf_ref[:] * kf_ref[pl.ds(i, 1), :]).astype(vd.dtype)  # [j, s]
+    st_ref[i] = s_i * grow + _f32_acc(jax.lax.dot_general, vd.dtype)(
+        vd, pk, _NT)
+
+
+def _retention_kernel(qT_ref, k_ref, kT_ref, vT_ref, crow_ref, ccol_ref,
+                      oT_ref, st_ref, z_ref, qf_ref, kf_ref, acc_ref, *,
+                      scale, eps, groups):
+    """One (head, chunk) program, feature-major: tokens lie on the lanes,
+    so a slab of phi is a sublane-broadcast multiply and the loop over
+    the d slabs indexes rows."""
+    f32, cd = jnp.float32, qT_ref.dtype
+    dot = _f32_acc(jax.lax.dot_general, cd)
+    d, c = kT_ref.shape
+    crow, to_end, grow = _state_enter(st_ref, z_ref, kf_ref, kT_ref,
+                                      crow_ref)
     ccol = ccol_ref[:]                      # [C, 1]
-    c_end = crow[:, c - 1:c]                # [1, 1]
     reach = np.float32(scale * scale) * jnp.exp(crow)
-    to_end = jnp.exp(c_end - crow)
-    grow = jnp.exp(c_end)
     s_pos = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
     t_pos = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
     decay = jnp.exp(jnp.where(t_pos >= s_pos, crow - ccol, _NEG))   # [s, t]
-    z0_ref[:] = z_ref[:].astype(z0_ref.dtype)
     z_cd = z_ref[:].astype(cd)
     qf_ref[:] = qT_ref[:].astype(f32)
-    kf_ref[:] = kT_ref[:].astype(f32)
 
     # within the chunk: the quadratic form over its own tokens
     dens = []
     for g in range(groups):
-        s = dot(k_ref[:], qT_ref[g], nn) * np.float32(scale)        # [s, t]
+        s = dot(k_ref[:], qT_ref[g], _NN) * np.float32(scale)       # [s, t]
         w = s * s * decay
-        acc_ref[g] = dot(vT_ref[:], w.astype(cd), nn)               # [dv, t]
-        zq = dot(z_cd, qT_ref[g], nn)                               # [i, t]
+        acc_ref[g] = dot(vT_ref[:], w.astype(cd), _NN)              # [dv, t]
+        zq = dot(z_cd, qT_ref[g], _NN)                              # [i, t]
         dens.append(jnp.sum(w, axis=0, keepdims=True) + reach *
                     jnp.sum(zq * qf_ref[g], axis=0, keepdims=True))
-    vd = (vT_ref[:].astype(f32) * to_end).astype(cd)                # [dv, s]
-    kd = (kf_ref[:] * to_end).astype(cd)
-    z_ref[:] = z_ref[:] * grow + dot(kd, kT_ref[:], nt)
+    vd = _state_advance_z(z_ref, kf_ref, kT_ref, vT_ref, to_end, grow)
 
     # the state: query slab i for every head, then advance slab i
     def slab(i, carry):
         s_i = st_ref[i]                                             # [dv, j]
-        s0_ref[i] = s_i.astype(s0_ref.dtype)
         s_cd = s_i.astype(cd)
         for g in range(groups):
             pq = (qf_ref[g] * qf_ref[g, pl.ds(i, 1), :]).astype(cd)  # [j, t]
-            acc_ref[g] += dot(s_cd, pq, nn) * reach
-        pk = (kf_ref[:] * kf_ref[pl.ds(i, 1), :]).astype(cd)        # [j, s]
-        st_ref[i] = s_i * grow + dot(vd, pk, nt)
+            acc_ref[g] += dot(s_cd, pq, _NN) * reach
+        _state_advance_slab(st_ref, kf_ref, i, s_i, vd, grow)
         return carry
 
     jax.lax.fori_loop(np.int32(0), np.int32(d), slab, np.int32(0))
@@ -665,8 +720,39 @@ def _retention_kernel(qT_ref, k_ref, kT_ref, vT_ref, crow_ref, ccol_ref,
             .astype(oT_ref.dtype)
 
 
+def _retention_states_kernel(kT_ref, vT_ref, crow_ref, s0_ref, z0_ref,
+                             st_ref, z_ref, kf_ref):
+    """One (head, chunk) program of the states pass: write the state the
+    chunk starts from, then advance it as the forward kernel does.  No
+    query is read."""
+    _, to_end, grow = _state_enter(st_ref, z_ref, kf_ref, kT_ref, crow_ref)
+    z0_ref[:] = z_ref[:].astype(z0_ref.dtype)
+    vd = _state_advance_z(z_ref, kf_ref, kT_ref, vT_ref, to_end, grow)
+
+    def slab(i, carry):
+        s_i = st_ref[i]
+        s0_ref[i] = s_i.astype(s0_ref.dtype)
+        _state_advance_slab(st_ref, kf_ref, i, s_i, vd, grow)
+        return carry
+
+    jax.lax.fori_loop(np.int32(0), np.int32(kT_ref.shape[0]), slab,
+                      np.int32(0))
+
+
+def _chunk_spec(*block):
+    """A (head, chunk) program's block of an operand [bh, n, *block]."""
+    zeros = (np.int32(0),) * len(block)
+    return pl.BlockSpec((None, None) + block, lambda h, j: (h, j) + zeros)
+
+
+_RETENTION_PARAMS = dict(
+    compiler_params=pltpu.CompilerParams(
+        dimension_semantics=("parallel", "arbitrary"),
+        vmem_limit_bytes=100 * 1024 * 1024))
+
+
 def _retention_pallas(qh, kh, vh, ah, scale, eps, interpret):
-    """The forward in Pallas.  Same operands and results as
+    """The forward in Pallas.  Same operands and result as
     ``_retention_scan``; grid (head, chunk), the chunk axis sequential."""
     bh, n, g, c, d = qh.shape
     dv = vh.shape[-1]
@@ -675,33 +761,16 @@ def _retention_pallas(qh, kh, vh, ah, scale, eps, interpret):
                          "%d/%d must be multiples of %d"
                          % (c, d, dv, _LANES))
     cs = jnp.cumsum(ah.astype(jnp.float32), axis=-1)        # [bh, n, C]
-    zero = np.int32(0)
     kernel = functools.partial(_retention_kernel, scale=scale, eps=eps,
                                groups=g)
-    oT, S0, Z0 = pl.pallas_call(
+    oT = pl.pallas_call(
         kernel,
         grid=(bh, n),
-        in_specs=[
-            pl.BlockSpec((None, None, g, d, c),
-                         lambda h, j: (h, j, zero, zero, zero)),
-            pl.BlockSpec((None, None, c, d), lambda h, j: (h, j, zero, zero)),
-            pl.BlockSpec((None, None, d, c), lambda h, j: (h, j, zero, zero)),
-            pl.BlockSpec((None, None, dv, c), lambda h, j: (h, j, zero, zero)),
-            pl.BlockSpec((None, None, 1, c), lambda h, j: (h, j, zero, zero)),
-            pl.BlockSpec((None, None, c, 1), lambda h, j: (h, j, zero, zero)),
-        ],
-        out_specs=[
-            pl.BlockSpec((None, None, g, dv, c),
-                         lambda h, j: (h, j, zero, zero, zero)),
-            pl.BlockSpec((None, None, d, dv, d),
-                         lambda h, j: (h, j, zero, zero, zero)),
-            pl.BlockSpec((None, None, d, d), lambda h, j: (h, j, zero, zero)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, n, g, dv, c), qh.dtype),
-            jax.ShapeDtypeStruct((bh, n, d, dv, d), qh.dtype),
-            jax.ShapeDtypeStruct((bh, n, d, d), qh.dtype),
-        ],
+        in_specs=[_chunk_spec(g, d, c), _chunk_spec(c, d),
+                  _chunk_spec(d, c), _chunk_spec(dv, c), _chunk_spec(1, c),
+                  _chunk_spec(c, 1)],
+        out_specs=_chunk_spec(g, dv, c),
+        out_shape=jax.ShapeDtypeStruct((bh, n, g, dv, c), qh.dtype),
         scratch_shapes=[
             pltpu.VMEM((d, dv, d), jnp.float32),
             pltpu.VMEM((d, d), jnp.float32),
@@ -709,38 +778,72 @@ def _retention_pallas(qh, kh, vh, ah, scale, eps, interpret):
             pltpu.VMEM((d, c), jnp.float32),
             pltpu.VMEM((g, dv, c), jnp.float32),
         ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
-            vmem_limit_bytes=100 * 1024 * 1024),
         name="power_retention_fwd",
         interpret=interpret,
+        **_RETENTION_PARAMS,
     )(jnp.swapaxes(qh, -1, -2), kh, jnp.swapaxes(kh, -1, -2),
       jnp.swapaxes(vh, -1, -2), cs[:, :, None, :], cs[..., None])
-    return jnp.swapaxes(oT, -1, -2), S0, Z0
+    return jnp.swapaxes(oT, -1, -2)
+
+
+def _retention_states_pallas(kh, vh, ah, interpret):
+    """The states pass in Pallas.  Same operands and results as
+    ``_retention_states_scan``, the forward kernel's grid."""
+    bh, n, c, d = kh.shape
+    dv = vh.shape[-1]
+    cs = jnp.cumsum(ah.astype(jnp.float32), axis=-1)
+    return pl.pallas_call(
+        _retention_states_kernel,
+        grid=(bh, n),
+        in_specs=[_chunk_spec(d, c), _chunk_spec(dv, c), _chunk_spec(1, c)],
+        out_specs=[_chunk_spec(d, dv, d), _chunk_spec(d, d)],
+        out_shape=[jax.ShapeDtypeStruct((bh, n, d, dv, d), kh.dtype),
+                   jax.ShapeDtypeStruct((bh, n, d, d), kh.dtype)],
+        scratch_shapes=[
+            pltpu.VMEM((d, dv, d), jnp.float32),
+            pltpu.VMEM((d, d), jnp.float32),
+            pltpu.VMEM((d, c), jnp.float32),
+        ],
+        name="power_retention_bwd_states",
+        interpret=interpret,
+        **_RETENTION_PARAMS,
+    )(jnp.swapaxes(kh, -1, -2), jnp.swapaxes(vh, -1, -2), cs[:, :, None, :])
 
 
 def _retention_grads(qh, kh, vh, ah, S0, Z0, do, scale, eps):
     """Backward of the chunked state form: the chunks in reverse, the
     state's cotangent carried from each chunk's end to its start, one
     chunk's own gradients by ``jax.vjp`` of ``_retention_chunk`` from the
-    saved chunk-start state.  One head at a time: a chunk's phi(q) alone
-    is G*C*d*d elements."""
-    f32 = jnp.float32
+    chunk-start state.  One head at a time: a chunk's phi(q) alone
+    is G*C*d*d elements.
+
+    A chunk reads its state from the whole S0 and Z0 by (head, chunk)
+    index; the loops carry no slice of them.  One head's states fit a
+    v5e's VMEM (67 MB at Brumby's widths), and XLA:TPU, given them as a
+    loop operand, moved them out to HBM and back in every chunk."""
+    f32, i32 = jnp.float32, jnp.int32
     chunk_fn = functools.partial(_retention_chunk, scale=scale, eps=eps)
+    bh, n = qh.shape[:2]
 
     def head(xs):
+        h = xs[0]
+
         def body(carry, ys):
-            S_c, Z_c, q_c, k_c, v_c, a_c, do_c = ys
+            j, q_c, k_c, v_c, a_c, do_c = ys
+            S_c, Z_c = (jax.lax.dynamic_slice(
+                x, (h, j) + (np.int32(0),) * (x.ndim - 2),
+                (1, 1) + x.shape[2:])[0, 0]
+                for x in (S0, Z0))
             _, vjp = jax.vjp(chunk_fn, S_c.astype(f32), Z_c.astype(f32),
                              q_c, k_c, v_c, a_c)
             dS, dZ, dq, dk, dv, da = vjp(carry + (do_c,))
             return (dS, dZ), (dq, dk, dv, da)
 
-        init = (jnp.zeros(xs[0].shape[1:], f32),
-                jnp.zeros(xs[1].shape[1:], f32))
-        return jax.lax.scan(body, init, xs, reverse=True)[1]
+        init = (jnp.zeros(S0.shape[2:], f32), jnp.zeros(Z0.shape[2:], f32))
+        return jax.lax.scan(body, init, (jnp.arange(n, dtype=i32),) + xs[1:],
+                            reverse=True)[1]
 
-    return jax.lax.map(head, (S0, Z0, qh, kh, vh, ah, do))
+    return jax.lax.map(head, (jnp.arange(bh, dtype=i32), qh, kh, vh, ah, do))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
@@ -751,41 +854,52 @@ def power_retention(q, k, v, log_gate, chunk=128, eps=1e-6, use_kernel=False,
     q [B, S, Hq, d], k [B, S, Hkv, d], v [B, S, Hkv, dv], log_gate
     [B, S, Hkv] (float32, <= 0) -> [B, S, Hq, dv]; query head i reads
     key/value head i // (Hq // Hkv); q.k is scaled by 1/sqrt(d).
-    ``use_kernel`` runs the forward as the Pallas kernel (TPU, or
-    ``interpret=True``), else as the same algorithm in ``jnp``; the
-    backward scans the chunks in reverse in ``jnp`` either way.
+    ``use_kernel`` runs the forward and the backward's states pass as
+    Pallas kernels (TPU, or ``interpret=True``), else as the same
+    algorithm in ``jnp``; the backward's gradients scan the chunks in
+    reverse in ``jnp`` either way.
 
-    A recomputation segment keeps nothing of this op (``ops/remat.py``)
-    and runs its forward again, by the residuals' bytes: at 16,384 tokens,
-    8 key/value heads of 128 and chunks of 1,024 the chunk states are
-    ``S0`` [8, 16, 128, 128, 128] bf16 = 537 MB, ``Z0`` 4 MB and ``o``
-    168 MB a layer, several times the op's output.
+    The forward saves its operands alone.  The backward first remakes the
+    state at each chunk's start from k, v and the gate — the state's
+    advance, 15% of the forward's products at 5 query heads a key/value
+    head, bit for bit what the forward held — so nothing of the states
+    (at 16,384 tokens, 8 key/value heads of 128 and chunks of 1,024:
+    ``S0`` [8, 16, 128, 128, 128] bf16 = 537 MB, ``Z0`` 4 MB a layer)
+    lives between the passes.  A recomputation segment keeps the op's
+    output (``ops/remat.py``; 168 MB a layer there), so its replay holds
+    no retention forward.
     """
-    return _retention_fwd(q, k, v, log_gate, chunk, eps, use_kernel,
-                          interpret)[0]
-
-
-def _retention_fwd(q, k, v, log_gate, chunk, eps, use_kernel, interpret):
     b, s = q.shape[:2]
     scale = 1.0 / math.sqrt(q.shape[-1])
     with jax.named_scope("power_retention_fwd"):
         qh, kh, vh, ah = _retention_heads(q, k, v, log_gate, chunk)
         if use_kernel:
-            o, S0, Z0 = _retention_pallas(qh, kh, vh, ah, scale, eps,
-                                          interpret)
+            o = _retention_pallas(qh, kh, vh, ah, scale, eps, interpret)
         else:
-            o, S0, Z0 = _retention_scan(qh, kh, vh, ah, scale, eps)
-        o = _retention_unlay(o, b, s)
-    return o, (q, k, v, log_gate, S0, Z0)
+            o = _retention_scan(qh, kh, vh, ah, scale, eps)
+        return _retention_unlay(o, b, s)
+
+
+def _retention_fwd(q, k, v, log_gate, chunk, eps, use_kernel, interpret):
+    # the primal body itself (``.fun``), then the mark: a trace of the op
+    # that is not differentiated marks nothing and counts nothing
+    (o,) = _remat.keep(power_retention.fun(q, k, v, log_gate, chunk, eps,
+                                           use_kernel, interpret))
+    return o, (q, k, v, log_gate)
 
 
 def _retention_bwd(chunk, eps, use_kernel, interpret, res, do):
-    q, k, v, log_gate, S0, Z0 = res
+    q, k, v, log_gate = res
     b, s = q.shape[:2]
     scale = 1.0 / math.sqrt(q.shape[-1])
+    _tel.bump("power_retention_states_traced")
     with jax.named_scope("power_retention_bwd"):
         qh, kh, vh, ah = _retention_heads(q, k, v, log_gate, chunk)
         doh = _retention_heads(do.astype(q.dtype), k, v, log_gate, chunk)[0]
+        if use_kernel:
+            S0, Z0 = _retention_states_pallas(kh, vh, ah, interpret)
+        else:
+            S0, Z0 = _retention_states_scan(kh, vh, ah)
         dq, dk, dv, da = _retention_grads(qh, kh, vh, ah, S0, Z0, doh,
                                           scale, eps)
         dq = _retention_unlay(dq, b, s)

@@ -6,8 +6,11 @@ backward pass computes the segment's forward again, except the values an
 op handed to ``keep``.  An op keeps a residual that is no larger than its
 own output and that costs a kernel (or a sort) to remake: the flash
 forward's ``out`` and ``lse`` (``ops/lm.py``), the routing's integer
-results (``ops/moe.py``).  What is kept is the very value the replay would
-remake, so no arithmetic changes.
+results (``ops/moe.py``), the retention op's output
+(``ops/pallas_kernels.py``: its backward remakes the chunk states it reads
+from k, v and the gate, so the output is all the replay ran the forward
+for).  What is kept is the very value the replay would remake, so no
+arithmetic changes.
 """
 from __future__ import annotations
 

@@ -569,11 +569,16 @@ COUNTERS = {
                            "segment marked to be kept for the backward "
                            "instead of replayed (ops/remat.py:keep: two an "
                            "attention op on the kernel path, four a "
-                           "SparseMoE op), summed over traces",
+                           "SparseMoE op, one a PowerRetention op), summed "
+                           "over traces",
     "power_retention_traced": "_contrib_PowerRetention ops traced (the "
                               "chunked state form)",
     "power_retention_chunks": "chunks a sequence over all traced "
                               "_contrib_PowerRetention ops",
+    "power_retention_states_traced": "retention backward rules traced: each "
+                                     "remakes the chunk-start states from "
+                                     "k, v and the gate by the states-only "
+                                     "pass (the forward saves none)",
     "sparse_moe_traced": "_contrib_SparseMoE ops traced (dropless top-k "
                          "routing, grouped matrix product)",
     "sparse_moe_rows": "routed rows (tokens x experts a token) over all "

@@ -13,6 +13,7 @@ import mxnet_tpu as mx
 from mxnet_tpu import telemetry
 from mxnet_tpu.executor import _build_graph_fn
 from mxnet_tpu.ops import lm
+from mxnet_tpu.ops import pallas_kernels as pk
 from mxnet_tpu.ops.lm import blocked_softmax_ce, rms_norm
 from mxnet_tpu.ops.pallas_kernels import power_retention
 from mxnet_tpu.symbol.symbol import _topo
@@ -111,6 +112,74 @@ def test_pallas_forward_agrees_in_interpret_mode():
         jnp.abs(want).max())
     with pytest.raises(ValueError, match="multiples of 128"):
         power_retention(q, k, v, a, 64, EPS, True, True)
+
+
+def emitting_scan(qh, kh, vh, ah, scale, eps):
+    """The scan as it was while the forward saved the chunk states: a
+    chunk's outputs and the state it started from, side by side."""
+    bh, d, dv = kh.shape[0], kh.shape[-1], vh.shape[-1]
+    step = jax.vmap(functools.partial(pk._retention_chunk, scale=scale,
+                                      eps=eps))
+
+    def body(carry, xs):
+        S, Z = carry
+        S1, Z1, o = step(S, Z, *xs)
+        return (S1, Z1), (o, S.astype(qh.dtype), Z.astype(qh.dtype))
+
+    init = (jnp.zeros((bh, d, dv, d), jnp.float32),
+            jnp.zeros((bh, d, d), jnp.float32))
+    xs = tuple(jnp.moveaxis(x, 1, 0) for x in (qh, kh, vh, ah))
+    _, out = jax.lax.scan(body, init, xs)
+    return tuple(jnp.moveaxis(x, 0, 1) for x in out)
+
+
+@pytest.mark.parametrize("form,s,chunk,hq,hkv,d", [
+    ("jnp", 24, 8, 2, 2, 8),            # whole chunks, a query head a key
+    ("jnp", 21, 8, 4, 2, 8),            # a ragged tail, grouped heads
+    ("pallas", 300, 128, 4, 2, 128)])   # the same through the kernel
+def test_states_pass_remakes_the_saved_states_bit_for_bit(form, s, chunk, hq,
+                                                          hkv, d):
+    q, k, v, a = retention_inputs(5, s, 2.0, b=1, hq=hq, hkv=hkv, d=d)
+    qh, kh, vh, ah = pk._retention_heads(q, k, v, a, chunk)
+    scale = 1.0 / math.sqrt(d)
+    o, S0, Z0 = emitting_scan(qh, kh, vh, ah, scale, EPS)
+    if form == "pallas":
+        got = pk._retention_states_pallas(kh, vh, ah, True)
+        np.testing.assert_allclose(
+            pk._retention_pallas(qh, kh, vh, ah, scale, EPS, True), o,
+            rtol=1e-4, atol=1e-6)
+    else:
+        got = pk._retention_states_scan(kh, vh, ah)
+        np.testing.assert_array_equal(
+            pk._retention_scan(qh, kh, vh, ah, scale, EPS), o)
+    assert float(jnp.abs(S0[:, -1]).max()) > 0 and S0.shape[1] == -(-s // chunk)
+    np.testing.assert_array_equal(got[0], S0)
+    np.testing.assert_array_equal(got[1], Z0)
+
+
+def test_pallas_states_pass_carries_the_gradients_in_interpret_mode():
+    q, k, v, a = retention_inputs(13, 200, 2.0, b=1, hq=2, hkv=1, d=128)
+    mix = jnp.cos(jnp.arange(q.size, dtype=jnp.float32)).reshape(q.shape)
+    want = jax.grad(lambda *x: (retention_quadratic(*x) * mix).sum(),
+                    (0, 1, 2, 3))(q, k, v, a)
+    got = jax.grad(lambda *x: (power_retention(*x, 128, EPS, True, True)
+                               * mix).sum(), (0, 1, 2, 3))(q, k, v, a)
+    for g, w in zip(got, want):
+        assert float(jnp.abs(g - w).max()) <= 2e-4 * float(jnp.abs(w).max())
+
+
+def test_states_pass_is_counted_once_a_backward_trace():
+    q, k, v, a = retention_inputs(2, 20, 2.0)
+
+    def traces(fn):
+        before = telemetry.counter("power_retention_states_traced")
+        jax.make_jaxpr(fn)(q, k, v, a)
+        return telemetry.counter("power_retention_states_traced") - before
+
+    assert traces(lambda *x: power_retention(*x, 8)) == 0
+    assert traces(jax.grad(lambda *x: power_retention(*x, 8).sum(),
+                           (0, 1, 2, 3))) == 1
+    assert "power_retention_states_traced" in telemetry.core.COUNTERS
 
 
 def test_retention_op_counts_what_it_traces():

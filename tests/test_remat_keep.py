@@ -1,8 +1,8 @@
 """What a recomputation segment keeps (``ops/remat.py``), on the CPU at toy
-sizes: the flash forward's ``out`` and ``lse`` and the routing's integers
-are not remade in the backward pass, every number stays what the plain
-``jax.checkpoint`` gives, a segment that marks nothing is the parent's
-program, and outside a segment an op traces what it traced before."""
+sizes: the flash forward's ``out`` and ``lse``, the routing's integers and
+the retention op's output are not remade in the backward pass, every number
+stays what the plain ``jax.checkpoint`` gives, and outside a segment an op
+traces what it traced before."""
 import collections
 
 import jax
@@ -147,11 +147,11 @@ def test_a_segment_sorts_and_chooses_once(name):
 @pytest.mark.parametrize("name,kernel,kept", [
     ("trinity", True, 8 * 2 + 6 * 4), ("trinity", False, 6 * 4),
     ("lfm2", True, 2 * 2 + 3 * 4), ("lfm2", False, 3 * 4),
-    ("brumby", False, 0)])
+    ("brumby", False, BRUMBY_TINY["num_hidden_layers"])])
 def test_the_counter_reads_the_values_kept_a_trace(request, name, kernel,
                                                    kept):
     """Two an attention op on the kernel path, four a sparse-expert op,
-    none for a retention layer."""
+    one a retention op."""
     if kernel:
         request.getfixturevalue("kernel_path")
     (total, _), count = traced(toy(name))
@@ -160,28 +160,68 @@ def test_the_counter_reads_the_values_kept_a_trace(request, name, kernel,
         assert total["flash_attention_fwd"] == (kept - 4 * total["top_k"]) / 2
 
 
-def test_a_brumby_segment_is_the_parents_program():
-    """Nothing is marked, so the policy changes nothing: the retention
-    forward still runs twice a layer, and the jaxpr is the one a plain
-    ``jax.checkpoint`` gives."""
-    def text():
-        grad, spec = graph_gradient(toy("brumby"))
-        jaxpr = jax.make_jaxpr(grad)(*spec)
-        replayed = [eqn for eqn, inside in walk(jaxpr.jaxpr)
-                    if eqn.primitive.name == "scan" and "remat2" in inside
-                    and str(eqn.source_info.name_stack).endswith(
-                        "power_retention_fwd")]
-        return str(jaxpr), len(replayed)
+def retention_passes(jaxpr):
+    """(forwards replayed, states passes): scans and Pallas calls of the
+    retention forward inside the backward's ``remat2`` equations, and the
+    backward rules' own passes over the chunks that carry a state forward
+    (the gradients' scan runs in reverse under a ``lax.map``)."""
+    replayed = states = 0
+    for eqn, inside in walk(jaxpr):
+        if eqn.primitive.name not in ("scan", "pallas_call"):
+            continue
+        scope = str(eqn.source_info.name_stack)
+        if scope.endswith("power_retention_fwd") and "remat2" in inside:
+            replayed += 1
+        if scope.endswith("power_retention_bwd") and (
+                eqn.params.get("name") == "power_retention_bwd_states" or
+                eqn.params.get("num_carry") == 2):
+            states += 1
+    return replayed, states
 
-    before = telemetry.counter("executor_remat_kept")
-    ours, replayed = text()
-    assert telemetry.counter("executor_remat_kept") == before
-    assert replayed == BRUMBY_TINY["num_hidden_layers"]
+
+def test_a_brumby_replay_holds_no_retention_forward():
+    """The op's output is kept, so the segment's replay stops before the
+    retention forward; the backward rule makes the chunk states itself,
+    one pass a layer.  A plain ``jax.checkpoint`` replays the forward as
+    before, and makes the states the same way."""
+    layers = BRUMBY_TINY["num_hidden_layers"]
+    counters = ("executor_remat_kept", "power_retention_states_traced")
+
+    def passes():
+        grad, spec = graph_gradient(toy("brumby"))
+        before = [telemetry.counter(name) for name in counters]
+        jaxpr = jax.make_jaxpr(grad)(*spec).jaxpr
+        return retention_passes(jaxpr), [
+            telemetry.counter(name) - was
+            for name, was in zip(counters, before)]
+
+    assert passes() == ((0, layers), [layers, layers])
     with pytest.MonkeyPatch.context() as patch:
         patch.setattr(remat, "POLICY", None)
-        theirs, _ = text()
-    policy = "policy=%s" % remat.POLICY
-    assert policy in ours and ours.replace(policy, "policy=None") == theirs
+        assert passes() == ((layers, layers), [layers, layers])
+
+
+@pytest.mark.parametrize("name", ["lfm2", "trinity"])
+def test_a_graph_without_retention_is_the_parents_program(monkeypatch, name):
+    """Nothing of the retention op is reached: with its scans and kernels
+    made to raise, the gradient's jaxpr is letter for letter the same."""
+    def text():
+        grad, spec = graph_gradient(toy(name))
+        return str(jax.make_jaxpr(grad)(*spec))
+
+    before = telemetry.counter("power_retention_states_traced")
+    ours = text()
+    assert telemetry.counter("power_retention_states_traced") == before
+    assert "power_retention" not in ours
+
+    def unreachable(*args, **kwargs):
+        raise AssertionError("the retention op in a graph without one")
+
+    for fn in ("_retention_heads", "_retention_scan", "_retention_pallas",
+               "_retention_states_scan", "_retention_states_pallas",
+               "_retention_grads"):
+        monkeypatch.setattr(pk, fn, unreachable)
+    assert text() == ours
 
 
 def test_an_image_graph_keeps_nothing():
@@ -243,7 +283,8 @@ def compiled(name, recompute=True):
 
 
 @pytest.mark.parametrize("name,kernel", [
-    ("lfm2", True), ("trinity", True), ("lfm2", False), ("trinity", False)])
+    ("lfm2", True), ("trinity", True), ("lfm2", False), ("trinity", False),
+    ("brumby", False)])
 def test_kept_values_change_no_number(request, name, kernel):
     """Bit for bit the plain ``jax.checkpoint``'s loss and gradients op by
     op; compiled, the unsegmented graph's within the rounding of another

@@ -123,8 +123,9 @@ def main():
               flush=True)
 
     # power retention at the Brumby widths (5 query heads a key/value
-    # head of 128, chunks of 1024): the Pallas forward, and the chunked
-    # jnp backward through the states it saves
+    # head of 128, chunks of 1024): the Pallas forward, which writes the
+    # outputs alone, and through the gradient the states kernel that
+    # remakes the chunk states before the chunked jnp backward reads them
     for seq, grad in ((4096, False), (3000, True)):
         q = jax.ShapeDtypeStruct((1, seq, 10, 128), jnp.bfloat16,
                                  sharding=one)
@@ -138,9 +139,20 @@ def main():
                       (0, 1, 2, 3)) if grad else retain
         lowered = jax.jit(fn).lower(q, kv, kv, gate)
         assert "tpu_custom_call" in lowered.as_text()
-        lowered.compile()
-        print("AOT ok power_retention S=%d grad=%s" % (seq, grad),
-              flush=True)
+        compiled = lowered.compile()
+        kernels = [name for name in ("power_retention_fwd",
+                                     "power_retention_bwd_states")
+                   if "%" + name in compiled.as_text()]
+        # the gradient alone reads no output: its program holds the
+        # states kernel and no forward
+        assert kernels == ["power_retention_bwd_states" if grad
+                           else "power_retention_fwd"], kernels
+        mem = compiled.memory_analysis()
+        print("AOT ok power_retention S=%d grad=%s: %s, %.3f GB of "
+              "arguments, outputs and temporaries"
+              % (seq, grad, kernels[0],
+                 (mem.argument_size_in_bytes + mem.output_size_in_bytes +
+                  mem.temp_size_in_bytes) / 1e9), flush=True)
 
     # the kernel from mx.pallas's docstring, through the op registry
     # (same kernel and helper the interpret-mode tests use)
