@@ -18,7 +18,8 @@ from . import random as _random
 from .ops.registry import parse_attr_string
 
 __all__ = ["InitDesc", "Initializer", "register", "create", "Zero", "One",
-           "Constant", "Uniform", "Normal", "Orthogonal", "Xavier",
+           "Constant", "Uniform", "StateSpaceInit", "Normal", "Orthogonal",
+           "Xavier",
            "MSRAPrelu", "Bilinear", "LSTMBias", "Load", "Mixed", "init"]
 
 _REG = Registry("initializer")
@@ -164,6 +165,32 @@ class Uniform(Initializer):
     def _init_weight(self, _, arr):
         arr[:] = _random.host_rng().uniform(-self.scale, self.scale,
                                             arr.shape)
+
+
+@register
+class StateSpaceInit(Initializer):
+    """Mamba-2's published initial range of a state-space scan's
+    parameters (Dao and Gu, arXiv:2405.21060, and its reference module's
+    defaults): ``a_log`` is the log of A drawn uniformly in [low, high]
+    (1, 16 there), ``dt_bias`` is softplus^-1 of a step drawn
+    log-uniformly in [low, high] (0.001, 0.1 there), so that a token's
+    decay exp(-A dt) spans ~0.2 to ~0.999 over the heads."""
+
+    def __init__(self, param="a_log", low=1.0, high=16.0):
+        super().__init__(param=param, low=low, high=high)
+        if param not in ("a_log", "dt_bias"):
+            raise ValueError("StateSpaceInit: param %r (a_log or dt_bias)"
+                             % (param,))
+        self.param, self.low, self.high = param, low, high
+
+    def _init_weight(self, _, arr):
+        rng = _random.host_rng()
+        if self.param == "a_log":
+            arr[:] = np.log(rng.uniform(self.low, self.high, arr.shape))
+        else:
+            dt = np.exp(rng.uniform(np.log(self.low), np.log(self.high),
+                                    arr.shape))
+            arr[:] = np.log(np.expm1(dt))
 
 
 @register

@@ -1,7 +1,8 @@
-"""Graph pieces the sparse-expert language models share (``lfm2.py``,
-``trinity.py``): bias-free projections, per-head reshapes, the SwiGLU
-MLP, stacked expert tensors, the sparse-expert op with its variables, the
-one-segment-a-layer recomputation scope and the probe outputs."""
+"""Graph pieces the language models share (``lfm2.py``, ``trinity.py``
+and ``granite.py``): bias-free projections, per-head reshapes, the SwiGLU
+MLP with separate or one fused input matrix, stacked expert tensors, the
+sparse-expert op with its variables, the one-segment-a-layer
+recomputation scope and the probe outputs."""
 from __future__ import annotations
 
 from .. import attribute, initializer
@@ -22,6 +23,16 @@ def swiglu(h, width, hidden, pre):
     act = act * S.Activation(act, act_type="sigmoid") * \
         dense(h, width, pre + "w3")
     return dense(act, hidden, pre + "w2")
+
+
+def fused_swiglu(h, width, hidden, pre):
+    """SwiGLU stored as one input matrix: [g | u] = h W_input, halves of
+    *width* in that order; (silu(g) * u) W_output."""
+    both = dense(h, 2 * width, pre + "input")
+    gate = S.slice_axis(both, axis=-1, begin=0, end=width)
+    up = S.slice_axis(both, axis=-1, begin=width, end=None)
+    return dense(gate * S.Activation(gate, act_type="sigmoid") * up, hidden,
+                 pre + "output")
 
 
 def stacked(name, shape, dtype):
@@ -66,7 +77,7 @@ def layer_scope(i, recompute):
     """One layer, one recomputation segment: the backward pass computes
     the layer again but for what its ops keep (``ops/remat.py``: the
     attention kernel's output and row statistic, the routing's
-    indices)."""
+    indices, the state-space scan's output)."""
     return attribute.AttrScope(force_mirroring="True",
                                mirror_stage=str(i)) if recompute \
         else attribute.AttrScope()

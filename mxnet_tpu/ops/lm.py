@@ -1,6 +1,10 @@
-"""Language-model ops: RMSNorm, rotary embedding, power retention, causal
-grouped-query attention, a gated short convolution and a blocked
-softmax-cross-entropy head (the sparse-expert layer is ``ops/moe.py``).
+"""Language-model ops: ``RMSNorm``, ``_contrib_RotaryEmbedding``,
+``_contrib_PowerRetention``, ``_contrib_CausalAttention`` (grouped-query,
+with or without a window), ``_contrib_ShortConv`` (gated) and
+``_contrib_CausalConv1D`` (biased, with an activation) over one taps
+helper, ``_contrib_StateSpaceScan`` (Mamba-2's selective scan) and the
+blocked softmax-cross-entropy head ``_contrib_BlockedSoftmaxCE`` (the
+sparse-expert layer is ``ops/moe.py``).
 
 No reference counterpart: the reference's op corpus predates all of them.
 They are registered like every other op so that a language model is a
@@ -196,20 +200,27 @@ def _causal_attention(query, key, value, scale=None, causal=True,
                             jax.default_backend() == "tpu", window)
 
 
+def causal_taps(x, weight, bias=None):
+    """Causal depthwise convolution along the sequence in float32: x
+    [B, S, C], weight [C, K], bias [C] or None -> float32 [B, S, C],
+    c_t = sum_j weight[:, j] x_{t-K+1+j} (+ bias), zeros before the
+    sequence."""
+    taps, s = weight.shape[1], x.shape[1]
+    xp = jnp.pad(x.astype(jnp.float32), [(0, 0), (taps - 1, 0), (0, 0)])
+    w = weight.astype(jnp.float32)
+    c = sum(w[:, j] * xp[:, j:j + s] for j in range(taps))
+    return c if bias is None else c + bias.astype(jnp.float32)
+
+
 def short_conv(data, weight):
     """The gated causal short convolution of one layer: data [B, S, 3C]
     holds (Bg, Cg, u) in that order, weight [C, K] is depthwise and has no
     bias: c_t = sum_j weight[:, j] (Bg u)_{t-K+1+j}, zeros before the
-    sequence; returns Cg * c, [B, S, C]."""
-    taps = weight.shape[1]
+    sequence; returns Cg * c, [B, S, C].  The taps are ``causal_taps``,
+    which ``_contrib_CausalConv1D`` shares."""
     with jax.named_scope("short_conv"):
         bg, cg, u = jnp.split(data, 3, axis=-1)
-        bu = jnp.pad((bg * u).astype(jnp.float32),
-                     [(0, 0), (taps - 1, 0), (0, 0)])
-        s = data.shape[1]
-        w = weight.astype(jnp.float32)
-        c = sum(w[:, j] * bu[:, j:j + s] for j in range(taps))
-        return cg * c.astype(data.dtype)
+        return cg * causal_taps(bg * u, weight).astype(data.dtype)
 
 
 @register("_contrib_ShortConv", aliases=["ShortConv"])
@@ -219,6 +230,45 @@ def _short_conv(data, weight, **kw):
     [B, S, C] = Cg * conv(Bg * u)."""
     _tel.bump("short_conv_traced")
     return short_conv(data, weight)
+
+
+@register("_contrib_CausalConv1D", aliases=["CausalConv1D"])
+def _causal_conv1d(data, weight, *maybe_bias, act_type="silu",
+                   no_bias=False, **kw):
+    """Causal depthwise convolution along the sequence with an optional
+    bias and activation: data [B, S, C], weight [C, K], bias [C] ->
+    [B, S, C] = act(conv(data) + bias), float32 inside; ``act_type`` is
+    ``silu`` or None."""
+    if act_type not in ("silu", None):
+        raise ValueError("_contrib_CausalConv1D: act_type %r is not "
+                         "implemented (silu and None are)" % (act_type,))
+    _tel.bump("causal_conv_traced")
+    with jax.named_scope("causal_conv"):
+        c = causal_taps(data, weight, None if no_bias or not maybe_bias
+                        else maybe_bias[0])
+        if act_type == "silu":
+            c = c * jax.nn.sigmoid(c)
+        return c.astype(data.dtype)
+
+
+@register("_contrib_StateSpaceScan", aliases=["StateSpaceScan"])
+def _state_space_scan(data, dt, a_log, b, c, d, chunk=256, **kw):
+    """Mamba-2's selective state-space scan: data [B, S, H, P], dt
+    [B, S, H] (after the softplus; float32 inside), a_log and d [H], b and
+    c [B, S, G, N] -> [B, S, H, P]; head h reads group h // (H // G).  For
+    each head, with a state S [N, P] that starts at zero: S_t =
+    exp(-exp(a_log) dt_t) S_{t-1} + dt_t b_t x_t^T, y_t = c_t S_t + d x_t,
+    in the chunked dual form; the forward and the hand-derived backward
+    are Pallas kernels on a TPU and the same algorithm in ``jnp``
+    elsewhere."""
+    from .pallas_kernels import state_space_kernel_fits, state_space_scan
+    chunk = int(chunk)
+    _tel.bump("state_space_traced")
+    _tel.bump("state_space_chunks", -(-data.shape[1] // chunk))
+    kernel = jax.default_backend() == "tpu" and \
+        state_space_kernel_fits(data, b, chunk)
+    return state_space_scan(data, dt.astype(jnp.float32), a_log, b, c, d,
+                            chunk, kernel)
 
 
 def _head_blocks(data, label, block):

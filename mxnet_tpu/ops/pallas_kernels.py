@@ -43,7 +43,7 @@ from jax.experimental.pallas import tpu as pltpu
 from .. import telemetry as _tel
 from . import remat as _remat
 
-__all__ = ["flash_attention", "power_retention"]
+__all__ = ["flash_attention", "power_retention", "state_space_scan"]
 
 _NEG = np.float32(-1e30)
 _TINY = np.float32(1e-30)
@@ -910,3 +910,615 @@ def _retention_bwd(chunk, eps, use_kernel, interpret, res, do):
 
 
 power_retention.defvjp(_retention_fwd, _retention_bwd)
+
+
+# ---------------------------------------------------------------------------
+# State-space scan (Mamba-2's SSD; Dao and Gu, arXiv:2405.21060) in the
+# chunked dual form.  For each head, with a_t = dt_t * A <= 0 (A =
+# -exp(a_log)), c the running sum of a inside a chunk and a state S [N, P]
+# that starts at zero:
+#
+#   y_t   = sum_{s<=t} exp(c_t - c_s) (C_t . B_s) dt_s x_s    (this chunk)
+#           + exp(c_t) C_t S_start + D x_t
+#   S_end = exp(c_end) S_start + sum_s exp(c_end - c_s) dt_s B_s x_s^T
+#
+# dt, a, c, every decay factor and the carried state are float32; a decay
+# factor is exp of a difference of running sums, never a ratio of two
+# exponentials.  Products take operands in the inputs' dtype and accumulate
+# in float32.  B and C belong to a group of heads, so C B^T of a chunk is
+# one [Q, Q] product for all of them.
+
+_TN = (((0,), (0,)), ((), ()))
+
+
+def _ssm_pad(v, length):
+    pad = length - v.shape[1]
+    return jnp.pad(v, [(0, 0), (0, pad)] + [(0, 0)] * (v.ndim - 2)) \
+        if pad else v
+
+
+def _ssm_prepare(x, dt, a_log, b, c, chunk):
+    """The sequence padded with zeros to whole chunks (x = 0 and dt = 0
+    come after every real token and reach none) and the running sum of a
+    inside each chunk: -> x, dt, the sum [B, Sp, H], b, c, chunks."""
+    n = -(-x.shape[1] // chunk)
+    x, dt, b, c = (_ssm_pad(v, n * chunk)
+                   for v in (x, dt.astype(jnp.float32), b, c))
+    a = dt * -jnp.exp(a_log.astype(jnp.float32))
+    cs = jnp.cumsum(a.reshape(a.shape[0], n, chunk, -1), axis=2)
+    return x, dt, cs.reshape(a.shape), b, c, n
+
+
+def _ssm_chunks(n, groups, v, heads=True):
+    """[B, Sp, H, ..] -> [n, B, Q, G, H // G, ..], chunks first for a scan
+    and heads by group; b and c ([B, Sp, G, N]) with ``heads=False``."""
+    shape = (groups, v.shape[2] // groups) if heads else (groups,)
+    v = v.reshape((v.shape[0], n, v.shape[1] // n) + shape + v.shape[3:])
+    return jnp.moveaxis(v, 1, 0)
+
+
+def _ssm_unchunk(v, heads=True):
+    """[n, B, Q, G, R, ..] -> [B, Sp, H, ..] (``heads=False``: no R)."""
+    v = jnp.moveaxis(v, 0, 1)
+    split = 5 if heads else 4
+    return v.reshape((v.shape[0], v.shape[1] * v.shape[2], -1) +
+                     v.shape[split:])
+
+
+def _ssm_decay(cs):
+    """exp(c_t - c_s) for s <= t and 0 above: cs [B, Q, G, R] ->
+    [B, G, R, t, s]."""
+    ch = jnp.moveaxis(cs, 1, -1)
+    t = jnp.arange(cs.shape[1])
+    return jnp.exp(jnp.where(t[:, None] >= t[None, :],
+                             ch[..., :, None] - ch[..., None, :], -jnp.inf))
+
+
+def _ssm_advance(S, x, dt, cs, b):
+    """The state once a chunk, S [B, G, R, N, P] float32."""
+    cd = x.dtype
+    end = cs[:, -1]
+    w = jnp.exp(end[:, None] - cs) * dt
+    xw = (x.astype(jnp.float32) * w[..., None]).astype(cd)
+    return jnp.exp(end)[..., None, None] * S + \
+        _f32_acc(jnp.einsum, cd)("bsgn,bsgrp->bgrnp", b, xw)
+
+
+def _ssm_chunk(S, x, dt, cs, b, c, d):
+    """One chunk of every head in ``jnp``: the state at the chunk's start
+    -> (the state at its end, the outputs).  x [B, Q, G, R, P], dt and cs
+    [B, Q, G, R], b and c [B, Q, G, N], d [G, R]."""
+    f32, cd = jnp.float32, x.dtype
+    ein = _f32_acc(jnp.einsum, cd)
+    m = _ssm_decay(cs) * ein("btgn,bsgn->bgts", c, b)[:, :, None] * \
+        jnp.moveaxis(dt, 1, -1)[..., None, :]
+    y = ein("bgrts,bsgrp->btgrp", m.astype(cd), x)
+    y = y + jnp.exp(cs)[..., None] * ein("btgn,bgrnp->btgrp", c,
+                                         S.astype(cd))
+    y = y + d[..., None] * x.astype(f32)
+    return _ssm_advance(S, x, dt, cs, b), y.astype(cd)
+
+
+def _ssm_chunk_grads(S0, dS1, x, dt, cs, b, c, d, dy):
+    """Backward of ``_ssm_chunk`` by hand: the state the chunk started
+    from (in the operands' dtype), the cotangents of the state at its end
+    and of its outputs -> the cotangent of the state at its start and
+    (dx, d dt as far as dt enters directly, d cs of the running sum, db,
+    dc, dd).
+
+    The running sum's cotangent is a difference that a reverse running
+    sum follows (``_ssm_bwd``): entry [t, s] of F = (dy x^T) o decay o
+    C B^T adds F dt_s at t and takes it away at s, and only entries with
+    s < s0 <= t may remain in the sum at s0.  Both sides are therefore
+    taken from the one float32 F, its row and column sums: two roundings
+    of the same entry (y itself and a second product, say) would leave
+    bf16 noise from every entry of the chunk where exact arithmetic
+    leaves nothing, which is larger than the gradient of a head that
+    forgets within a few tokens."""
+    f32, cd = jnp.float32, x.dtype
+    ein = _f32_acc(jnp.einsum, cd)
+    xf, dyf = x.astype(f32), dy.astype(f32)
+    dt_row = jnp.moveaxis(dt, 1, -1)[..., None, :]
+    decay = _ssm_decay(cs)
+    g = ein("btgn,bsgn->bgts", c, b)[:, :, None]
+    v = ein("bgrts,btgrp->bsgrp", (decay * g).astype(cd), dy)
+    end = cs[:, -1]
+    e = jnp.exp(end[:, None] - cs)
+    w = e * dt
+    grow = jnp.exp(end)
+    ecs = jnp.exp(cs)[..., None]
+    dS1c = dS1.astype(cd)
+    bds = ein("bsgn,bgrnp->bsgrp", b, dS1c)
+    dx = dt[..., None] * v + d[..., None] * dyf + w[..., None] * bds
+    k = ein("btgrp,bsgrp->bgrts", dy, x) * decay
+    dg = jnp.sum(k * dt_row, axis=2).astype(cd)
+    dye = (dyf * ecs).astype(cd)
+    xw = (xf * w[..., None]).astype(cd)
+    dc = ein("bgts,bsgn->btgn", dg, b) + ein("btgrp,bgrnp->btgn", dye, S0)
+    db = ein("bgts,btgn->bsgn", dg, c) + ein("bsgrp,bgrnp->bsgn", xw, dS1c)
+    dS0 = grow[..., None, None] * dS1 + ein("btgn,btgrp->bgrnp", c, dye)
+    f = k * g
+    rows = jnp.moveaxis(jnp.sum(f * dt_row, axis=-1), -1, 1)
+    cols = jnp.moveaxis(jnp.sum(f, axis=-2), -1, 1)
+    xb = xf * bds
+    direct = cols + e * jnp.sum(xb, axis=-1)
+    dcs = rows + jnp.sum(dyf * ecs * ein("btgn,bgrnp->btgrp", c, S0),
+                         axis=-1) - dt * direct
+    # c_end is the running sum's last entry
+    dcs = dcs.at[:, -1].add(
+        jnp.sum(w[..., None] * xb, axis=(1, -1)) +
+        grow * jnp.sum(S0.astype(f32) * dS1, axis=(-2, -1)))
+    dd = jnp.sum(dyf * xf, axis=(0, 1, -1))
+    return dS0, (dx.astype(cd), direct, dcs, db, dc, dd)
+
+
+def _ssm_zero_state(x, b):
+    (bsz, _, h, p), (g, n) = x.shape, b.shape[2:]
+    return jnp.zeros((bsz, g, h // g, n, p), jnp.float32)
+
+
+def _ssm_scan(x, dt, cs, b, c, d, n):
+    """The chunked form in ``jnp``: a scan over the chunks of every head
+    at once.  -> y [B, Sp, H, P]."""
+    g = b.shape[2]
+    xs = tuple(_ssm_chunks(n, g, v) for v in (x, dt, cs)) + \
+        tuple(_ssm_chunks(n, g, v, False) for v in (b, c))
+    dg = d.reshape(g, -1)
+    y = jax.lax.scan(lambda S, v: _ssm_chunk(S, *v, dg),
+                     _ssm_zero_state(x, b), xs)[1]
+    return _ssm_unchunk(y)
+
+
+def _ssm_grads_scan(x, dt, cs, b, c, d, dy, n):
+    """The backward in ``jnp``: a states pass (the advance alone, each
+    chunk's start kept in the operands' dtype), then the chunks in reverse
+    with the state's cotangent carried."""
+    g = b.shape[2]
+    dg = d.reshape(g, -1)
+    xc, dtc, csc, dyc = (_ssm_chunks(n, g, v) for v in (x, dt, cs, dy))
+    bc, cc = (_ssm_chunks(n, g, v, False) for v in (b, c))
+    zero = _ssm_zero_state(x, b)
+    states = jax.lax.scan(
+        lambda S, v: (_ssm_advance(S, *v), S.astype(x.dtype)), zero,
+        (xc, dtc, csc, bc))[1]
+
+    def body(dS, v):
+        S0, x_, dt_, cs_, b_, c_, dy_ = v
+        return _ssm_chunk_grads(S0, dS, x_, dt_, cs_, b_, c_, dg, dy_)
+
+    dx, direct, dcs, db, dc, dd = jax.lax.scan(
+        body, zero, (states, xc, dtc, csc, bc, cc, dyc), reverse=True)[1]
+    return (_ssm_unchunk(dx), _ssm_unchunk(direct), _ssm_unchunk(dcs),
+            _ssm_unchunk(db, False), _ssm_unchunk(dc, False),
+            dd.sum(0).reshape(-1))
+
+
+# The Pallas kernels: a program a (batch, chunk, block of heads), the
+# chunk axis sequential and the blocks of heads inside it, so that the
+# chunk's C B^T is computed once (at a group's first block, into scratch)
+# and the float32 state of EVERY head (2 MB at 64 heads of 64 x 128) stays
+# in scratch from one chunk to the next.  Inside a program the heads go a
+# lane tile at a time: with P = 64 two heads fill 128 lanes, so x, y and
+# the state are read, computed and written in aligned [.., 128] tiles, and
+# the one product that is a head's own, (decay o C B^T) x_h, is done for
+# the tile's heads at once as [M_1 | M_2] @ blockdiag(x_1, x_2), which
+# costs the MXU what two 64-wide products would and needs no 64-lane slice.
+# A value a head ([Q, 1] or [1, 1]) is spread over its head's lanes by
+# ``_ssm_lanes``; dt and the running sum come in as [Q, heads] tiles and
+# as their transposes, for the decay matrix's columns and rows.
+
+_SSM_PARAMS = dict(
+    compiler_params=pltpu.CompilerParams(
+        dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+        vmem_limit_bytes=100 * 1024 * 1024))
+
+
+def _ssm_tiling(h, g, p, heads):
+    """(heads a lane tile, heads a program) for h heads of p in g groups:
+    the most heads a program up to *heads* that divide a group's and fill
+    whole lane tiles."""
+    per_group = h // g
+    r = min(max(_LANES // p, 1), per_group)
+    for hb in range(max(r, min(heads, per_group)), r - 1, -1):
+        if per_group % hb == 0 and hb % r == 0:
+            return r, hb
+    raise ValueError("state_space_scan kernel: lane tiles of %d heads do "
+                     "not tile %d heads a group" % (r, per_group))
+
+
+def state_space_kernel_fits(x, b, chunk, heads=32):
+    """Whether the Pallas scan takes these shapes: lane tiles of whole
+    heads, a state and a chunk of whole lane tiles."""
+    p, nstate = x.shape[-1], b.shape[-1]
+    try:
+        r, _ = _ssm_tiling(x.shape[2], b.shape[2], p, heads)
+    except ValueError:
+        return False
+    return (r * p) % _LANES == 0 and nstate % _LANES == 0 and \
+        chunk % _LANES == 0
+
+
+def _ssm_lanes(cols, p):
+    """One value a head over the head's p lanes: cols[i] [rows, 1] ->
+    [rows, len(cols) * p]."""
+    rows, width = cols[0].shape[0], len(cols) * p
+    out = jnp.broadcast_to(cols[-1], (rows, width))
+    lane = jax.lax.broadcasted_iota(jnp.int32, (rows, width), 1)
+    for i in range(len(cols) - 2, -1, -1):
+        out = jnp.where(lane < np.int32((i + 1) * p), cols[i], out)
+    return out
+
+
+def _ssm_of_head(v, i, p):
+    """v [rows, r * p] with every lane outside head i's zeroed."""
+    if v.shape[1] == p:
+        return v
+    lane = jax.lax.broadcasted_iota(jnp.int32, v.shape, 1)
+    mine = (lane >= np.int32(i * p)) & (lane < np.int32((i + 1) * p))
+    return jnp.where(mine, v, jnp.zeros_like(v))
+
+
+def _ssm_block_diag(v, p):
+    """v [Q, r * p] -> [r * Q, r * p]: row block i keeps head i's lanes."""
+    r = v.shape[1] // p
+    return v if r == 1 else jnp.concatenate(
+        [_ssm_of_head(v, i, p) for i in range(r)], axis=0)
+
+
+def _ssm_enter(st_ref, g_ref, b_ref, c_ref, cs_ref, dt_ref, per_group):
+    """What the three kernels do first: zero this block's state at the
+    first chunk of the grid, C B^T into scratch at a group's first block
+    (if *g_ref*); -> (the running sum and dt [Q, hb], decay to the chunk's
+    end [Q, hb], over the whole chunk [1, hb], whether the block is its
+    group's first)."""
+    k = pl.program_id(2)
+
+    @pl.when(pl.program_id(1) == 0)
+    def _zero():
+        st_ref[k] = jnp.zeros(st_ref.shape[1:], st_ref.dtype)
+
+    first = _i32(jax.lax.rem, k, per_group) == 0
+    if g_ref is not None:
+        @pl.when(first)
+        def _group():
+            g_ref[:] = _f32_acc(jax.lax.dot_general, b_ref.dtype)(
+                c_ref[:], b_ref[:], _NT)
+
+    cs, dt = cs_ref[:], dt_ref[:]
+    q = cs.shape[0]
+    end = cs[q - 1:q, :]
+    return cs, dt, jnp.exp(end - cs), jnp.exp(end), first
+
+
+def _ssm_lower(q):
+    t = jax.lax.broadcasted_iota(jnp.int32, (q, q), 0)
+    s = jax.lax.broadcasted_iota(jnp.int32, (q, q), 1)
+    return t >= s
+
+
+def _ssm_fwd_kernel(x_ref, b_ref, c_ref, dt_ref, dtT_ref, cs_ref, csT_ref,
+                    d_ref, out_ref, st_ref, g_ref=None, *, p, r, per_group):
+    """The forward (out: y [Q, hb * P]) and, without *g_ref*, the states
+    pass (out: the state each chunk starts from, [N, hb * P])."""
+    f32, cd = jnp.float32, x_ref.dtype
+    dot = _f32_acc(jax.lax.dot_general, cd)
+    cs, dt, to_end, grow, _ = _ssm_enter(st_ref, g_ref, b_ref, c_ref,
+                                         cs_ref, dt_ref, per_group)
+    k = pl.program_id(2)
+    tw = r * p
+    w = to_end * dt
+    if g_ref is not None:
+        ecs = jnp.exp(cs)
+        lower = _ssm_lower(cs.shape[0])
+    for i in range(x_ref.shape[1] // tw):
+        lanes = slice(i * tw, (i + 1) * tw)
+        hs = range(i * r, (i + 1) * r)
+        xt = x_ref[:, lanes]
+        S = st_ref[k, i]
+        if g_ref is None:
+            out_ref[:, lanes] = S.astype(out_ref.dtype)
+        else:
+            ms = [(jnp.exp(jnp.where(lower, cs[:, h:h + 1] -
+                                     csT_ref[h:h + 1, :], _NEG)) *
+                   g_ref[:] * dtT_ref[h:h + 1, :]).astype(cd) for h in hs]
+            y = dot(jnp.concatenate(ms, axis=1), _ssm_block_diag(xt, p),
+                    _NN)
+            y = y + dot(c_ref[:], S.astype(cd), _NN) * \
+                _ssm_lanes([ecs[:, h:h + 1] for h in hs], p)
+            y = y + d_ref[:, lanes] * xt.astype(f32)
+            out_ref[:, lanes] = y.astype(out_ref.dtype)
+        xw = (xt.astype(f32) *
+              _ssm_lanes([w[:, h:h + 1] for h in hs], p)).astype(cd)
+        st_ref[k, i] = S * _ssm_lanes([grow[:, h:h + 1] for h in hs], p) + \
+            dot(b_ref[:], xw, _TN)
+
+
+def _ssm_bwd_kernel(x_ref, dy_ref, b_ref, c_ref, dt_ref, dtT_ref, cs_ref,
+                    csT_ref, d_ref, s0_ref, dx_ref, rows_ref, edw_ref,
+                    colsT_ref, db_ref, dc_ref, dd_ref, ds_ref, g_ref, dg_ref,
+                    dbacc_ref, dcacc_ref, *, p, r, per_group):
+    """One (batch, chunk, block of heads) program of the backward, the
+    chunks in reverse (the block maps turn the axis round) with the
+    state's cotangent carried in scratch: ``_ssm_chunk_grads`` a lane tile
+    of heads at a time.  db and dc gather over a group's blocks of heads
+    in scratch and are written at its last.  Of the running sum's
+    cotangent the kernel writes what it has as columns ([Q, hb]: the row
+    sums of F with everything that enters at t, and exp(c_end - c_s) dw_s)
+    and what it has as rows ([hb, Q]: the column sums of F); ``_ssm_bwd``
+    puts them together."""
+    f32, cd = jnp.float32, x_ref.dtype
+    dot = _f32_acc(jax.lax.dot_general, cd)
+    cs, dt, to_end, grow, first = _ssm_enter(ds_ref, g_ref, b_ref, c_ref,
+                                             cs_ref, dt_ref, per_group)
+    k = pl.program_id(2)
+
+    @pl.when(first)
+    def _group():
+        dg_ref[:] = jnp.zeros_like(dg_ref)
+        dbacc_ref[:] = jnp.zeros_like(dbacc_ref)
+        dcacc_ref[:] = jnp.zeros_like(dcacc_ref)
+
+    q, tw = cs.shape[0], r * p
+    ecs = jnp.exp(cs)
+    lower = _ssm_lower(q)
+    head_lane = jax.lax.broadcasted_iota(jnp.int32, cs.shape, 1)
+    last_row = jax.lax.broadcasted_iota(jnp.int32, (q, 1), 0) == \
+        np.int32(q - 1)
+    rows_all = jnp.zeros(cs.shape, f32)
+    edw_all = jnp.zeros(cs.shape, f32)
+    for i in range(x_ref.shape[1] // tw):
+        lanes = slice(i * tw, (i + 1) * tw)
+        hs = range(i * r, (i + 1) * r)
+        xt, dyt = x_ref[:, lanes], dy_ref[:, lanes]
+        xf, dyf = xt.astype(f32), dyt.astype(f32)
+        dS1 = ds_ref[k, i]
+        dS1c = dS1.astype(cd)
+        S0 = s0_ref[:, lanes]
+        ms, row_sums = [], []
+        for n_, h in enumerate(hs):
+            dt_row = dtT_ref[h:h + 1, :]
+            decay = jnp.exp(jnp.where(lower, cs[:, h:h + 1] -
+                                      csT_ref[h:h + 1, :], _NEG))
+            ms.append((decay * g_ref[:]).astype(cd))
+            kk = dot(_ssm_of_head(dyt, n_, p), xt, _NT) * decay
+            dg_ref[:] += kk * dt_row
+            f = kk * g_ref[:]
+            row_sums.append(jnp.sum(f * dt_row, axis=1, keepdims=True))
+            colsT_ref[h:h + 1, :] = jnp.sum(f, axis=0, keepdims=True)
+        v = dot(jnp.concatenate(ms, axis=0), _ssm_block_diag(dyt, p), _TN)
+        bds = dot(b_ref[:], dS1c, _NN)
+        e_l, dt_l, ecs_l, grow_l = (
+            _ssm_lanes([a[:, h:h + 1] for h in hs], p)
+            for a in (to_end, dt, ecs, grow))
+        w_l = e_l * dt_l
+        d_l = d_ref[:, lanes]
+        dx_ref[:, lanes] = (dt_l * v + d_l * dyf + w_l * bds) \
+            .astype(dx_ref.dtype)
+        dye_f = dyf * ecs_l
+        dye = dye_f.astype(cd)
+        dcacc_ref[:] += dot(dye, S0, _NT)
+        dbacc_ref[:] += dot((xf * w_l).astype(cd), dS1c, _NT)
+        ds_ref[k, i] = grow_l * dS1 + dot(c_ref[:], dye, _TN)
+        xb = xf * bds
+        carried = dye_f * dot(c_ref[:], S0, _NN)
+        tail = jnp.sum(w_l * xb, axis=0, keepdims=True) + \
+            grow_l * jnp.sum(S0.astype(f32) * dS1, axis=0, keepdims=True)
+        dd_ref[:, lanes] = jnp.sum(dyf * xf, axis=0, keepdims=True)
+        for n_, h in enumerate(hs):
+            enters, dw, end = (
+                jnp.sum(_ssm_of_head(a, n_, p), axis=1, keepdims=True)
+                for a in (carried, xb, tail))
+            mine = head_lane == np.int32(h)
+            rows_all = jnp.where(
+                mine, row_sums[n_] + enters +
+                jnp.where(last_row, end, np.float32(0.0)), rows_all)
+            edw_all = jnp.where(mine, to_end[:, h:h + 1] * dw, edw_all)
+    rows_ref[:] = rows_all
+    edw_ref[:] = edw_all
+
+    @pl.when(_i32(jax.lax.rem, k, per_group) == np.int32(per_group - 1))
+    def _write():
+        dg = dg_ref[:].astype(cd)
+        dc_ref[:] = (dcacc_ref[:] + dot(dg, b_ref[:], _NN)) \
+            .astype(dc_ref.dtype)
+        db_ref[:] = (dbacc_ref[:] + dot(dg, c_ref[:], _TN)) \
+            .astype(db_ref.dtype)
+
+
+def _ssm_head_tiles(v, n, hb):
+    """[B, Sp, H] -> a tile a (chunk, block of heads), [B, n, H / hb, Q,
+    hb], and its transpose [.., hb, Q]."""
+    bsz, sp, h = v.shape
+    v = v.reshape(bsz, n, sp // n, h // hb, hb)
+    return v.transpose(0, 1, 3, 2, 4), v.transpose(0, 1, 3, 4, 2)
+
+
+def _ssm_from_tiles(t):
+    bsz, n, blocks, q, hb = t.shape
+    return t.transpose(0, 1, 3, 2, 4).reshape(bsz, n * q, blocks * hb)
+
+
+def _ssm_specs(n, q, hb, p, nstate, per_group, reverse):
+    """Block specs of the grid (batch, chunk, block of heads); *reverse*
+    visits the chunks last to first."""
+    zero = np.int32(0)
+
+    def chunk(j):
+        return np.int32(n - 1) - j if reverse else j
+
+    def group(k):
+        return _i32(jax.lax.div, k, per_group)
+
+    return dict(
+        seq=pl.BlockSpec((None, q, hb * p),
+                         lambda b, j, k: (b, chunk(j), k)),
+        grp=pl.BlockSpec((None, q, nstate),
+                         lambda b, j, k: (b, chunk(j), group(k))),
+        tile=pl.BlockSpec((None, None, None, q, hb),
+                          lambda b, j, k: (b, chunk(j), k, zero, zero)),
+        tileT=pl.BlockSpec((None, None, None, hb, q),
+                           lambda b, j, k: (b, chunk(j), k, zero, zero)),
+        skip=pl.BlockSpec((1, hb * p), lambda b, j, k: (zero, k)),
+        state=pl.BlockSpec((None, None, nstate, hb * p),
+                           lambda b, j, k: (b, chunk(j), zero, k)),
+        row=pl.BlockSpec((None, None, 1, hb * p),
+                         lambda b, j, k: (b, chunk(j), zero, k)))
+
+
+def _ssm_operands(x, dt, cs, b, c, d, n, heads, reverse=False):
+    """What every kernel reads, the kernels' static facts, the grid's
+    block specs (*reverse*: the chunks last to first), the grid and the
+    heads a program."""
+    bsz, sp, h, p = x.shape
+    g, nstate = b.shape[2:]
+    r, hb = _ssm_tiling(h, g, p, heads)
+    dt_t, dt_T = _ssm_head_tiles(dt, n, hb)
+    cs_t, cs_T = _ssm_head_tiles(cs, n, hb)
+    operands = (b.reshape(bsz, sp, g * nstate), c.reshape(bsz, sp,
+                                                          g * nstate),
+                dt_t, dt_T, cs_t, cs_T,
+                jnp.repeat(d.astype(jnp.float32), p)[None, :])
+    facts = dict(p=p, r=r, per_group=h // g // hb)
+    spec = _ssm_specs(n, sp // n, hb, p, nstate, h // g // hb, reverse)
+    return operands, facts, spec, (bsz, n, h // hb), hb
+
+
+def _ssm_pallas(x, dt, cs, b, c, d, n, heads, interpret, states=False):
+    """The forward in Pallas: same operands and result as ``_ssm_scan``;
+    with *states* the states pass instead: the state each chunk starts
+    from, [B, n, N, H * P] in the operands' dtype."""
+    bsz, sp, h, p = x.shape
+    nstate = b.shape[3]
+    operands, facts, spec, grid, hb = _ssm_operands(x, dt, cs, b, c, d, n,
+                                                    heads)
+    q, tiles = sp // n, hb // facts["r"]
+    scratch = [pltpu.VMEM((h // hb, tiles, nstate, facts["r"] * p),
+                          jnp.float32)]
+    if states:
+        out_spec, out_shape = spec["state"], (bsz, n, nstate, h * p)
+    else:
+        out_spec, out_shape = spec["seq"], (bsz, sp, h * p)
+        scratch.append(pltpu.VMEM((q, q), jnp.float32))
+    out = pl.pallas_call(
+        functools.partial(_ssm_fwd_kernel, **facts), grid=grid,
+        in_specs=[spec["seq"], spec["grp"], spec["grp"], spec["tile"],
+                  spec["tileT"], spec["tile"], spec["tileT"], spec["skip"]],
+        out_specs=out_spec,
+        out_shape=jax.ShapeDtypeStruct(out_shape, x.dtype),
+        scratch_shapes=scratch,
+        name="state_space_bwd_states" if states else "state_space_fwd",
+        interpret=interpret, **_SSM_PARAMS,
+    )(x.reshape(bsz, sp, h * p), *operands)
+    return out if states else out.reshape(x.shape)
+
+
+def _ssm_grads_pallas(x, dt, cs, b, c, d, dy, n, heads, interpret):
+    """The backward in Pallas: same operands and results as
+    ``_ssm_grads_scan``; the states pass, then the reverse pass."""
+    bsz, sp, h, p = x.shape
+    g, nstate = b.shape[2:]
+    f32 = jnp.float32
+    S0 = _ssm_pallas(x, dt, cs, b, c, d, n, heads, interpret, states=True)
+    operands, facts, spec, grid, hb = _ssm_operands(x, dt, cs, b, c, d, n,
+                                                    heads, reverse=True)
+    q, r = sp // n, facts["r"]
+    flat = (bsz, sp, h * p)
+    tile_shape = jax.ShapeDtypeStruct((bsz, n, h // hb, q, hb), f32)
+    group_shape = jax.ShapeDtypeStruct((bsz, sp, g * nstate), f32)
+    dx, rows, edw, colsT, db, dc, dd = pl.pallas_call(
+        functools.partial(_ssm_bwd_kernel, **facts), grid=grid,
+        in_specs=[spec["seq"]] * 2 + [
+            spec["grp"], spec["grp"], spec["tile"], spec["tileT"],
+            spec["tile"], spec["tileT"], spec["skip"], spec["state"]],
+        out_specs=[spec["seq"], spec["tile"], spec["tile"], spec["tileT"],
+                   spec["grp"], spec["grp"], spec["row"]],
+        out_shape=[jax.ShapeDtypeStruct(flat, x.dtype), tile_shape,
+                   tile_shape,
+                   jax.ShapeDtypeStruct((bsz, n, h // hb, hb, q), f32),
+                   group_shape, group_shape,
+                   jax.ShapeDtypeStruct((bsz, n, 1, h * p), f32)],
+        scratch_shapes=[
+            pltpu.VMEM((h // hb, hb // r, nstate, r * p), f32),
+            pltpu.VMEM((q, q), f32), pltpu.VMEM((q, q), f32),
+            pltpu.VMEM((q, nstate), f32), pltpu.VMEM((q, nstate), f32)],
+        name="state_space_bwd", interpret=interpret, **_SSM_PARAMS,
+    )(x.reshape(flat), dy.reshape(flat), *operands, S0)
+    direct = _ssm_from_tiles(jnp.swapaxes(colsT, -1, -2)) + \
+        _ssm_from_tiles(edw)
+    return (dx.reshape(x.shape), direct,
+            _ssm_from_tiles(rows) - dt * direct,
+            db.reshape(b.shape).astype(b.dtype),
+            dc.reshape(c.shape).astype(c.dtype),
+            dd.sum((0, 1, 2)).reshape(h, p).sum(-1))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9))
+def state_space_scan(x, dt, a_log, b, c, d, chunk=256, use_kernel=False,
+                     interpret=False, heads=32):
+    """Mamba-2's selective state-space scan in the chunked dual form.
+
+    x [B, S, H, P], dt [B, S, H] (float32, after the softplus), a_log and
+    d [H], b and c [B, S, G, N] -> y [B, S, H, P]; head h reads group
+    h // (H // G).  For each head, with a_t = -exp(a_log) dt_t and a state
+    S [N, P] that starts at zero: S_t = exp(a_t) S_{t-1} + dt_t b_t x_t^T,
+    y_t = c_t S_t + d x_t.  ``use_kernel`` runs the forward, the
+    backward's states pass and its reverse pass as Pallas kernels (TPU, or
+    ``interpret=True``) of *heads* heads a program, else the same
+    algorithm in ``jnp``, a scan over chunks.  *chunk* changes the
+    rounding and nothing else.
+
+    The forward saves its operands alone.  The backward first
+    remakes the state at each chunk's start from x, dt and b alone (the
+    state's advance: one of a head's three products) in the operands'
+    dtype, so nothing of the states (at 16,384 tokens, 64 heads of 64 x
+    128 and chunks of 256: 67 MB a layer in bf16) lives between the
+    passes; then the chunks in reverse.  The [chunks, H, Q, Q] decay
+    tensor is never whole: the kernels hold one head's [Q, Q] at a time,
+    the ``jnp`` path one chunk's.  A recomputation segment keeps the
+    op's output (``ops/remat.py``; 134 MB a layer there), for which alone
+    the replay would run the forward again.
+    """
+    s = x.shape[1]
+    with jax.named_scope("state_space_fwd"):
+        xp, dtp, cs, bp, cp, n = _ssm_prepare(x, dt, a_log, b, c, chunk)
+        df = d.astype(jnp.float32)
+        if use_kernel:
+            y = _ssm_pallas(xp, dtp, cs, bp, cp, df, n, heads, interpret)
+        else:
+            y = _ssm_scan(xp, dtp, cs, bp, cp, df, n)
+        return y[:, :s]
+
+
+def _ssm_fwd(x, dt, a_log, b, c, d, chunk, use_kernel, interpret, heads):
+    (y,) = _remat.keep(state_space_scan.fun(x, dt, a_log, b, c, d, chunk,
+                                            use_kernel, interpret, heads))
+    return y, (x, dt, a_log, b, c, d)
+
+
+def _ssm_bwd(chunk, use_kernel, interpret, heads, res, dy):
+    x, dt, a_log, b, c, d = res
+    s = x.shape[1]
+    f32 = jnp.float32
+    _tel.bump("state_space_states_traced")
+    with jax.named_scope("state_space_bwd"):
+        xp, dtp, cs, bp, cp, n = _ssm_prepare(x, dt, a_log, b, c, chunk)
+        dyp = _ssm_pad(dy.astype(x.dtype), n * chunk)
+        df = d.astype(f32)
+        if use_kernel:
+            grads = _ssm_grads_pallas(xp, dtp, cs, bp, cp, df, dyp, n,
+                                      heads, interpret)
+        else:
+            grads = _ssm_grads_scan(xp, dtp, cs, bp, cp, df, dyp, n)
+        dx, direct, dcs, db, dc, dd = grads
+        # c is the running sum of a = dt * A inside a chunk, A = -exp(a_log)
+        A = -jnp.exp(a_log.astype(f32))
+        da = jax.lax.cumsum(dcs.reshape(dcs.shape[0], n, chunk, -1), axis=2,
+                            reverse=True).reshape(dcs.shape)
+        ddt = direct + da * A
+        da_log = jnp.sum(da * dtp, axis=(0, 1)) * A
+    return (dx[:, :s], ddt[:, :s].astype(dt.dtype),
+            da_log.astype(a_log.dtype), db[:, :s].astype(b.dtype),
+            dc[:, :s].astype(c.dtype), dd.astype(d.dtype))
+
+
+state_space_scan.defvjp(_ssm_fwd, _ssm_bwd)

@@ -31,6 +31,8 @@ HINTS = {
     "_contrib_BlockedSoftmaxCE": "blockedsoftmaxce",
     "_contrib_CausalAttention": "causalattention",
     "_contrib_ShortConv": "shortconv",
+    "_contrib_CausalConv1D": "causalconv1d",
+    "_contrib_StateSpaceScan": "statespacescan",
     "_contrib_SparseMoE": "sparsemoe",
     "elemwise_add": "_plus", "elemwise_sub": "_minus",
     "elemwise_mul": "_mul", "elemwise_div": "_div",
@@ -68,6 +70,11 @@ def op_input_names(op, attrs):
         return ["query", "key", "value"], []
     if name == "_contrib_ShortConv":
         return ["data", "weight"], []
+    if name == "_contrib_CausalConv1D":
+        return (["data", "weight"] if a.get("no_bias", False)
+                else ["data", "weight", "bias"]), []
+    if name == "_contrib_StateSpaceScan":
+        return ["data", "dt", "a_log", "b", "c", "d"], []
     if name == "_contrib_SparseMoE":
         return ["data", "router_weight", "w1_weight", "w3_weight",
                 "w2_weight"], ["expert_bias"]
@@ -168,6 +175,10 @@ def infer_param_shapes(node, in_structs):
         out[1] = S((dshape[int(a.get("axis", -1)) % len(dshape)],))
     elif name == "_contrib_ShortConv":
         out[1] = S((dshape[-1] // 3, int(a.get("kernel", 3))))
+    elif name == "_contrib_CausalConv1D":
+        out[1] = S((dshape[-1], int(a.get("kernel", 4))))
+        if len(in_structs) > 2:
+            out[2] = S((dshape[-1],))
     elif name == "_contrib_BlockedSoftmaxCE":
         out[1] = S((int(a.get("num_hidden")), dshape[-1]))
         out[2] = jax.ShapeDtypeStruct(dshape[:-1], np.float32)

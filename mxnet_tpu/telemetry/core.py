@@ -569,7 +569,8 @@ COUNTERS = {
                            "segment marked to be kept for the backward "
                            "instead of replayed (ops/remat.py:keep: two an "
                            "attention op on the kernel path, four a "
-                           "SparseMoE op, one a PowerRetention op), summed "
+                           "SparseMoE op, one a PowerRetention or a "
+                           "StateSpaceScan op), summed "
                            "over traces",
     "power_retention_traced": "_contrib_PowerRetention ops traced (the "
                               "chunked state form)",
@@ -593,6 +594,15 @@ COUNTERS = {
     "window_attention_traced": "_contrib_CausalAttention ops traced with a "
                                "sliding window (banded kernels on a TPU)",
     "short_conv_traced": "_contrib_ShortConv ops traced",
+    "causal_conv_traced": "_contrib_CausalConv1D ops traced",
+    "state_space_traced": "_contrib_StateSpaceScan ops traced (the chunked "
+                          "dual form)",
+    "state_space_chunks": "chunks a sequence over all traced "
+                          "_contrib_StateSpaceScan ops",
+    "state_space_states_traced": "state-space backward rules traced: each "
+                                 "remakes the chunk-start states from x, "
+                                 "dt and b by the states pass (the forward "
+                                 "saves none)",
     "lm_head_fused_traced": "_contrib_BlockedSoftmaxCE forward rules traced "
                             "(under differentiation: loss, dh and dW in "
                             "one scan; the undifferentiated op does not "

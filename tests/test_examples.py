@@ -62,6 +62,12 @@ def test_trinity_lm_trains_through_module_fit(capsys):
     assert float(words[2]) < 0.5 * float(words[4])
 
 
+def test_granite_lm_trains_through_module_fit(capsys):
+    out = run_example("granite_lm.py", ["--num-epochs", "6"], capsys)
+    words = out.strip().splitlines()[-1].split()
+    assert float(words[2]) < 0.5 * float(words[4])
+
+
 def test_model_parallel_lstm_group2ctx(capsys):
     out = run_example("model_parallel_lstm.py", ["--num-steps", "40"],
                       capsys)

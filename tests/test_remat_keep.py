@@ -15,6 +15,7 @@ from mxnet_tpu import telemetry
 from mxnet_tpu.executor import _build_graph_fn
 from mxnet_tpu.io import DataBatch, DataDesc
 from mxnet_tpu.models.brumby import BRUMBY_TINY, brumby_symbol
+from mxnet_tpu.models.granite import GRANITE_TINY, granite_hybrid_symbol
 from mxnet_tpu.models.lfm2 import LFM2_MOE_TINY, lfm2_moe_symbol
 from mxnet_tpu.models.trinity import AFMOE_TINY, afmoe_symbol
 from mxnet_tpu.ops import lm, moe, remat
@@ -22,7 +23,8 @@ from mxnet_tpu.ops import pallas_kernels as pk
 
 TOYS = {"trinity": (afmoe_symbol, AFMOE_TINY),
         "lfm2": (lfm2_moe_symbol, LFM2_MOE_TINY),
-        "brumby": (brumby_symbol, BRUMBY_TINY)}
+        "brumby": (brumby_symbol, BRUMBY_TINY),
+        "granite": (granite_hybrid_symbol, GRANITE_TINY)}
 
 
 def walk(jaxpr, inside=()):
@@ -147,11 +149,12 @@ def test_a_segment_sorts_and_chooses_once(name):
 @pytest.mark.parametrize("name,kernel,kept", [
     ("trinity", True, 8 * 2 + 6 * 4), ("trinity", False, 6 * 4),
     ("lfm2", True, 2 * 2 + 3 * 4), ("lfm2", False, 3 * 4),
-    ("brumby", False, BRUMBY_TINY["num_hidden_layers"])])
+    ("brumby", False, BRUMBY_TINY["num_hidden_layers"]),
+    ("granite", False, 9)])
 def test_the_counter_reads_the_values_kept_a_trace(request, name, kernel,
                                                    kept):
     """Two an attention op on the kernel path, four a sparse-expert op,
-    one a retention op."""
+    one a retention op, one a state-space scan."""
     if kernel:
         request.getfixturevalue("kernel_path")
     (total, _), count = traced(toy(name))
@@ -282,9 +285,42 @@ def compiled(name, recompute=True):
         [g[0].asnumpy() for g in mod._exec_group.grad_arrays]
 
 
+def test_a_granite_replay_holds_no_scan_forward():
+    """The scan's output is kept, so a mamba segment's replay stops before
+    the scan; the backward rule makes the chunk states itself, one forward
+    pass over the chunks a layer, and then visits them in reverse.  A
+    plain ``jax.checkpoint`` replays the scan's forward as before."""
+    counters = ("executor_remat_kept", "state_space_states_traced")
+
+    def passes():
+        grad, spec = graph_gradient(toy("granite"))
+        before = [telemetry.counter(name) for name in counters]
+        jaxpr = jax.make_jaxpr(grad)(*spec).jaxpr
+        replayed = states = reverse = 0
+        for eqn, inside in walk(jaxpr):
+            if eqn.primitive.name != "scan":
+                continue
+            scope = str(eqn.source_info.name_stack)
+            if scope.endswith("state_space_fwd") and "remat2" in inside:
+                replayed += 1
+            if scope.endswith("state_space_bwd"):
+                if eqn.params["reverse"]:
+                    reverse += 1
+                else:
+                    states += 1
+        return (replayed, states, reverse), [
+            telemetry.counter(name) - was
+            for name, was in zip(counters, before)]
+
+    assert passes() == ((0, 9, 9), [9, 9])
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(remat, "POLICY", None)
+        assert passes() == ((9, 9, 9), [9, 9])
+
+
 @pytest.mark.parametrize("name,kernel", [
     ("lfm2", True), ("trinity", True), ("lfm2", False), ("trinity", False),
-    ("brumby", False)])
+    ("brumby", False), ("granite", False)])
 def test_kept_values_change_no_number(request, name, kernel):
     """Bit for bit the plain ``jax.checkpoint``'s loss and gradients op by
     op; compiled, the unsegmented graph's within the rounding of another

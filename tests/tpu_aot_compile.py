@@ -154,6 +154,31 @@ def main():
                  (mem.argument_size_in_bytes + mem.output_size_in_bytes +
                   mem.temp_size_in_bytes) / 1e9), flush=True)
 
+    # the state-space scan at the Granite widths (64 heads of 64 in one
+    # group, state 128, chunks of 256, 16,384 tokens, 32 heads a
+    # program): the forward kernel alone, and through the gradient the
+    # states pass and the reverse pass, which read no output
+    from mxnet_tpu.ops.pallas_kernels import state_space_scan
+    operands = (spec(1, 16384, 64, 64),
+                spec(1, 16384, 64, dtype=jnp.float32),
+                spec(64, dtype=jnp.float32), spec(1, 16384, 1, 128),
+                spec(1, 16384, 1, 128), spec(64, dtype=jnp.float32))
+
+    def scan(*x):
+        return state_space_scan(*x, 256, True)
+    for grad in (False, True):
+        fn = jax.grad(lambda *x: scan(*x).astype(jnp.float32).sum(),
+                      tuple(range(6))) if grad else scan
+        text = jax.jit(fn).lower(*operands).compile().as_text()
+        kernels = [name for name in ("state_space_fwd",
+                                     "state_space_bwd_states",
+                                     "state_space_bwd")
+                   if "%" + name + "." in text or "%" + name + " " in text]
+        assert kernels == (["state_space_bwd_states", "state_space_bwd"]
+                           if grad else ["state_space_fwd"]), kernels
+        print("AOT ok state_space_scan grad=%s: %s" % (grad, kernels),
+              flush=True)
+
     # the kernel from mx.pallas's docstring, through the op registry
     # (same kernel and helper the interpret-mode tests use)
     from test_pallas_register import _register_scale, _registered_fn
