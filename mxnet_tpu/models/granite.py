@@ -99,7 +99,7 @@ def granite_hybrid_symbol(cfg, recompute=True, probes=()):
         proj = dense(h, inner + conv_dim + nh, pre + "in_proj")
         z = sliced(proj, 0, inner)
         xbc = S.contrib.CausalConv1D(
-            sliced(proj, inner, inner + conv_dim),
+            proj, begin=inner, end=inner + conv_dim,
             weight=S.Variable(
                 pre + "conv_weight", shape=(conv_dim, taps), dtype=dtype,
                 init=initializer.Xavier(factor_type="in", magnitude=1)),

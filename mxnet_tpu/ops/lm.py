@@ -212,15 +212,86 @@ def causal_taps(x, weight, bias=None):
     return c if bias is None else c + bias.astype(jnp.float32)
 
 
+def _kernel_backend():
+    return jax.default_backend() == "tpu"
+
+
+def sequence_conv(data, weight, bias=None, silu=False, gated=False,
+                  begin=0):
+    """What both convolution ops compute, in ``data``'s dtype: the taps
+    over channels *begin* .. *begin* + C of ``data`` (gated: over Bg * u,
+    ``data`` [B, S, 3C] holding Bg, Cg, u in that order), the bias, SiLU
+    if *silu*, one rounding, times Cg if *gated*.  On a TPU, where the
+    kernels take the shapes (``causal_conv_kernel_fits``: channels in
+    whole lane tiles, the sequence in whole tiles of 512, at most 8 taps),
+    one Pallas pass forward and one backward (``causal_conv_fwd`` /
+    ``causal_conv_bwd``, counter ``causal_conv_kernel_traced``) that read
+    the channels where they lie if *begin* is at a whole channel tile and
+    a sliced copy of them if not; else the ``jnp`` form below, which is
+    the definition."""
+    from .pallas_kernels import (causal_conv_kernel_fits,
+                                 causal_conv_reads_in_place)
+    channels = weight.shape[0]
+    kernels = _kernel_backend() and causal_conv_kernel_fits(
+        data.shape[1], channels, weight.shape[1])
+    if not gated and data.shape[-1] != channels and not (
+            kernels and causal_conv_reads_in_place(channels, begin)):
+        data = jax.lax.slice_in_dim(data, begin, begin + channels, axis=-1)
+        begin = 0
+    if kernels:
+        _tel.bump("causal_conv_kernel_traced")
+        if bias is None:        # adding zeros changes nothing
+            bias = jnp.zeros(weight.shape[:1], weight.dtype)
+        return _conv_kernels(data, weight, bias, silu, gated, begin)
+    if gated:
+        bg, cg, u = jnp.split(data, 3, axis=-1)
+    c = causal_taps(bg * u if gated else data, weight, bias)
+    if silu:
+        c = c * jax.nn.sigmoid(c)
+    return cg * c.astype(data.dtype) if gated else c.astype(data.dtype)
+
+
+def _conv_scope(gated):
+    return jax.named_scope("short_conv" if gated else "causal_conv")
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _conv_kernels(data, weight, bias, silu, gated, begin):
+    """``sequence_conv`` as the two Pallas kernels.  Nothing is kept for
+    the backward but the op's inputs: it remakes the pre-activation from
+    them in VMEM."""
+    from .pallas_kernels import _causal_conv_fwd_impl
+    return _causal_conv_fwd_impl(data, weight, bias, silu, gated, begin,
+                                 False)
+
+
+def _conv_kernels_fwd(data, weight, bias, silu, gated, begin):
+    return _conv_kernels(data, weight, bias, silu, gated, begin), \
+        (data, weight, bias)
+
+
+def _conv_kernels_bwd(silu, gated, begin, saved, dy):
+    from .pallas_kernels import _causal_conv_bwd_impl
+    with _conv_scope(gated):
+        ddata, dw, dbias = _causal_conv_bwd_impl(*saved, dy, silu, gated,
+                                                 begin, False)
+        after = saved[0].shape[-1] - begin - ddata.shape[-1]
+        if begin or after:      # the channels the op did not read
+            ddata = jnp.pad(ddata, [(0, 0), (0, 0), (begin, after)])
+        return ddata, dw, dbias
+
+
+_conv_kernels.defvjp(_conv_kernels_fwd, _conv_kernels_bwd)
+
+
 def short_conv(data, weight):
     """The gated causal short convolution of one layer: data [B, S, 3C]
     holds (Bg, Cg, u) in that order, weight [C, K] is depthwise and has no
     bias: c_t = sum_j weight[:, j] (Bg u)_{t-K+1+j}, zeros before the
     sequence; returns Cg * c, [B, S, C].  The taps are ``causal_taps``,
-    which ``_contrib_CausalConv1D`` shares."""
-    with jax.named_scope("short_conv"):
-        bg, cg, u = jnp.split(data, 3, axis=-1)
-        return cg * causal_taps(bg * u, weight).astype(data.dtype)
+    which ``_contrib_CausalConv1D`` shares through ``sequence_conv``."""
+    with _conv_scope(True):
+        return sequence_conv(data, weight, gated=True)
 
 
 @register("_contrib_ShortConv", aliases=["ShortConv"])
@@ -234,21 +305,28 @@ def _short_conv(data, weight, **kw):
 
 @register("_contrib_CausalConv1D", aliases=["CausalConv1D"])
 def _causal_conv1d(data, weight, *maybe_bias, act_type="silu",
-                   no_bias=False, **kw):
+                   no_bias=False, begin=0, end=None, **kw):
     """Causal depthwise convolution along the sequence with an optional
-    bias and activation: data [B, S, C], weight [C, K], bias [C] ->
-    [B, S, C] = act(conv(data) + bias), float32 inside; ``act_type`` is
-    ``silu`` or None."""
+    bias and activation: data [B, S, W], weight [C, K], bias [C] ->
+    [B, S, C] = act(conv(data[..., begin:end]) + bias), float32 inside;
+    ``act_type`` is ``silu`` or None.  ``begin`` and ``end`` (0 and W by
+    default) name the channels of a wider tensor that are the op's input,
+    so that a projection's output need not be sliced first."""
     if act_type not in ("silu", None):
         raise ValueError("_contrib_CausalConv1D: act_type %r is not "
                          "implemented (silu and None are)" % (act_type,))
+    begin = int(begin)
+    end = data.shape[-1] if end is None else int(end)
+    if not 0 <= begin < end <= data.shape[-1] or \
+            end - begin != weight.shape[0]:
+        raise ValueError("_contrib_CausalConv1D: channels %d:%d of %d do "
+                         "not match a weight of %d channels"
+                         % (begin, end, data.shape[-1], weight.shape[0]))
     _tel.bump("causal_conv_traced")
-    with jax.named_scope("causal_conv"):
-        c = causal_taps(data, weight, None if no_bias or not maybe_bias
-                        else maybe_bias[0])
-        if act_type == "silu":
-            c = c * jax.nn.sigmoid(c)
-        return c.astype(data.dtype)
+    with _conv_scope(False):
+        return sequence_conv(
+            data, weight, None if no_bias or not maybe_bias
+            else maybe_bias[0], act_type == "silu", begin=begin)
 
 
 @register("_contrib_StateSpaceScan", aliases=["StateSpaceScan"])

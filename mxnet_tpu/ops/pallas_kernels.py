@@ -1522,3 +1522,267 @@ def _ssm_bwd(chunk, use_kernel, interpret, heads, res, dy):
 
 
 state_space_scan.defvjp(_ssm_fwd, _ssm_bwd)
+
+
+# ---------------------------------------------------------------------------
+# The sequence convolution (``ops/lm.py``: ``_contrib_CausalConv1D`` and
+# ``_contrib_ShortConv``): K taps along the sequence, depthwise, with what
+# each op does right before and after them, in one pass over the data.
+#
+# Channels on lanes, the sequence on sublanes, a program a (batch, channel
+# tile, sequence tile).  A tile is loaded once in the data's dtype and cast
+# to float32 in VMEM; the K - 1 tokens before it come as a second, small
+# block of the same operand (the ``_CONV_HALO`` rows that end where the
+# tile starts; zeros before the sequence), so no padded copy exists and the
+# forward's programs do not depend on each other.  The term of tap j,
+# x_{t-K+1+j}, is rows ``halo - (K-1-j) ..`` of [halo rows | tile]: a
+# sublane roll and an aligned slice (``_conv_window``).  The backward walks
+# the sequence tiles last to first: it remakes the pre-activation from x as
+# the forward made it, forms g = dy * act'(c), keeps the first rows of g in
+# scratch for the tile before (dx_t reads g_{t+1..t+K-1}), and gathers dw
+# and dbias over the sequence axis in a float32 block that stays in VMEM.
+# The gated op's Bg, Cg and u, and a plain op's input inside a wider tensor
+# (``begin``), are column blocks of the one operand in the block maps: no
+# split and no slice is materialised.
+#
+# Weight and bias reach the kernels as one float32 [16, C] block, rows
+# 0..K-1 the taps, row K the bias (zeros without one: adding them changes
+# nothing); the backward's dw and dbias leave in the same layout, a block a
+# batch row, summed over the batch outside.
+
+_CONV_ROWS = 512       # tokens a tile
+_CONV_LANES = 512      # most channels a tile
+_CONV_HALO = 16        # rows of the block before a tile: a bf16 sublane tile
+_CONV_PARAMS_ROWS = 16  # rows of the taps-and-bias block: K + 1 <= 9
+_CONV_VMEM_BYTES = 100 * 1024 * 1024
+
+
+def causal_conv_kernel_fits(seq, channels, taps):
+    """Whether the convolution kernels take these shapes: whole lane
+    tiles of channels, whole sequence tiles, taps inside the halo and
+    (with the bias) inside the parameters' block."""
+    return channels % _LANES == 0 and seq % _CONV_ROWS == 0 and \
+        1 <= taps <= 8
+
+
+def causal_conv_reads_in_place(channels, begin):
+    """Whether the kernels' block maps can start at column *begin* of a
+    wider tensor: at a whole channel tile.  Elsewhere they are handed a
+    slice."""
+    return begin % _conv_lanes(channels) == 0
+
+
+def _conv_lanes(channels):
+    """The widest channel tile up to ``_CONV_LANES`` that divides."""
+    return max(t for t in range(_LANES, min(channels, _CONV_LANES) + 1,
+                                _LANES) if channels % t == 0)
+
+
+def _conv_window(z, at, rows):
+    """z[at:at + rows] for any *at*: a sublane roll, then an aligned
+    slice."""
+    if at % 8 == 0:
+        return z[at:at + rows]
+    return pltpu.roll(z, np.int32(z.shape[0] - at), 0)[:rows]
+
+
+def _conv_operand(refs, gated, first):
+    """What the taps read, float32 [halo + rows, lanes] — the rows before
+    the tile (zeros where the tile is the sequence's *first*), then the
+    tile; for the gated op Bg * u, rounded as the data is — and the three
+    gates' tiles in float32 (None, None, None without gates)."""
+    f32 = jnp.float32
+    if gated:
+        bg_ref, cg_ref, u_ref, bg_halo, u_halo = refs
+        bg, u = bg_ref[:].astype(f32), u_ref[:].astype(f32)
+        cur = (bg * u).astype(bg_ref.dtype).astype(f32)
+        halo = (bg_halo[:].astype(f32) * u_halo[:].astype(f32)) \
+            .astype(bg_ref.dtype).astype(f32)
+        gates = (bg, cg_ref[:].astype(f32), u)
+    else:
+        x_ref, x_halo = refs
+        cur, halo = x_ref[:].astype(f32), x_halo[:].astype(f32)
+        gates = (None, None, None)
+    halo = jnp.where(first, jnp.zeros_like(halo), halo)
+    return jnp.concatenate([halo, cur], axis=0), gates
+
+
+def _conv_preact(z, wb_ref, taps, rows):
+    """(the K shifted terms of the tile's rows, their weighted sum plus
+    the bias)."""
+    terms = [_conv_window(z, _CONV_HALO - (taps - 1 - j), rows)
+             for j in range(taps)]
+    c = terms[0] * wb_ref[0:1, :]
+    for j in range(1, taps):
+        c = c + terms[j] * wb_ref[j:j + 1, :]
+    return terms, c + wb_ref[taps:taps + 1, :]
+
+
+def _conv_fwd_kernel(*refs, taps, silu, gated):
+    *ins, wb_ref, o_ref = refs
+    f32 = jnp.float32
+    z, (_, cg, _) = _conv_operand(ins, gated, pl.program_id(2) == 0)
+    _, c = _conv_preact(z, wb_ref, taps, o_ref.shape[0])
+    if silu:
+        c = c * jax.nn.sigmoid(c)
+    out = c.astype(o_ref.dtype)
+    if gated:
+        out = (cg * out.astype(f32)).astype(o_ref.dtype)
+    o_ref[:] = out
+
+
+def _conv_bwd_kernel(*refs, taps, silu, gated, tiles):
+    """One (batch, channel tile, sequence tile, part) program of the
+    backward, the sequence tiles last to first.  Part 0 does the work and
+    writes dx (dBg for the gated op, whose dCg and du wait in scratch and
+    are copied out by parts 1 and 2 into their column blocks of the one
+    [B, S, 3C] gradient)."""
+    f32 = jnp.float32
+    n_in = 5 if gated else 2
+    ins, (dy_ref, wb_ref, dx_ref, acc_ref, g_head), held = \
+        refs[:n_in], refs[n_in:n_in + 5], refs[n_in + 5:]
+    step, part = pl.program_id(2), pl.program_id(3)
+    rows, cd = dx_ref.shape[0], dx_ref.dtype
+
+    @pl.when(part == 0)
+    def _work():
+        @pl.when(step == 0)
+        def _start():
+            acc_ref[:] = jnp.zeros_like(acc_ref)
+            g_head[:] = jnp.zeros_like(g_head)
+
+        z, (bg, cg, u) = _conv_operand(
+            ins, gated, step == np.int32(tiles - 1))
+        terms, c = _conv_preact(z, wb_ref, taps, rows)
+        g = dy_ref[:].astype(f32)
+        if gated:
+            a = c * jax.nn.sigmoid(c) if silu else c
+            held[0][:] = (g * a.astype(cd).astype(f32)).astype(cd)
+            g = (g * cg).astype(cd).astype(f32)
+        if silu:
+            sig = jax.nn.sigmoid(c)
+            g = g * (sig * (np.float32(1.0) + c * (np.float32(1.0) - sig)))
+        for j in range(taps):
+            acc_ref[j:j + 1, :] += jnp.sum(g * terms[j], axis=0,
+                                           keepdims=True)
+        acc_ref[taps:taps + 1, :] += jnp.sum(g, axis=0, keepdims=True)
+        zg = jnp.concatenate([g, g_head[:]], axis=0)
+        g_head[:] = g[:_CONV_HALO]
+        dx = _conv_window(zg, taps - 1, rows) * wb_ref[0:1, :]
+        for j in range(1, taps):
+            dx = dx + _conv_window(zg, taps - 1 - j, rows) * \
+                wb_ref[j:j + 1, :]
+        if gated:
+            dx = dx.astype(cd).astype(f32)
+            held[1][:] = (dx * bg).astype(cd)
+            dx = dx * u
+        dx_ref[:] = dx.astype(cd)
+
+    for n, ref in enumerate(held):
+        @pl.when(part == np.int32(n + 1))
+        def _copy(ref=ref):
+            dx_ref[:] = ref[:]
+
+
+def _conv_specs(rows, lanes, blocks, gated, seq_tile, first):
+    """Block specs of the data's tiles, the halos before them, the
+    parameters' block and a [B, S, C] tensor's tiles, over a grid whose
+    first three axes are (batch, channel tile, sequence step); *seq_tile*
+    maps a step to its tile, *blocks* is the channel tiles a gate (Bg, Cg
+    and u are column blocks 0, 1 and 2 of the gated op's data), *first*
+    the channel tile of the data's tensor at which the input starts."""
+    per = rows // _CONV_HALO
+
+    def tile(at):
+        return pl.BlockSpec(
+            (None, rows, lanes), lambda b, c, j, *_: (
+                b, seq_tile(j), c + np.int32(at)))
+
+    def halo(at):
+        return pl.BlockSpec(
+            (None, _CONV_HALO, lanes), lambda b, c, j, *_: (
+                b, jnp.maximum(seq_tile(j) * np.int32(per) - np.int32(1),
+                               np.int32(0)), c + np.int32(at)))
+
+    data = [tile(0), tile(blocks), tile(2 * blocks), halo(0),
+            halo(2 * blocks)] if gated else [tile(first), halo(first)]
+    params = pl.BlockSpec((_CONV_PARAMS_ROWS, lanes),
+                          lambda b, c, *_: (np.int32(0), c))
+    return data, params, tile(0)
+
+
+def _conv_params(weight, bias):
+    """float32 [16, C]: rows 0..K-1 the taps, row K the bias."""
+    channels, taps = weight.shape
+    f32 = jnp.float32
+    return jnp.concatenate([
+        weight.astype(f32).T, bias.astype(f32)[None, :],
+        jnp.zeros((_CONV_PARAMS_ROWS - taps - 1, channels), f32)], axis=0)
+
+
+def _causal_conv_fwd_impl(data, weight, bias, silu, gated, begin,
+                          interpret):
+    """The forward in Pallas: data [B, S, W] whose channels begin ..
+    begin + C are the input (gated: [B, S, 3C] holding Bg, Cg, u), weight
+    [C, K], bias [C] -> [B, S, C] in the data's dtype."""
+    rows = _CONV_ROWS
+    bsz, seq = data.shape[:2]
+    channels, taps = weight.shape
+    lanes = _conv_lanes(channels)
+    specs, params, out = _conv_specs(rows, lanes, channels // lanes, gated,
+                                     lambda j: j, begin // lanes)
+    return pl.pallas_call(
+        functools.partial(_conv_fwd_kernel, taps=taps, silu=silu,
+                          gated=gated),
+        grid=(bsz, channels // lanes, seq // rows),
+        in_specs=specs + [params], out_specs=out,
+        out_shape=jax.ShapeDtypeStruct((bsz, seq, channels), data.dtype),
+        name="causal_conv_fwd", interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel"),
+            vmem_limit_bytes=_CONV_VMEM_BYTES),
+    )(*[data] * len(specs), _conv_params(weight, bias))
+
+
+def _causal_conv_bwd_impl(data, weight, bias, dy, silu, gated, begin,
+                          interpret):
+    """The backward in Pallas: -> (d input [B, S, C] (gated: d data
+    [B, S, 3C]), d weight, d bias), reading the forward's operands and dy
+    once."""
+    rows = _CONV_ROWS
+    bsz, seq = data.shape[:2]
+    channels, taps = weight.shape
+    lanes = _conv_lanes(channels)
+    blocks, tiles = channels // lanes, seq // rows
+    parts = 3 if gated else 1
+
+    def seq_tile(j):
+        return np.int32(tiles - 1) - j
+
+    specs, params, like_out = _conv_specs(rows, lanes, blocks, gated,
+                                          seq_tile, begin // lanes)
+    grad = pl.BlockSpec(
+        (None, rows, lanes), lambda b, c, j, part: (
+            b, seq_tile(j), part * np.int32(blocks) + c))
+    acc = pl.BlockSpec((None, _CONV_PARAMS_ROWS, lanes),
+                       lambda b, c, j, part: (b, np.int32(0), c))
+    ddata, sums = pl.pallas_call(
+        functools.partial(_conv_bwd_kernel, taps=taps, silu=silu,
+                          gated=gated, tiles=tiles),
+        grid=(bsz, blocks, tiles, parts),
+        in_specs=specs + [like_out, params], out_specs=[grad, acc],
+        out_shape=[jax.ShapeDtypeStruct((bsz, seq, parts * channels),
+                                        data.dtype),
+                   jax.ShapeDtypeStruct((bsz, _CONV_PARAMS_ROWS, channels),
+                                        jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((_CONV_HALO, lanes), jnp.float32)] +
+        [pltpu.VMEM((rows, lanes), data.dtype)] * (parts - 1),
+        name="causal_conv_bwd", interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary",
+                                 "arbitrary"),
+            vmem_limit_bytes=_CONV_VMEM_BYTES),
+    )(*[data] * len(specs), dy, _conv_params(weight, bias))
+    sums = sums.sum(0)
+    return ddata, sums[:taps].T.astype(weight.dtype), \
+        sums[taps].astype(bias.dtype)

@@ -176,9 +176,12 @@ def infer_param_shapes(node, in_structs):
     elif name == "_contrib_ShortConv":
         out[1] = S((dshape[-1] // 3, int(a.get("kernel", 3))))
     elif name == "_contrib_CausalConv1D":
-        out[1] = S((dshape[-1], int(a.get("kernel", 4))))
+        end = a.get("end")
+        channels = (dshape[-1] if end is None else int(end)) - \
+            int(a.get("begin", 0))
+        out[1] = S((channels, int(a.get("kernel", 4))))
         if len(in_structs) > 2:
-            out[2] = S((dshape[-1],))
+            out[2] = S((channels,))
     elif name == "_contrib_BlockedSoftmaxCE":
         out[1] = S((int(a.get("num_hidden")), dshape[-1]))
         out[2] = jax.ShapeDtypeStruct(dshape[:-1], np.float32)

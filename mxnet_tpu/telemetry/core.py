@@ -595,6 +595,12 @@ COUNTERS = {
                                "sliding window (banded kernels on a TPU)",
     "short_conv_traced": "_contrib_ShortConv ops traced",
     "causal_conv_traced": "_contrib_CausalConv1D ops traced",
+    "causal_conv_kernel_traced": "_contrib_CausalConv1D and "
+                                 "_contrib_ShortConv ops traced on the "
+                                 "Pallas path (causal_conv_fwd / "
+                                 "causal_conv_bwd: a TPU, channels in "
+                                 "whole lane tiles, the sequence in whole "
+                                 "tiles); the others run the jnp form",
     "state_space_traced": "_contrib_StateSpaceScan ops traced (the chunked "
                           "dual form)",
     "state_space_chunks": "chunks a sequence over all traced "

@@ -102,7 +102,7 @@ def test_pallas_tier_compiles_for_v5e_without_a_chip():
         pytest.skip(proc.stdout.strip().splitlines()[-1])
     assert proc.returncode == 0, proc.stdout[-2000:] + proc.stderr[-4000:]
     done = [ln for ln in proc.stdout.splitlines() if ln.startswith("AOT ok")]
-    assert len(done) == 19, done
+    assert len(done) == 22, done
 
 
 @pytest.mark.slow

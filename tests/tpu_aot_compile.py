@@ -179,6 +179,31 @@ def main():
         print("AOT ok state_space_scan grad=%s: %s" % (grad, kernels),
               flush=True)
 
+    # the sequence convolution at both cells' widths through the graph
+    # ops' own function: Granite's (4,352 channels of in_proj's 8,512 read
+    # at column 4,096, 4 taps, bias, SiLU, one document of 16,384) and
+    # LFM2's (gated, 2,048 channels in a [2, 8192, 6144] input, 3 taps),
+    # forward and backward kernels, and no slice of the wide input left
+    # beside them; then Granite's with the channels at column 64, inside
+    # a channel tile, where the kernels stay and are handed a slice
+    lm._kernel_backend = lambda: True
+    for name, data, weight, bias, kw in (
+            ("granite", spec(1, 16384, 8512), spec(4352, 4), spec(4352),
+             dict(silu=True, begin=4096)),
+            ("lfm2", spec(2, 8192, 6144), spec(2048, 3), None,
+             dict(gated=True)),
+            ("granite at column 64", spec(1, 16384, 8512), spec(4352, 4),
+             spec(4352), dict(silu=True, begin=64))):
+        def conv(d, w, *b, kw=kw):
+            return lm.sequence_conv(d, w, *b, **kw).astype(jnp.float32).sum()
+        args = (data, weight) + (() if bias is None else (bias,))
+        text = jax.jit(jax.value_and_grad(conv, tuple(range(len(args))))) \
+            .lower(*args).compile().as_text()
+        assert "causal_conv_fwd" in text and "%causal_conv_bwd" in text
+        assert (" slice(" in text.split("ENTRY")[1]) == \
+            bool(kw.get("begin", 0) % 256), name
+        print("AOT ok sequence convolution %s grad" % name, flush=True)
+
     # the kernel from mx.pallas's docstring, through the op registry
     # (same kernel and helper the interpret-mode tests use)
     from test_pallas_register import _register_scale, _registered_fn
