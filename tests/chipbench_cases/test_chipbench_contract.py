@@ -1,6 +1,8 @@
 """BENCHMARK.json against the contract's limits, the files it names, the
 yardstick's own arithmetic (trace reduction, FLOP counts, peaks, the rule)
 and the device gate of run.py."""
+import copy
+import importlib
 import json
 import os
 import re
@@ -25,43 +27,43 @@ def load(*parts):
 BENCH = load("BENCHMARK.json")
 
 
-def test_benchmark_json_keeps_the_contract():
-    assert set(BENCH) == {"command", "paths", "run_seconds", "configs",
+def keeps_the_contract(bench):
+    assert set(bench) == {"command", "paths", "run_seconds", "configs",
                           "workloads", "end_to_end", "per_layer"}
-    assert BENCH["paths"] == ["chipbench", "tests/chipbench_cases"]
-    assert 1 <= BENCH["run_seconds"] <= 51
+    assert bench["paths"] == ["chipbench", "tests/chipbench_cases"]
+    assert 1 <= bench["run_seconds"] <= 51
     # a full check with all 24 cells must fit the driver's limit
-    assert (2 + 14 * 24) * (BENCH["run_seconds"] + 60) + 24 * 180 + 1200 \
+    assert (2 + 14 * 24) * (bench["run_seconds"] + 60) + 24 * 180 + 1200 \
         <= 43200
-    metrics = BENCH["end_to_end"] + BENCH["per_layer"]
+    metrics = bench["end_to_end"] + bench["per_layer"]
     names = [e["name"] for k in ("configs", "workloads")
-             for e in BENCH[k]] + [m["name"] for m in metrics]
-    for name in names + [w[k] for w in BENCH["workloads"]
+             for e in bench[k]] + [m["name"] for m in metrics]
+    for name in names + [w[k] for w in bench["workloads"]
                          for k in ("config", "traffic")]:
         assert NAME.match(name), name
-    for group in (BENCH["configs"], BENCH["workloads"], metrics):
+    for group in (bench["configs"], bench["workloads"], metrics):
         got = [e["name"] for e in group]
         assert len(got) == len(set(got)), got
-    cells = {w["name"] for w in BENCH["workloads"]}
-    assert len({(w["config"], w["traffic"]) for w in BENCH["workloads"]}) \
+    cells = {w["name"] for w in bench["workloads"]}
+    assert len({(w["config"], w["traffic"]) for w in bench["workloads"]}) \
         == len(cells)
-    assert sum(w["chips"] == 4 for w in BENCH["workloads"]) <= \
+    assert sum(w["chips"] == 4 for w in bench["workloads"]) <= \
         max(1, len(cells) // 4)
-    assert {c["name"] for c in BENCH["configs"]} == \
-        {w["config"] for w in BENCH["workloads"]}
-    e2e = {m["name"]: m for m in BENCH["end_to_end"]}
+    assert {c["name"] for c in bench["configs"]} == \
+        {w["config"] for w in bench["workloads"]}
+    e2e = {m["name"]: m for m in bench["end_to_end"]}
     assert "setup_s" in e2e and "workloads" not in e2e["setup_s"]
     for m in metrics:
         assert UNIT.match(m["unit"]), m
         assert m["better"] in ("lower", "higher")
         assert set(m.get("workloads", cells)) <= cells and \
             m.get("workloads", cells), m
-    for m in BENCH["end_to_end"]:
+    for m in bench["end_to_end"]:
         assert set(m) <= {"name", "unit", "better", "bound", "source",
                           "workloads"}
         assert m["source"] in ("host_clock", "device_trace")
         assert 0.01 <= m["bound"] <= 0.1
-    for m in BENCH["per_layer"]:
+    for m in bench["per_layer"]:
         assert set(m) <= {"name", "unit", "better", "source", "layer",
                           "moves", "workloads"}
         assert m["source"] in ("device_trace", "program_span",
@@ -74,9 +76,62 @@ def test_benchmark_json_keeps_the_contract():
             assert m["source"] == "device_trace"
     for cell in cells:         # setup_s, one more end-to-end, one per-layer
         assert any(cell in m.get("workloads", cells) and m["name"] !=
-                   "setup_s" for m in BENCH["end_to_end"]), cell
+                   "setup_s" for m in bench["end_to_end"]), cell
         assert any(cell in m.get("workloads", cells)
-                   for m in BENCH["per_layer"]), cell
+                   for m in bench["per_layer"]), cell
+
+
+def test_benchmark_json_keeps_the_contract():
+    keeps_the_contract(BENCH)
+
+
+LATER = "toy_model.fit"
+
+
+def with_a_later_cell(bench):
+    """A copy of *bench* with what a later ``model_config`` PR adds
+    without editing a file that is there: a configuration and its cell,
+    the cell's name appended to every ``workloads`` list (the shared
+    ``.fit``, ``.setup`` and kernel metrics, and the rate it reports),
+    and one per-layer entry of its own after the last."""
+    bench = copy.deepcopy(bench)
+    bench["configs"].append({
+        "name": "toy_model", "source": "a toy configuration",
+        "file": "chipbench/configs/toy_model.json", "reduced": [],
+        "why": "a later configuration"})
+    bench["workloads"].append({
+        "name": LATER, "config": "toy_model", "traffic": "fit_lm_16k",
+        "chips": 1, "why": "a later cell"})
+    for m in bench["end_to_end"] + bench["per_layer"]:
+        if "workloads" in m:
+            m["workloads"].append(LATER)
+    bench["per_layer"].append({
+        "name": "early_router_ms.fit", "unit": "ms", "better": "lower",
+        "source": "device_trace", "layer": "Sparse-expert layer",
+        "moves": "train_items_s_per_chip", "workloads": [LATER]})
+    return bench
+
+
+OWN_CHECKS = {
+    "contract": (__name__, "keeps_the_contract"),
+    "setup": ("test_chipbench_setup_metrics",
+              "setup_entries_keep_their_form"),
+    "lfm2": ("test_chipbench_lfm2", "cell_and_metrics_are_listed"),
+    "trinity": ("test_chipbench_trinity", "cell_and_metrics_are_listed"),
+    "granite": ("test_chipbench_granite", "cell_and_metrics_are_listed")}
+
+
+@pytest.mark.parametrize("owner", sorted(OWN_CHECKS))
+def test_a_later_cell_and_metric_can_be_appended(owner):
+    """Every configuration's own checks of ``BENCHMARK.json`` pass on the
+    file as it is and on the file with a later cell and metric appended:
+    a ``model_config`` PR appends and edits no test (PR 37 could not)."""
+    module, fn = OWN_CHECKS[owner]
+    check = getattr(importlib.import_module(module), fn)
+    check(BENCH)
+    later = with_a_later_cell(BENCH)
+    assert len(later["per_layer"]) == len(BENCH["per_layer"]) + 1
+    check(later)
 
 
 def test_every_named_file_exists_and_agrees():

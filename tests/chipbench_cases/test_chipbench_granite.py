@@ -303,44 +303,42 @@ def test_state_space_readers():
     assert share.read(ctx) is None
 
 
-def test_benchmark_json_has_the_cell():
+def cell_and_metrics_are_listed(bench):
     """Only what this configuration owns: later cells and metrics may be
-    appended to ``BENCHMARK.json`` without an edit here.  The two
-    state-space readers have no entry yet (PERF.md section 7): a PR that
-    changes the program may only append to ``per_layer``, and
-    ``test_chipbench_setup_metrics.py`` holds the five ``.setup`` entries
-    to be its last."""
-    cell = [w for w in BENCH["workloads"]
+    appended to ``BENCHMARK.json``, and later cells to the shared
+    entries' ``workloads``, without an edit here."""
+    cell = [w for w in bench["workloads"]
             if w["config"] == "granite_4_0_h_micro"]
     assert CELL in [w["name"] for w in cell]
     fit = [w for w in cell if w["name"] == CELL][0]
     assert fit["chips"] == 1 and fit["traffic"] == "fit_lm_16k"
-    config = [c for c in BENCH["configs"]
+    config = [c for c in bench["configs"]
               if c["name"] == "granite_4_0_h_micro"][0]
     for why in (fit["why"], config["why"]):     # the driver's rule of form
         assert 1 <= len(why) <= 200 and why.isprintable()
-    mine = {m["name"]: m for m in BENCH["per_layer"]
+    mine = {m["name"]: m for m in bench["per_layer"]
             if CELL in m.get("workloads", [])}
     assert {"dispatch_ms.fit", "step_device_ms.fit", "busy_mfu_pct.fit",
             "device_idle_pct.fit", "step_prepare_ms.fit",
-            "step_enqueue_ms.fit", "metric_host_ms.fit"} <= set(mine)
+            "step_enqueue_ms.fit", "metric_host_ms.fit", "attention_ms.fit",
+            "causal_conv_ms.fit", "causal_conv_roofline_pct.fit"} <= \
+        set(mine)
     for name, better in (("state_space_ms.fit", "lower"),
                          ("state_space_roofline_pct.fit", "higher")):
         assert os.path.exists(os.path.join(
             REPO, "chipbench", "layer_metrics", name + ".py"))
-        for m in BENCH["per_layer"]:        # once a benchmark PR lists them
-            if m["name"] == name:
-                assert (m["source"], m["layer"], m["moves"], m["better"],
-                        m["workloads"][0]) == (
-                    "device_trace", "State-space kernel",
-                    "train_items_s_per_chip", better, CELL)
-    # no other cell's kernel metric and no set-up metric lists the cell
-    assert not any(n.startswith(("attention_", "moe_", "retention_",
-                                 "window_attention_")) or
-                   n.endswith(".setup") for n in mine)
-    rate = [m for m in BENCH["end_to_end"]
+        m = mine[name]
+        assert (m["source"], m["layer"], m["moves"], m["better"],
+                m["workloads"][0]) == (
+            "device_trace", "State-space kernel",
+            "train_items_s_per_chip", better, CELL)
+    rate = [m for m in bench["end_to_end"]
             if m["name"] == "train_items_s_per_chip"][0]
     assert CELL in rate["workloads"]
+
+
+def test_benchmark_json_has_the_cell():
+    cell_and_metrics_are_listed(BENCH)
 
 
 TOY_TRAFFIC = dict(seq_len=40, sequences_per_step=2, batch_per_chip=80,
@@ -362,9 +360,6 @@ def toy_root(tmp_path):
         json.dumps(tr))
     layers = [m["name"] for m in BENCH["per_layer"]
               if CELL in m.get("workloads", [])]
-    layers += [n for n in ("state_space_ms.fit",
-                           "state_space_roofline_pct.fit")
-               if n not in layers]          # unlisted yet; read all the same
     bench = {
         "configs": [{"name": "toy", "file": "chipbench/configs/toy.json"}],
         "workloads": [{"name": "toy.cell", "config": "toy",

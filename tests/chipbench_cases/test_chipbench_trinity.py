@@ -269,29 +269,39 @@ def test_window_attention_readers():
     assert share.read(ctx) is None
 
 
-def test_benchmark_json_has_the_cell_and_its_two_metrics():
+def cell_and_metrics_are_listed(bench):
     """Only what this configuration owns: later cells and metrics may be
-    appended to ``BENCHMARK.json`` without an edit here."""
-    cell = [w for w in BENCH["workloads"] if w["config"] == "trinity_mini"]
+    appended to ``BENCHMARK.json``, and later cells to the shared
+    entries' ``workloads``, without an edit here."""
+    cell = [w for w in bench["workloads"] if w["config"] == "trinity_mini"]
     assert "trinity_mini.fit" in [w["name"] for w in cell]
     fit = [w for w in cell if w["name"] == "trinity_mini.fit"][0]
     assert fit["chips"] == 1 and fit["traffic"] == "fit_lm_16k"
-    config = [c for c in BENCH["configs"] if c["name"] == "trinity_mini"][0]
+    config = [c for c in bench["configs"] if c["name"] == "trinity_mini"][0]
     for why in (fit["why"], config["why"]):     # the driver's rule of form
         assert 1 <= len(why) <= 200 and why.isprintable()
-    mine = {m["name"]: m for m in BENCH["per_layer"]
+    mine = {m["name"]: m for m in bench["per_layer"]
             if "trinity_mini.fit" in m.get("workloads", [])}
     assert {"dispatch_ms.fit", "step_device_ms.fit", "busy_mfu_pct.fit",
             "device_idle_pct.fit", "step_prepare_ms.fit",
             "step_enqueue_ms.fit", "metric_host_ms.fit"} <= set(mine)
-    for name in ("window_attention_ms.fit",
-                 "window_attention_roofline_pct.fit"):
+    for name, layer in (("window_attention_ms.fit", "Attention kernel"),
+                        ("window_attention_roofline_pct.fit",
+                         "Attention kernel"),
+                        ("attention_ms.fit", "Attention kernel"),
+                        ("attention_roofline_pct.fit", "Attention kernel"),
+                        ("moe_ms.fit", "Sparse-expert layer"),
+                        ("moe_roofline_pct.fit", "Sparse-expert layer")):
         m = mine[name]
         assert (m["source"], m["layer"], m["moves"]) == (
-            "device_trace", "Attention kernel", "train_items_s_per_chip")
-    rate = [m for m in BENCH["end_to_end"]
+            "device_trace", layer, "train_items_s_per_chip")
+    rate = [m for m in bench["end_to_end"]
             if m["name"] == "train_items_s_per_chip"][0]
     assert "trinity_mini.fit" in rate["workloads"]
+
+
+def test_benchmark_json_has_the_cell_and_its_two_metrics():
+    cell_and_metrics_are_listed(BENCH)
 
 
 TOY_TRAFFIC = dict(seq_len=24, sequences_per_step=2, batch_per_chip=48,
