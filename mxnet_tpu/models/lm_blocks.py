@@ -1,8 +1,8 @@
-"""Graph pieces the language models share (``lfm2.py``, ``trinity.py``
-and ``granite.py``): bias-free projections, per-head reshapes, the SwiGLU
-MLP with separate or one fused input matrix, stacked expert tensors, the
-sparse-expert op with its variables, the one-segment-a-layer
-recomputation scope and the probe outputs."""
+"""Graph pieces the language models share (``lfm2.py``, ``trinity.py``,
+``granite.py`` and ``smallthinker.py``): bias-free projections, per-head
+reshapes, the SwiGLU MLP with separate or one fused input matrix, stacked
+expert tensors, the sparse-expert op with its variables, the
+one-segment-a-layer recomputation scope and the probe outputs."""
 from __future__ import annotations
 
 from .. import attribute, initializer
@@ -41,15 +41,15 @@ def stacked(name, shape, dtype):
 
 
 def sparse_experts(h, pre, dtype, hidden, width, num_experts, held=None,
-                   **attrs):
+                   route=None, selection_bias=True, **attrs):
     """(the held experts' part of the layer's result, the chosen expert
-    ids) of ``_contrib_SparseMoE`` on h: a router over *num_experts*, the
-    selection bias as a float32 auxiliary state drawn uniformly in
-    +-0.1, and *held* (all, if None) stacked experts of *width*;
-    *attrs* go to the op."""
+    ids) of ``_contrib_SparseMoE`` on h: a router over *num_experts* that
+    reads *route* (h, if None), the selection bias as a float32 auxiliary
+    state drawn uniformly in +-0.1 unless not *selection_bias*, and *held*
+    (all, if None) stacked experts of *width*; *attrs* go to the op
+    (``act_type="relu"`` for ReGLU experts)."""
     held = num_experts if held is None else held
-    moe = S.contrib.SparseMoE(
-        h,
+    inputs = dict(
         router_weight=S.Variable(pre + "router_weight",
                                  shape=(num_experts, hidden), dtype=dtype),
         w1_weight=stacked(pre + "experts_w1_weight", (held, hidden, width),
@@ -57,11 +57,18 @@ def sparse_experts(h, pre, dtype, hidden, width, num_experts, held=None,
         w3_weight=stacked(pre + "experts_w3_weight", (held, hidden, width),
                           dtype),
         w2_weight=stacked(pre + "experts_w2_weight", (held, width, hidden),
-                          dtype),
-        expert_bias=S.Variable(pre + "expert_bias", shape=(num_experts,),
-                               dtype="float32",
-                               init=initializer.Uniform(0.1)),
-        num_experts=num_experts, name=pre + "moe", **attrs)
+                          dtype))
+    if route is not None:
+        inputs["route_data"] = route
+        attrs["route_apart"] = True
+    if selection_bias:
+        inputs["expert_bias"] = S.Variable(
+            pre + "expert_bias", shape=(num_experts,), dtype="float32",
+            init=initializer.Uniform(0.1))
+    else:
+        attrs["no_bias"] = True
+    moe = S.contrib.SparseMoE(h, **inputs, num_experts=num_experts,
+                              name=pre + "moe", **attrs)
     return moe[0], moe[1]
 
 

@@ -32,6 +32,12 @@ activations are kept for the backward, a later chunk's are computed
 again there.  The combine still reads ``tokens x k`` row indices (k
 gathers of ``[tokens, H]``).  With every expert held there is one chunk
 of ``tokens x k`` rows and no mask: the program PR 31 measured.
+
+The router may read another tensor than the experts do (a family that
+routes from the attention's input), and the experts' gate is SiLU
+(SwiGLU) or ReLU (ReGLU): one routing routine and one expert machine for
+all of them, the activation a static argument of the hand-derived
+backward.
 """
 from __future__ import annotations
 
@@ -121,11 +127,29 @@ def _grouped_outer(lhs, rhs, sizes, dtype):
                                       preferred_element_type=dtype)
 
 
-def _gate(h1, h3):
-    """(silu(h1) * h3, sigmoid(h1)) in float32."""
+_ACTS = ("silu", "relu")
+
+
+def _gate(h1, h3, act):
+    """(act(h1) * h3, what the gate's derivative reads of h1) in float32:
+    sigmoid(h1) for SiLU (SwiGLU), the mask h1 > 0 for ReLU (ReGLU)."""
     h1, h3 = h1.astype(jnp.float32), h3.astype(jnp.float32)
+    if act == "relu":
+        on = h1 > 0
+        return jnp.where(on, h1, 0.0) * h3, on
     sig = jax.nn.sigmoid(h1)
     return h1 * sig * h3, sig
+
+
+def _gate_grads(da, h1, h3, act, d, dt):
+    """(dh1, dh3) in *dt* from the cotangent *da* of act(h1) * h3, with *d*
+    what ``_gate`` returned beside it."""
+    h1f, h3f = h1.astype(jnp.float32), h3.astype(jnp.float32)
+    if act == "relu":
+        return (jnp.where(d, da * h3f, 0.0).astype(dt),
+                (da * jnp.where(d, h1f, 0.0)).astype(dt))
+    return ((da * h3f * d * (1.0 + h1f * (1.0 - d))).astype(dt),
+            (da * h1f * d).astype(dt))
 
 
 _SHARE_ROOM = 1.5       # a chunk of a partial share: this x the expected rows
@@ -141,17 +165,18 @@ def _share_rows(rows, held, num_experts):
     return min(rows, max(want, unit))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(8,))
-def _experts(x, w1, w3, w2, weight, order, inverse, sizes, chunk):
-    """sum over a token's k choices of weight * (silu(h W1_e) * (h W3_e))
-    W2_e.  x [T, H]; weight [T, k] float32; *order* [T k]: the flat
-    (token, choice) pair at each row of the expert-sorted order, *inverse*
-    its inverse; *sizes* [G]: rows of each held expert.  *chunk* is None
-    when every expert is held.  Otherwise the rows past sum(sizes) belong
-    to experts held elsewhere and add nothing, and the sorted order is
-    worked through *chunk* rows at a time, as far as sum(sizes) reaches."""
+@functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9))
+def _experts(x, w1, w3, w2, weight, order, inverse, sizes, chunk, act):
+    """sum over a token's k choices of weight * (act(h W1_e) * (h W3_e))
+    W2_e, *act* ``silu`` or ``relu``.  x [T, H]; weight [T, k] float32;
+    *order* [T k]: the flat (token, choice) pair at each row of the
+    expert-sorted order, *inverse* its inverse; *sizes* [G]: rows of each
+    held expert.  *chunk* is None when every expert is held.  Otherwise the
+    rows past sum(sizes) belong to experts held elsewhere and add nothing,
+    and the sorted order is worked through *chunk* rows at a time, as far
+    as sum(sizes) reaches."""
     return _experts_fwd(x, w1, w3, w2, weight, order, inverse, sizes,
-                        chunk)[0]
+                        chunk, act)[0]
 
 
 def _rows(x, index):
@@ -215,7 +240,7 @@ def _combine(y, inverse, k, chunk=None, start=0):
                for j in range(k))
 
 
-def _chunk_fwd(x, w1, w3, w2, weight, order, sizes, chunk, start=0):
+def _chunk_fwd(x, w1, w3, w2, weight, order, sizes, chunk, act, start=0):
     """(the chunk's rows of the last product, what its backward reads,
     where the chunk stops)."""
     rows, stop = _sorted_rows(x, weight, order, sizes, chunk, start)
@@ -224,7 +249,7 @@ def _chunk_fwd(x, w1, w3, w2, weight, order, sizes, chunk, start=0):
     h3 = _grouped(xs, w3, sizes, live)
     # the combine's weight goes in before the last product, which is
     # linear in its rows: the backward then needs no copy of its output
-    aw = (_gate(h1, h3)[0] * ws[:, None]).astype(x.dtype)
+    aw = (_gate(h1, h3, act)[0] * ws[:, None]).astype(x.dtype)
     return _grouped(aw, w2, sizes, live), rows + (h1, h3), stop
 
 
@@ -240,15 +265,15 @@ def _later_chunks(chunk, order, sizes, stop, body, init):
                               lambda state: body(*state), (stop, init))[1]
 
 
-def _experts_fwd(x, w1, w3, w2, weight, order, inverse, sizes, chunk):
+def _experts_fwd(x, w1, w3, w2, weight, order, inverse, sizes, chunk, act):
     k = weight.shape[1]
     with jax.named_scope("moe_experts_fwd"):
         y, kept, stop = _chunk_fwd(x, w1, w3, w2, weight, order, sizes,
-                                   chunk)
+                                   chunk, act)
 
         def more(start, out):
             y, _, stop = _chunk_fwd(x, w1, w3, w2, weight, order, sizes,
-                                    chunk, start)
+                                    chunk, act, start)
             return stop, out + _combine(y, inverse, k, chunk, start)
 
         out = _later_chunks(chunk, order, sizes, stop, more,
@@ -257,19 +282,17 @@ def _experts_fwd(x, w1, w3, w2, weight, order, inverse, sizes, chunk):
                                  sizes)
 
 
-def _chunk_bwd(dout, kept, w1, w3, w2, inverse, k, chunk, start=0):
+def _chunk_bwd(dout, kept, w1, w3, w2, inverse, k, chunk, act, start=0):
     """One chunk's part of (dx [T, H] float32, dw1, dw3, dw2, dweight
     [T k] float32) from what ``_chunk_fwd`` kept."""
     xs, ws, live, sizes, head, h1, h3 = kept
     dt = xs.dtype
     g = _rows(dout.astype(dt), head // k)
-    a, sig = _gate(h1, h3)
+    a, d = _gate(h1, h3, act)
     daw = _grouped(g, w2, sizes, live, transpose=True).astype(jnp.float32)
     dws = jnp.sum(daw * a, axis=-1)
     da = daw * ws[:, None]
-    h1f, h3f = h1.astype(jnp.float32), h3.astype(jnp.float32)
-    dh1 = (da * h3f * sig * (1.0 + h1f * (1.0 - sig))).astype(dt)
-    dh3 = (da * h1f * sig).astype(dt)
+    dh1, dh3 = _gate_grads(da, h1, h3, act, d, dt)
     dxs = (_grouped(dh1, w1, sizes, live, transpose=True)
            .astype(jnp.float32) +
            _grouped(dh3, w3, sizes, live, transpose=True)
@@ -281,7 +304,7 @@ def _chunk_bwd(dout, kept, w1, w3, w2, inverse, k, chunk, start=0):
             _from_chunk(dws, inverse, chunk, start))
 
 
-def _experts_bwd(chunk, res, dout):
+def _experts_bwd(chunk, act, res, dout):
     x, weight, kept, w1, w3, w2, order, inverse, sizes = res
     k = weight.shape[1]
     with jax.named_scope("moe_experts_bwd"):
@@ -292,9 +315,9 @@ def _experts_bwd(chunk, res, dout):
         # larger than a whole chunk meet, in float32
         def more(start, grads):
             _, kept, stop = _chunk_fwd(x, w1, w3, w2, weight, order, sizes,
-                                       chunk, start)
+                                       chunk, act, start)
             part = _chunk_bwd(dout, kept, w1, w3, w2, inverse, k, chunk,
-                              start)
+                              act, start)
             return stop, tuple(
                 (a.astype(jnp.float32) + b.astype(jnp.float32))
                 .astype(a.dtype) for a, b in zip(grads, part))
@@ -302,7 +325,7 @@ def _experts_bwd(chunk, res, dout):
         stop = None if chunk is None else _span(sizes, 0, chunk)[0]
         dx, dw1, dw3, dw2, dweight = _later_chunks(
             chunk, order, sizes, stop, more,
-            _chunk_bwd(dout, kept, w1, w3, w2, inverse, k, chunk))
+            _chunk_bwd(dout, kept, w1, w3, w2, inverse, k, chunk, act))
     return dx.astype(x.dtype), dw1, dw3, dw2, dweight.reshape(-1, k), \
         None, None, None
 
@@ -313,15 +336,17 @@ _experts.defvjp(_experts_fwd, _experts_bwd)
 def sparse_moe(x, router_weight, w1, w3, w2, expert_bias, num_experts,
                num_experts_per_tok, first_expert=0, scoring="sigmoid",
                norm_topk_prob=True, routed_scaling_factor=1.0,
-               norm_topk_eps=1e-6):
+               norm_topk_eps=1e-6, route=None, act="silu"):
     """(the held experts' part of the layer's result, the k expert ids of
-    every token as float32).  x [..., H]; router_weight [num_experts, H];
+    every token as float32).  x [..., H]; router_weight [num_experts, R];
     w1, w3 [held, H, I] and w2 [held, I, H] for the experts
     ``first_expert .. first_expert + held - 1``; expert_bias
-    [num_experts], added to the scores for the choice only;
+    [num_experts] or None, added to the scores for the choice only;
     ``routed_scaling_factor`` (a family's ``route_scale``) multiplies the
     weights after their normalisation by the chosen scores' sum +
-    ``norm_topk_eps``.
+    ``norm_topk_eps``.  The router reads *route* [..., R] where one is
+    given (its gradient goes there), else x; the experts read x and gate
+    by *act*: ``silu`` (SwiGLU) or ``relu`` (ReGLU).
 
     Inside a recomputation segment (``ops/remat.py``) the routing's
     integers are kept for the backward: the expert ids, ``order``,
@@ -340,10 +365,20 @@ def sparse_moe(x, router_weight, w1, w3, w2, expert_bias, num_experts,
             "sparse_moe: router of %d experts, %d held from %d, "
             "num_experts %s" % (router_weight.shape[0], held, first_expert,
                                 num_experts))
+    if act not in _ACTS:
+        raise ValueError("sparse_moe: act %r (%s)" % (act, ", ".join(_ACTS)))
     flat = x.reshape(-1, x.shape[-1])
     rows = flat.shape[0] * k
+    if route is None:
+        src = flat
+    elif route.shape[:-1] != x.shape[:-1]:
+        raise ValueError("sparse_moe: a routing input of %s for data of %s"
+                         % (route.shape, x.shape))
+    else:
+        src = route.reshape(-1, route.shape[-1])
+        _tel.bump("sparse_moe_router_apart_traced")
     with jax.named_scope("moe_route"):
-        logits = jnp.dot(flat.astype(jnp.float32),
+        logits = jnp.dot(src.astype(jnp.float32),
                          router_weight.astype(jnp.float32).T,
                          precision=jax.lax.Precision.HIGHEST)
         if scoring == "sigmoid":
@@ -354,7 +389,8 @@ def sparse_moe(x, router_weight, w1, w3, w2, expert_bias, num_experts,
             raise ValueError("sparse_moe: scoring %r (sigmoid, softmax)"
                              % (scoring,))
         idx, weight = topk_route(
-            scores, k, jax.lax.stop_gradient(expert_bias.astype(jnp.float32)),
+            scores, k, None if expert_bias is None else
+            jax.lax.stop_gradient(expert_bias.astype(jnp.float32)),
             bool(norm_topk_prob), float(routed_scaling_factor),
             float(norm_topk_eps))
         local = idx.reshape(-1) - int(first_expert)
@@ -368,28 +404,40 @@ def sparse_moe(x, router_weight, w1, w3, w2, expert_bias, num_experts,
         order, inverse, sizes = _remat.keep(order, inverse, sizes)
     _tel.bump("sparse_moe_traced")
     _tel.bump("sparse_moe_rows", rows)
+    if act == "relu":
+        _tel.bump("sparse_moe_reglu_traced")
     chunk = None
     if held < int(num_experts):
         chunk = _share_rows(rows, held, int(num_experts))
         _tel.bump("sparse_moe_held_rows_budget", chunk)
-    out = _experts(flat, w1, w3, w2, weight, order, inverse, sizes, chunk)
+    out = _experts(flat, w1, w3, w2, weight, order, inverse, sizes, chunk,
+                   act)
     return out.reshape(x.shape), \
         idx.reshape(x.shape[:-1] + (k,)).astype(jnp.float32)
 
 
-@register("_contrib_SparseMoE", aliases=["SparseMoE"], num_outputs=2,
-          nondiff_inputs=(5,))
-def _sparse_moe(data, router_weight, w1, w3, w2, expert_bias,
-                num_experts=None, num_experts_per_tok=1, first_expert=0,
-                scoring="sigmoid", norm_topk_prob=True,
-                routed_scaling_factor=1.0, norm_topk_eps=1e-6, **kw):
-    """Dropless sparse-expert SwiGLU layer: output 0 is the held experts'
-    part of the result, output 1 the chosen expert ids [..., k] (float32,
-    no gradient).  The stacked tensors' leading axis says how many experts
-    are held, ``first_expert`` which; the router scores all
-    ``num_experts``."""
+# the selection bias has no gradient by stop_gradient inside the op, not
+# by position: with ``route_apart`` input 5 is the routing operand
+@register("_contrib_SparseMoE", aliases=["SparseMoE"], num_outputs=2)
+def _sparse_moe(data, router_weight, w1, w3, w2, *rest, num_experts=None,
+                num_experts_per_tok=1, first_expert=0, scoring="sigmoid",
+                norm_topk_prob=True, routed_scaling_factor=1.0,
+                norm_topk_eps=1e-6, act_type="silu", route_apart=False,
+                no_bias=False, **kw):
+    """Dropless sparse-expert layer, SwiGLU (``act_type="silu"``) or ReGLU
+    (``"relu"``): output 0 is the held experts' part of the result, output
+    1 the chosen expert ids [..., k] (float32, no gradient).  The stacked
+    tensors' leading axis says how many experts are held, ``first_expert``
+    which; the router scores all ``num_experts``.  After the three stacks
+    come the routing operand ``route_data`` if ``route_apart`` (the router
+    reads it instead of ``data``) and the auxiliary ``expert_bias`` unless
+    ``no_bias``."""
+    rest = list(rest)
+    route = rest.pop(0) if route_apart else None
+    expert_bias = None if no_bias else rest.pop(0)
     out, choice = sparse_moe(
         data, router_weight, w1, w3, w2, expert_bias, int(num_experts),
         int(num_experts_per_tok), int(first_expert), scoring,
-        norm_topk_prob, routed_scaling_factor, norm_topk_eps)
+        norm_topk_prob, routed_scaling_factor, norm_topk_eps, route,
+        act_type)
     return out, jax.lax.stop_gradient(choice)

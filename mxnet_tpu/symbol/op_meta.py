@@ -77,7 +77,9 @@ def op_input_names(op, attrs):
         return ["data", "dt", "a_log", "b", "c", "d"], []
     if name == "_contrib_SparseMoE":
         return ["data", "router_weight", "w1_weight", "w3_weight",
-                "w2_weight"], ["expert_bias"]
+                "w2_weight"] + (["route_data"] if a.get("route_apart")
+                                else []), \
+            [] if a.get("no_bias", False) else ["expert_bias"]
     if name == "Embedding":
         return ["data", "weight"], []
     if name == "RNN":

@@ -589,6 +589,11 @@ COUNTERS = {
                                    "sorted order (ops/moe.py:_share_rows), "
                                    "summed over the SparseMoE ops traced "
                                    "that hold fewer experts than they route",
+    "sparse_moe_router_apart_traced": "_contrib_SparseMoE ops traced whose "
+                                      "router reads an operand of its own "
+                                      "(route_data), not the experts' input",
+    "sparse_moe_reglu_traced": "_contrib_SparseMoE ops traced with ReGLU "
+                               "experts (act_type relu)",
     "causal_attention_traced": "_contrib_CausalAttention ops traced without "
                                "a window",
     "window_attention_traced": "_contrib_CausalAttention ops traced with a "

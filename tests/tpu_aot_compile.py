@@ -122,6 +122,35 @@ def main():
         print("AOT ok sparse_moe grad, %s kernels only" % kernel,
               flush=True)
 
+    # the SmallThinker widths (hidden 2,560, experts of 768, top 6 of 64,
+    # 8 of them held here, 2,048 tokens): a contraction of 768 under the
+    # transposed products' tile of 1,024 and one of 2,560 that is no
+    # multiple of it, the router reading an operand of its own, ReGLU, no
+    # selection bias; every grouped product a Pallas kernel
+    moe._kernel_backend = lambda: True
+    text = jax.jit(jax.value_and_grad(
+        lambda x, r, *w: moe.sparse_moe(
+            x, w[0], *w[1:], None, num_experts=64, num_experts_per_tok=6,
+            scoring="softmax", norm_topk_eps=0.0, route=r, act="relu")[0]
+        .astype(jnp.float32).sum(), (0, 1, 2, 3, 4, 5))).lower(
+        spec(2048, 2560), spec(2048, 2560), spec(64, 2560),
+        spec(8, 2560, 768), spec(8, 2560, 768),
+        spec(8, 768, 2560)).compile().as_text()
+    assert text.count("%gmm") >= 6 and text.count("%tgmm") >= 3
+    assert "ragged" not in text
+    print("AOT ok sparse_moe ReGLU, router apart, 2,560 x 768", flush=True)
+
+    # 28 query heads over 4 (seven a key/value head), head size 128, a
+    # window of 4,096 in 16,384 tokens
+    q = jax.ShapeDtypeStruct((1, 16384, 28, 128), jnp.bfloat16, sharding=one)
+    kv = jax.ShapeDtypeStruct((1, 16384, 4, 128), jnp.bfloat16, sharding=one)
+    text = jax.jit(jax.value_and_grad(
+        lambda *x: lm.causal_attention(*x, 128 ** -0.5, True, True, 4096)
+        .astype(jnp.float32).sum(), (0, 1, 2))).lower(q, kv, kv) \
+        .compile().as_text()
+    assert "window_attention_bwd" in text
+    print("AOT ok window attention, seven heads a group, 4,096", flush=True)
+
     # power retention at the Brumby widths (5 query heads a key/value
     # head of 128, chunks of 1024): the Pallas forward, which writes the
     # outputs alone, and through the gradient the states kernel that
