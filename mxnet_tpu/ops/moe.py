@@ -88,6 +88,32 @@ def _half(n):
     return n // 2 if n % 256 == 0 else n
 
 
+_DEPTH = 1024
+_TILE_AREA = 1024 * 1280   # the largest [depth, width] tgmm tile that fits
+
+
+def _depth(d, lanes):
+    """The tile over a dimension *d* that the backward's grouped products
+    walk in steps (a transposed stack's contraction, a stacked gradient's
+    rows) beside an output tile *lanes* wide: all of *d* up to
+    ``_DEPTH``; else the deepest multiple of 256 from 512 to 1280 that
+    divides *d* and keeps depth x lanes within ``_TILE_AREA`` (``tgmm``'s
+    float32 [1280, 1280] accumulator overflows the 16 MiB of scoped VMEM);
+    else ``_DEPTH``.  The kernels pad a last tile that *d* does not fill:
+    ``gmm`` masks both operands in every step over it, ``tgmm`` computes
+    the padded rows and drops them.  A shallow tile costs steps: at 2,560
+    on the chip ``tgmm`` took longer in tiles of 512 than in the padded
+    1,024, and least time in 1,280 (PERF.md section 5)."""
+    if d <= _DEPTH:
+        depth = d
+    else:
+        depth = next((t for t in range(1280, 511, -256)
+                      if d % t == 0 and t * lanes <= _TILE_AREA), _DEPTH)
+    if depth != _DEPTH:
+        _tel.bump("sparse_moe_exact_tiles_traced")
+    return depth
+
+
 def _grouped(rows, stack, sizes, live=None, transpose=False):
     """rows [R, a] times the group's matrix of *stack* [G, a, b] ([G, b, a]
     with *transpose*) -> [R, b].  Rows past the last group are in no
@@ -95,10 +121,10 @@ def _grouped(rows, stack, sizes, live=None, transpose=False):
     if _pallas_grouped(rows.shape[0]):
         from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
         a, b = rows.shape[1], stack.shape[1 if transpose else 2]
-        # tiles (rows, contracted, out) from a sweep on the chip at
-        # a, b = 2048, 1792: the whole contraction in one tile when the
-        # stack is read as stored, 1024 of it when read transposed
-        tiling = (_TILE_ROWS, 1024 if transpose else a, _half(b))
+        # tiles (rows, contracted, out): the whole contraction in one tile
+        # when the stack is read as stored, ``_depth``'s when transposed
+        lanes = _half(b)
+        tiling = (_TILE_ROWS, _depth(a, lanes) if transpose else a, lanes)
         # the kernel's own index arithmetic is written for 32-bit JAX
         with jax.enable_x64(False):
             out = gmm(rows, stack, sizes, rows.dtype, tiling,
@@ -119,7 +145,8 @@ def _grouped_outer(lhs, rhs, sizes, dtype):
     (the stacked tensors' gradient; zeros for a group without rows)."""
     if _pallas_grouped(lhs.shape[0]):
         from jax.experimental.pallas.ops.tpu.megablox.gmm import tgmm
-        tiling = (_TILE_ROWS, 1024, _half(rhs.shape[1]))
+        lanes = _half(rhs.shape[1])
+        tiling = (_TILE_ROWS, _depth(lhs.shape[1], lanes), lanes)
         with jax.enable_x64(False):
             return tgmm(lhs.T, rhs, sizes, dtype, tiling)
     dims = _DIMS((([0], [0]), ([], [])), [0], [])

@@ -594,6 +594,9 @@ COUNTERS = {
                                       "(route_data), not the experts' input",
     "sparse_moe_reglu_traced": "_contrib_SparseMoE ops traced with ReGLU "
                                "experts (act_type relu)",
+    "sparse_moe_exact_tiles_traced": "backward grouped products traced on "
+                                     "the Pallas path whose depth the shape "
+                                     "rule set to other than 1,024",
     "causal_attention_traced": "_contrib_CausalAttention ops traced without "
                                "a window",
     "window_attention_traced": "_contrib_CausalAttention ops traced with a "

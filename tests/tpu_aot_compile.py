@@ -123,11 +123,13 @@ def main():
               flush=True)
 
     # the SmallThinker widths (hidden 2,560, experts of 768, top 6 of 64,
-    # 8 of them held here, 2,048 tokens): a contraction of 768 under the
-    # transposed products' tile of 1,024 and one of 2,560 that is no
-    # multiple of it, the router reading an operand of its own, ReGLU, no
-    # selection bias; every grouped product a Pallas kernel
+    # 8 of them held here, 2,048 tokens): the backward's six products at
+    # depths that divide 768 and 2,560 (768 and 1,280), the router reading
+    # an operand of its own, ReGLU, no selection bias; every grouped
+    # product a Pallas kernel
+    from mxnet_tpu import telemetry
     moe._kernel_backend = lambda: True
+    exact = telemetry.counter("sparse_moe_exact_tiles_traced")
     text = jax.jit(jax.value_and_grad(
         lambda x, r, *w: moe.sparse_moe(
             x, w[0], *w[1:], None, num_experts=64, num_experts_per_tok=6,
@@ -138,6 +140,9 @@ def main():
         spec(8, 768, 2560)).compile().as_text()
     assert text.count("%gmm") >= 6 and text.count("%tgmm") >= 3
     assert "ragged" not in text
+    # six backward products in the first chunk's program and six in the
+    # later chunks' loop body (8 of 64 experts held: a partial share)
+    assert telemetry.counter("sparse_moe_exact_tiles_traced") - exact == 12
     print("AOT ok sparse_moe ReGLU, router apart, 2,560 x 768", flush=True)
 
     # 28 query heads over 4 (seven a key/value head), head size 128, a
