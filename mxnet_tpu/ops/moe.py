@@ -29,9 +29,21 @@ row is ever dropped; a chunk stops after the last expert that fits in it
 whole (``_span``), so an expert's gradient is one grouped product's and
 adding the chunks' parts rounds nothing; only the first chunk's
 activations are kept for the backward, a later chunk's are computed
-again there.  The combine still reads ``tokens x k`` row indices (k
-gathers of ``[tokens, H]``).  With every expert held there is one chunk
-of ``tokens x k`` rows and no mask: the program PR 31 measured.
+again there.  The combine reads only the rows inside the chunk (see
+``_combine``).  With every expert held there is one chunk of ``tokens x
+k`` rows, no mask and no loop.
+
+The combine, each token's k rows of the last product added in float32,
+is one Pallas pass on a TPU (``_combine_pallas``): a tile of 128 tokens
+copies, for each group, the 8-row slabs of the sorted order from its first
+row of the group to its last, and adds its rows as one-hot products on the
+MXU, 256 staged rows at a time; it writes its sums once.  The ``jnp`` form
+gathers k [tokens, H] copies, of every row in or out of the chunk, and
+reads them back (and the backward first writes the [rows, H] sum of its
+two transposed products, which the kernel adds as it stages them).  The
+same rows, added in another order.  The kernel is called through a
+``jax.jit`` with its tiling static, so a program traces and lowers it once
+per shape, not once per call site.
 
 The router may read another tensor than the experts do (a family that
 routes from the attention's input), and the experts' gate is SiLU
@@ -45,6 +57,9 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from .. import telemetry as _tel
 from . import remat as _remat
@@ -258,13 +273,233 @@ def _from_chunk(y, where, chunk, start):
                      _rows(y, where), 0)
 
 
-def _combine(y, inverse, k, chunk=None, start=0):
+def _combine(ys, inverse, k, sizes, dtype, chunk=None, start=0, live=None):
     """sum over a token's k choices of its rows of y (the chunk of the
-    sorted order that starts at *start*), in float32: k gathers of [T, H]
-    added, no [T, k, H] tensor."""
+    sorted order that starts at *start*, whose groups have *sizes* rows),
+    in float32, in *dtype*.  *ys* is y, or two [rows, H] terms whose sum
+    rounded to their dtype is y (the backward's two transposed products),
+    as the grouped products left them: *live* [rows, 1] marks the rows in
+    a group, the others are no row of y.
+
+    On a TPU, for bfloat16 rows in whole lane tiles, one Pallas pass
+    (``_combine_pallas``): it reads the 8-row slabs that hold a tile's rows,
+    a slab outside the chunk or past its groups not at all, and writes
+    [T, H] once.  Elsewhere (the CPU, odd widths or rows) ``jnp``: the rows
+    past the groups zeroed, two terms added into [rows, H], then k gathers
+    of [T, H], each written and read again by their sum: the bytes of a
+    [T, k, H] tensor."""
     where = inverse.reshape(-1, k)
+    rows = ys[0].shape[0]
+    groups = sizes.shape[0] + 1     # the held experts' and the rest
+    tile = _combine_tile(where.shape[0], k, groups, len(ys), ys[0].shape[1])
+    if _kernel_backend() and tile and rows % 8 == 0 and \
+            all(y.dtype == jnp.bfloat16 for y in ys):
+        _tel.bump("sparse_moe_combine_kernel_traced")
+        return _combine_pallas(
+            tuple(ys), inverse, jnp.asarray(start, jnp.int32),
+            jnp.asarray(rows if live is None else jnp.sum(sizes), jnp.int32),
+            sizes.astype(jnp.int32), k=k, tile=tile,
+            slots=_combine_slots(tile, k, groups), dtype=jnp.dtype(dtype))
+    if live is not None:
+        ys = [jnp.where(live, y, 0) for y in ys]
+    y = ys[0] if len(ys) == 1 else (ys[0].astype(jnp.float32) +
+                                    ys[1].astype(jnp.float32)) \
+        .astype(ys[0].dtype)
     return sum(_from_chunk(y, where[:, j], chunk, start).astype(jnp.float32)
-               for j in range(k))
+               for j in range(k)).astype(dtype)
+
+
+_COMBINE_STAGE_BYTES = 64 << 20   # both slots' slabs of every term
+_COMBINE_VMEM_BYTES = 100 << 20
+_COMBINE_ROWS = 256               # staged rows a one-hot product reads
+
+
+def _combine_slots(tile, k, groups):
+    """Slabs of 8 sorted rows that a tile of *tile* tokens reads at most,
+    in whole products of ``_COMBINE_ROWS`` rows: the rows of one group a
+    tile takes are contiguous in the sorted order (it is stable by token),
+    so each group's run lies in its rows / 8 + 2 slabs."""
+    per = _COMBINE_ROWS // 8
+    return -(-min(tile * k, -(-tile * k // 8) + 2 * groups) // per) * per
+
+
+def _combine_tile(tokens, k, groups, terms, width):
+    """The largest tile of tokens (8 to 128) that divides *tokens* and
+    whose slabs, double-buffered for every term, fit
+    ``_COMBINE_STAGE_BYTES``; None if width is no whole lane tiles."""
+    if width % 128:
+        return None
+    return next((t for t in (128, 64, 32, 16, 8)
+                 if tokens % t == 0 and 2 * terms * _combine_slots(
+                     t, k, groups) * 8 * width * 2 <= _COMBINE_STAGE_BYTES),
+                None)
+
+
+def _combine_plan(where, limit, sizes, tile):
+    """(what each tile stages [tiles, 1, 3 (G + 1) + 1]: for each group of
+    the chunk, and for the rows past them, its first slab of 8 sorted rows,
+    how many slabs from there and where they go among the staged ones,
+    then their count; each (token, choice)'s row among its tile's staged
+    slabs [T, k], -1 for a row outside 0 .. *limit* - 1), int32.  *sizes*
+    [G] are the groups' rows in the chunk, in order from its row 0.
+
+    The rows of one group that a tile of tokens takes are contiguous in
+    the sorted order (it is stable by token), so a tile reads, for each
+    group, the slabs from its first row there to its last: no sort, a slab
+    that two groups share read once for each."""
+    tokens, k = where.shape
+    tiles, groups = tokens // tile, sizes.shape[0] + 1
+    p = where.reshape(tiles, tile * k)
+    live = (p >= 0) & (p < limit)
+    ends = jnp.cumsum(sizes.astype(jnp.int32))
+    group = jnp.sum(p[..., None] >= ends, axis=-1, dtype=jnp.int32)
+    mine = live[..., None] & (group[..., None] == jnp.arange(groups))
+    big = jnp.iinfo(jnp.int32).max
+    low = jnp.min(jnp.where(mine, p[..., None], big), axis=1)
+    high = jnp.max(jnp.where(mine, p[..., None], -1), axis=1)
+    first = jnp.where(high >= low, low // 8, 0)
+    count = jnp.where(high >= low, high // 8 + 1 - first, 0)
+    at = jnp.cumsum(count, axis=1, dtype=jnp.int32) - count
+    table = jnp.concatenate([first, count, at, at[:, -1:] + count[:, -1:]],
+                            axis=1).astype(jnp.int32)
+    shift = jnp.sum(jnp.where(mine, (8 * (at - first))[:, None, :], 0),
+                    axis=-1, dtype=jnp.int32)
+    pos = jnp.where(live, p + shift, -1)
+    return table[:, None, :], pos.astype(jnp.int32).reshape(tokens, k)
+
+
+def _loop(n, body):
+    """``body(i)`` for int32 i in 0 .. n - 1 (a traced n); under x64
+    ``fori_loop``'s own counter is 64-bit, which Mosaic has not."""
+    def step(i):
+        body(i)
+        return jax.lax.add(i, np.int32(1))
+    jax.lax.while_loop(lambda i: i < n, step, np.int32(0))
+
+
+def _combine_kernel(table_ref, after_ref, pos_ref, *refs, k, groups,
+                    terms):
+    """One tile of tokens: start the next tile's slab copies, wait for this
+    tile's (started a step before), then add each token's k rows in
+    float32 as one-hot products on the MXU, ``_COMBINE_ROWS`` staged rows
+    at a time, and write the tile once."""
+    srcs, (out_ref, acc_ref, sem), stages = \
+        refs[:terms], refs[terms:terms + 3], refs[terms + 3:]
+    i, tiles = pl.program_id(0), pl.num_programs(0)
+
+    def copies(slot, m, c):
+        return [pltpu.make_async_copy(src.at[pl.ds(c, 1)],
+                                      stage.at[slot, pl.ds(m, 1)],
+                                      sem.at[slot])
+                for src, stage in zip(srcs, stages)]
+
+    def fetch(table, slot):
+        def group(g):
+            first, at = table[0, g], table[0, 2 * groups + g]
+
+            def one(r):
+                for copy in copies(slot, at + r, first + r):
+                    copy.start()
+            _loop(table[0, groups + g], one)
+        _loop(np.int32(groups), group)
+
+    @pl.when(i == 0)
+    def _first():
+        fetch(table_ref, np.int32(0))
+
+    @pl.when(i + 1 < tiles)
+    def _next():
+        fetch(after_ref, jax.lax.rem(i + 1, np.int32(2)))
+
+    slot = jax.lax.rem(i, np.int32(2))
+    staged = table_ref[0, 3 * groups]
+
+    def wait(m):
+        for copy in copies(slot, np.int32(0), np.int32(0)):
+            copy.wait()
+    _loop(staged, wait)
+
+    f32, n, per = jnp.float32, _COMBINE_ROWS, _COMBINE_ROWS // 8
+    pos = pos_ref[...]
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def product(c):
+        # the tile's one-hot [tile, n] over staged rows c n .. c n + n - 1
+        # (-1, a row outside the chunk, hits none): row t adds exactly
+        # its own rows, in float32
+        base = jax.lax.mul(c, np.int32(n))
+        col = jax.lax.broadcasted_iota(jnp.int32, (pos.shape[0], n), 1) + base
+        hit = pos[:, 0:1] == col
+        for j in range(1, k):
+            hit = hit | (pos[:, j:j + 1] == col)
+        at = pl.ds(pl.multiple_of(jax.lax.mul(c, np.int32(per)), per), per)
+        y = [stage[slot, at].reshape(n, stage.shape[-1]) for stage in stages]
+        y = y[0] if terms == 1 else \
+            (y[0].astype(f32) + y[1].astype(f32)).astype(jnp.bfloat16)
+        # staged rows past this tile's slabs hold an earlier tile's rows or
+        # nothing yet: zeros, so that no NaN meets a zero of the one-hot
+        row = jax.lax.broadcasted_iota(jnp.int32, (n, 1), 0) + base
+        y = jnp.where(row < jax.lax.mul(staged, np.int32(8)), y,
+                      jnp.zeros_like(y))
+        acc_ref[...] += jnp.dot(hit.astype(jnp.bfloat16), y,
+                                preferred_element_type=f32)
+    _loop(jax.lax.div(staged + np.int32(per - 1), np.int32(per)), product)
+    out_ref[...] = acc_ref[...].astype(out_ref.dtype)
+
+
+def _combine_call(ys, inverse, start, limit, sizes, *, k, tile, slots,
+                  dtype, interpret=False):
+    """``_combine``'s Pallas pass: [T, H] in *dtype* from the [rows, H]
+    bfloat16 terms *ys* at the positions *inverse* [T k] - *start*, a row
+    outside 0 .. *limit* - 1 adding nothing; the chunk's groups have
+    *sizes* rows.
+
+    Called as ``_combine_pallas``, a ``jax.jit`` with the tiling static,
+    as JAX's ``gmm`` is: every call of one shape in a program (the layers,
+    the forward, its replay and the backward, the later chunks' loops)
+    shares one traced jaxpr and one lowered function, so tracing and
+    lowering the kernel is paid once per shape, not once per call."""
+    where = inverse.reshape(-1, k) - start
+    tokens = where.shape[0]
+    rows, width = ys[0].shape
+    tiles, groups = tokens // tile, sizes.shape[0] + 1
+    table, pos = _combine_plan(where, limit, sizes, tile)
+    # the slab that holds the groups' last row holds rows past them too,
+    # which the grouped products left unwritten: zeros (in place; nothing
+    # when the groups fill the rows)
+    at = jnp.minimum(limit // 8 * 8, rows - 8)
+    keep = (jnp.arange(8) + at < limit)[:, None]
+    ys = tuple(jax.lax.dynamic_update_slice_in_dim(
+        y, jnp.where(keep, jax.lax.dynamic_slice_in_dim(y, at, 8), 0),
+        at, 0) for y in ys)
+    last = np.int32(tiles - 1)
+    entries = 3 * groups + 1
+    smem = functools.partial(pl.BlockSpec, memory_space=pltpu.SMEM)
+    return pl.pallas_call(
+        functools.partial(_combine_kernel, k=k, groups=groups,
+                          terms=len(ys)),
+        grid=(tiles,),
+        in_specs=[smem((None, 1, entries),
+                       lambda i: (i, np.int32(0), np.int32(0))),
+                  smem((None, 1, entries), lambda i: (
+                      jnp.minimum(i + np.int32(1), last), np.int32(0),
+                      np.int32(0))),
+                  pl.BlockSpec((tile, k), lambda i: (i, np.int32(0)))] +
+        [pl.BlockSpec(memory_space=pl.ANY)] * len(ys),
+        out_specs=pl.BlockSpec((tile, width), lambda i: (i, np.int32(0))),
+        out_shape=jax.ShapeDtypeStruct((tokens, width), dtype),
+        scratch_shapes=[pltpu.VMEM((tile, width), jnp.float32),
+                        pltpu.SemaphoreType.DMA((2,))] +
+        [pltpu.VMEM((2, slots, 8, width), jnp.bfloat16)] * len(ys),
+        name="moe_combine", interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_COMBINE_VMEM_BYTES),
+    )(table, table, pos, *[y.reshape(rows // 8, 8, width) for y in ys])
+
+
+_combine_pallas = _tel.watch_jit(jax.jit(_combine_call, static_argnames=(
+    "k", "tile", "slots", "dtype", "interpret")), "moe_combine")
 
 
 def _chunk_fwd(x, w1, w3, w2, weight, order, sizes, chunk, act, start=0):
@@ -277,7 +512,8 @@ def _chunk_fwd(x, w1, w3, w2, weight, order, sizes, chunk, act, start=0):
     # the combine's weight goes in before the last product, which is
     # linear in its rows: the backward then needs no copy of its output
     aw = (_gate(h1, h3, act)[0] * ws[:, None]).astype(x.dtype)
-    return _grouped(aw, w2, sizes, live), rows + (h1, h3), stop
+    # rows past the groups are zeroed where the combine reads them
+    return _grouped(aw, w2, sizes), rows + (h1, h3), stop
 
 
 def _later_chunks(chunk, order, sizes, stop, body, init):
@@ -292,25 +528,38 @@ def _later_chunks(chunk, order, sizes, stop, body, init):
                               lambda state: body(*state), (stop, init))[1]
 
 
+def _sum_dtype(x, order, chunk):
+    """The combine's dtype: float32 where later chunks add to it, else
+    x's, which the float32 sum is rounded to once."""
+    return jnp.float32 if chunk is not None and chunk < order.shape[0] \
+        else x.dtype
+
+
 def _experts_fwd(x, w1, w3, w2, weight, order, inverse, sizes, chunk, act):
-    k = weight.shape[1]
+    k, dtype = weight.shape[1], _sum_dtype(x, order, chunk)
+
+    def combine(y, kept, start=0):
+        live, sizes = kept[2:4]
+        return _combine((y,), inverse, k, sizes, dtype, chunk, start, live)
+
     with jax.named_scope("moe_experts_fwd"):
         y, kept, stop = _chunk_fwd(x, w1, w3, w2, weight, order, sizes,
                                    chunk, act)
 
         def more(start, out):
-            y, _, stop = _chunk_fwd(x, w1, w3, w2, weight, order, sizes,
-                                    chunk, act, start)
-            return stop, out + _combine(y, inverse, k, chunk, start)
+            y, kept, stop = _chunk_fwd(x, w1, w3, w2, weight, order, sizes,
+                                       chunk, act, start)
+            return stop, out + combine(y, kept, start)
 
         out = _later_chunks(chunk, order, sizes, stop, more,
-                            _combine(y, inverse, k, chunk))
+                            combine(y, kept))
     return out.astype(x.dtype), (x, weight, kept, w1, w3, w2, order, inverse,
                                  sizes)
 
 
-def _chunk_bwd(dout, kept, w1, w3, w2, inverse, k, chunk, act, start=0):
-    """One chunk's part of (dx [T, H] float32, dw1, dw3, dw2, dweight
+def _chunk_bwd(dout, kept, w1, w3, w2, inverse, k, chunk, act, dtype,
+               start=0):
+    """One chunk's part of (dx [T, H] in *dtype*, dw1, dw3, dw2, dweight
     [T k] float32) from what ``_chunk_fwd`` kept."""
     xs, ws, live, sizes, head, h1, h3 = kept
     dt = xs.dtype
@@ -320,20 +569,20 @@ def _chunk_bwd(dout, kept, w1, w3, w2, inverse, k, chunk, act, start=0):
     dws = jnp.sum(daw * a, axis=-1)
     da = daw * ws[:, None]
     dh1, dh3 = _gate_grads(da, h1, h3, act, d, dt)
-    dxs = (_grouped(dh1, w1, sizes, live, transpose=True)
-           .astype(jnp.float32) +
-           _grouped(dh3, w3, sizes, live, transpose=True)
-           .astype(jnp.float32)).astype(dt)
+    # dx's rows are the sum of the two transposed products rounded to dt:
+    # the combine adds them as it reads them
+    dxs = (_grouped(dh1, w1, sizes, transpose=True),
+           _grouped(dh3, w3, sizes, transpose=True))
     dw1 = _grouped_outer(xs, dh1, sizes, w1.dtype)
     dw3 = _grouped_outer(xs, dh3, sizes, w3.dtype)
     dw2 = _grouped_outer((a * ws[:, None]).astype(dt), g, sizes, w2.dtype)
-    return (_combine(dxs, inverse, k, chunk, start), dw1, dw3, dw2,
-            _from_chunk(dws, inverse, chunk, start))
+    return (_combine(dxs, inverse, k, sizes, dtype, chunk, start, live),
+            dw1, dw3, dw2, _from_chunk(dws, inverse, chunk, start))
 
 
 def _experts_bwd(chunk, act, res, dout):
     x, weight, kept, w1, w3, w2, order, inverse, sizes = res
-    k = weight.shape[1]
+    k, dtype = weight.shape[1], _sum_dtype(x, order, chunk)
     with jax.named_scope("moe_experts_bwd"):
         # a later chunk's activations were not kept and are computed
         # again.  An expert's rows are in one chunk (``_span``), so of the
@@ -344,7 +593,7 @@ def _experts_bwd(chunk, act, res, dout):
             _, kept, stop = _chunk_fwd(x, w1, w3, w2, weight, order, sizes,
                                        chunk, act, start)
             part = _chunk_bwd(dout, kept, w1, w3, w2, inverse, k, chunk,
-                              act, start)
+                              act, dtype, start)
             return stop, tuple(
                 (a.astype(jnp.float32) + b.astype(jnp.float32))
                 .astype(a.dtype) for a, b in zip(grads, part))
@@ -352,7 +601,8 @@ def _experts_bwd(chunk, act, res, dout):
         stop = None if chunk is None else _span(sizes, 0, chunk)[0]
         dx, dw1, dw3, dw2, dweight = _later_chunks(
             chunk, order, sizes, stop, more,
-            _chunk_bwd(dout, kept, w1, w3, w2, inverse, k, chunk, act))
+            _chunk_bwd(dout, kept, w1, w3, w2, inverse, k, chunk, act,
+                       dtype))
     return dx.astype(x.dtype), dw1, dw3, dw2, dweight.reshape(-1, k), \
         None, None, None
 

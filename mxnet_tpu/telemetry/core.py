@@ -597,6 +597,11 @@ COUNTERS = {
     "sparse_moe_exact_tiles_traced": "backward grouped products traced on "
                                      "the Pallas path whose depth the shape "
                                      "rule set to other than 1,024",
+    "sparse_moe_combine_kernel_traced": "sparse-expert combines traced as "
+                                        "the one-pass Pallas kernel "
+                                        "(moe_combine: a TPU, bfloat16 rows "
+                                        "in whole lane tiles), forward and "
+                                        "backward, one a call site",
     "causal_attention_traced": "_contrib_CausalAttention ops traced without "
                                "a window",
     "window_attention_traced": "_contrib_CausalAttention ops traced with a "

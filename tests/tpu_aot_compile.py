@@ -145,6 +145,33 @@ def main():
     assert telemetry.counter("sparse_moe_exact_tiles_traced") - exact == 12
     print("AOT ok sparse_moe ReGLU, router apart, 2,560 x 768", flush=True)
 
+    # the combine alone at the three expert cells' shapes (16,384 tokens):
+    # Trinity's quarter share (k 8, hidden 2,048, the first chunk of 49,152
+    # rows, 32 groups, float32 for the later chunks to add to), SmallThinker's
+    # and LFM2's every expert held (k 6 at 2,560 over 64 groups, k 4 at
+    # 2,048 over 32); one term as the forward calls it, two as the backward
+    combined = telemetry.counter("sparse_moe_combine_kernel_traced")
+    for name, k, hidden, rows, groups, chunk, dtype in (
+            ("Trinity", 8, 2048, 49152, 32, 49152, jnp.float32),
+            ("SmallThinker", 6, 2560, 98304, 64, None, jnp.bfloat16),
+            ("LFM2", 4, 2048, 65536, 32, None, jnp.bfloat16)):
+        for terms in (1, 2):
+            def combine(ys, inverse, sizes, k=k, rows=rows, chunk=chunk,
+                        dtype=dtype):
+                live = None if chunk is None else \
+                    (jnp.arange(rows) < jnp.sum(sizes))[:, None]
+                return moe._combine(ys, inverse, k, sizes, dtype, chunk, 0,
+                                    live)
+            text = jax.jit(combine).lower(
+                (spec(rows, hidden),) * terms,
+                spec(16384 * k, dtype=jnp.int32),
+                spec(groups, dtype=jnp.int32)).compile().as_text()
+            assert "moe_combine" in text
+            print("AOT ok sparse_moe combine kernel, %s, %d term%s"
+                  % (name, terms, "s" if terms > 1 else ""), flush=True)
+    assert telemetry.counter("sparse_moe_combine_kernel_traced") - \
+        combined == 6
+
     # 28 query heads over 4 (seven a key/value head), head size 128, a
     # window of 4,096 in 16,384 tokens
     q = jax.ShapeDtypeStruct((1, 16384, 28, 128), jnp.bfloat16, sharding=one)
